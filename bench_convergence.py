@@ -35,10 +35,6 @@ def run_arm(tr, te, batch, steps, lr):
     import jax
     import jax.numpy as jnp
 
-    from fm_spark_tpu.utils.cpuguard import force_cpu_platform
-
-    force_cpu_platform()
-
     from fm_spark_tpu import models
     from fm_spark_tpu.data import Batches
     from fm_spark_tpu.sparse import make_field_sparse_sgd_step

@@ -279,17 +279,13 @@ def main(argv=None) -> int:
                 f"hot tier ({args.hot_buckets * args.bucket_rows} rows)"
                 f" >= decade {d}: nothing to tier at that rung")
 
-    from fm_spark_tpu.utils.cpuguard import force_cpu_platform
-
-    force_cpu_platform()
-
     from fm_spark_tpu import obs
     from fm_spark_tpu.utils import compile_cache
 
     run_id = args.run_id or obs.new_run_id()
     run_dir = os.path.join(args.art_dir, "obs", run_id)
     obs.configure(run_dir, run_id=run_id)
-    compile_cache.enable_from_env()
+    compile_cache.enable()
 
     import jax
 

@@ -88,13 +88,13 @@ def main():
         args.cap = max(512, int(args.cap / s))
         args.ffm_batch = max(256, int(args.ffm_batch / s))
 
-    from fm_spark_tpu.utils.cpuguard import force_cpu_platform
-
-    on_cpu = force_cpu_platform()
     import jax
 
+    from fm_spark_tpu.ops import pallas_interpret
+
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").strip() == "cpu"
     backend = jax.default_backend()
-    interpret = backend != "tpu"
+    interpret = pallas_interpret()
     if interpret and not (on_cpu or args.interpret_ok):
         raise SystemExit(
             "bench_kernels needs the real TPU for decision-grade "

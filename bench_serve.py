@@ -212,7 +212,7 @@ def _fleet_stats_delta(before: dict, after: dict) -> dict:
                       "failed", "retries")}
 
 
-def _fleet_ladder(args, run_dir: str, cache_dir
+def _fleet_ladder(args, run_dir: str
                   ) -> tuple[list[dict], list[dict]]:
     """Fleet rungs (ISSUE 17): aggregate QPS, p99 under shed, and
     replica-loss recovery time for an ``--fleet N`` replica fleet
@@ -248,8 +248,7 @@ def _fleet_ladder(args, run_dir: str, cache_dir
         work_dir=os.path.join(fleet_dir, "work"),
         journal=EventLog(os.path.join(run_dir, "fleet_health.jsonl")),
         buckets=args.fleet_buckets,
-        latency_budget_ms=args.latency_budget_ms,
-        compile_cache_dir=cache_dir)
+        latency_budget_ms=args.latency_budget_ms)
     fleet.start()
     door = FrontDoor(fleet,
                      admission=AdmissionController(
@@ -469,11 +468,6 @@ def main(argv=None) -> int:
     ap.add_argument("--slo-ms", type=float, default=None, dest="slo_ms",
                     help="arm the serve_request watchdog at this "
                          "deadline (overrun = structured HangDetected)")
-    ap.add_argument("--compile-cache", default=None, dest="compile_cache",
-                    metavar="DIR",
-                    help="persistent compile-cache dir (default: the "
-                         "repo-local cache — the warm path IS the "
-                         "point of this bench)")
     ap.add_argument("--art-dir", default=os.path.join(_REPO, "artifacts"),
                     dest="art_dir")
     ap.add_argument("--measured-path", default=None, dest="measured_path",
@@ -500,9 +494,10 @@ def main(argv=None) -> int:
     args.bucket_list = tuple(sorted(
         {int(b) for b in args.buckets.split(",") if b}))
 
-    from fm_spark_tpu.utils.cpuguard import force_cpu_platform
+    if args.fleet > 0:
+        from fm_spark_tpu.serve.fleet import refuse_on_tpu
 
-    force_cpu_platform()
+        refuse_on_tpu(f"bench_serve.py --fleet {args.fleet}")
 
     from fm_spark_tpu import obs
     from fm_spark_tpu.resilience import watchdog
@@ -511,7 +506,7 @@ def main(argv=None) -> int:
     run_id = args.run_id or obs.new_run_id()
     run_dir = os.path.join(args.art_dir, "obs", run_id)
     obs.configure(run_dir, run_id=run_id)
-    cache_dir = compile_cache.enable(args.compile_cache or None)
+    cache_dir = compile_cache.enable()
     if args.slo_ms is not None:
         watchdog.configure({"serve_request": args.slo_ms / 1e3},
                            action="raise")
@@ -546,8 +541,7 @@ def main(argv=None) -> int:
     fleet_rungs: list[dict] = []
     fleet_violations: list[dict] = []
     if args.fleet > 0:
-        fleet_rungs, fleet_violations = _fleet_ladder(
-            args, run_dir, cache_dir)
+        fleet_rungs, fleet_violations = _fleet_ladder(args, run_dir)
 
     # ------------------------------------------------- ledger + sentinel
     from fm_spark_tpu.obs import (

@@ -34,10 +34,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from fm_spark_tpu.utils.cpuguard import force_cpu_platform  # noqa: E402
-
-force_cpu_platform(only_if_env=False)
-
 B, F, K, BUCKET = 131072, 39, 64, 16384
 
 

@@ -19,5 +19,4 @@ built on top of these kernels in the sibling subpackages.
 
 __version__ = "0.1.0"
 
-from fm_spark_tpu import _jax_compat  # noqa: F401  (jax.shard_map shim)
 from fm_spark_tpu import ops, models  # noqa: F401

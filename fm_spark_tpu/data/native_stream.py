@@ -92,8 +92,7 @@ def native_stream_unsupported_reason(dataset: str, max_nnz: int,
         err = native.build_error()
         return (f"no native chunk parser for {dataset!r}"
                 + (f" (build error: {err})" if err else
-                   " (libfmfast.so is stale or the dataset has no "
-                   "chunk-row entry point)"))
+                   " (the dataset has no chunk-row entry point)"))
     fields = native.STREAM_FIELDS.get(dataset)
     if fields is not None:
         if int(max_nnz) < fields:
@@ -126,7 +125,7 @@ def make_stream_batches(reader: ShardReader, dataset: str, batch_size: int,
     ``native_ingest``: ``"auto"`` (default) uses the C++ chunk path when
     :func:`native_stream_supported` says it can be bit-identical and
     silently falls back to :class:`StreamBatches` otherwise (the
-    ``--native-ingest`` fallback rule — e.g. ``libfmfast.so`` absent);
+    ``--native-ingest`` fallback rule — e.g. no compiler to build with);
     ``True`` requires it (raises ``RuntimeError`` when unavailable);
     ``False`` forces the pure-Python path. The two return types speak
     the same batch-source protocol and produce bit-identical streams,

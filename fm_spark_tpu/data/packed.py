@@ -186,7 +186,7 @@ class PackedDataset:
         (read-only — see :meth:`_ones_vals`)."""
         from fm_spark_tpu import native
 
-        if native.gather_available():
+        if native.available():
             if isinstance(sel, slice):
                 start, stop, step = sel.indices(self.num_examples)
                 idx = np.arange(start, stop, step, dtype=np.int64)

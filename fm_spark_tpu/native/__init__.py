@@ -5,39 +5,64 @@ not, so the binding layer is plain ctypes over flat numpy buffers). Every
 function has a pure-numpy fallback in :mod:`fm_spark_tpu.data.hashing`
 with bit-identical output; ``available()`` says which path you're on, and
 nothing in the package *requires* the native path — it is a throughput
-lever for the one-time text→packed preprocessing job (SURVEY.md §7 hard
-part #1), not a correctness dependency.
+lever for the host side of the input pipeline (text→packed preprocessing,
+the packed row gather, the compact aux), not a correctness dependency.
+
+The library is built to ``libfmfast-<hash>.so``, the hash taken over
+``fasthash.cpp`` and the compile line, and only that file is ever loaded:
+a binary left behind by an older source (the tree is copied as it stands
+onto other machines) has another name and is ignored. What remains to
+fall back on is a machine with no compiler; a library that loads but
+lacks a symbol the bindings name is a bug and raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 import numpy as np
 
-_SRC = os.path.join(os.path.dirname(__file__), "fasthash.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "libfmfast.so")
+_DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_DIR, "fasthash.cpp")
+#: The pinned compile line (tools/build_native.py builds with the same).
+COMPILER = "g++"
+FLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib = None
 _build_error: str | None = None
 
 
-def _build() -> str | None:
-    """Compile the .so next to the source if stale/missing. Returns error."""
+def lib_path() -> str:
+    """Where the library for THIS source lives: the name carries the
+    content hash of ``fasthash.cpp`` + the compile line."""
+    h = hashlib.sha256(" ".join((COMPILER, *FLAGS)).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"libfmfast-{h.hexdigest()[:16]}.so")
+
+
+def build(out_path: str) -> None:
+    """Compile ``fasthash.cpp`` to ``out_path`` (raises on failure).
+    Written under a temporary name and renamed, so a concurrent process
+    never loads a half-written library."""
+    tmp = f"{out_path}.{os.getpid()}.tmp"
+    cmd = [COMPILER, *FLAGS, _SRC, "-o", tmp]
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return None
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO]
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        proc = subprocess.run(cmd, capture_output=True, text=True,
+                              timeout=300)
         if proc.returncode != 0:
-            return f"g++ failed: {proc.stderr[-500:]}"
-        return None
-    except Exception as e:  # g++ missing, read-only dir, ...
-        return f"{type(e).__name__}: {e}"
+            raise RuntimeError(
+                f"{' '.join(cmd)} failed (rc={proc.returncode}):\n"
+                f"{proc.stderr[-2000:]}")
+        os.replace(tmp, out_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load():
@@ -45,10 +70,16 @@ def _load():
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        _build_error = _build()
-        if _build_error is not None:
-            return None
-        lib = ctypes.CDLL(_SO)
+        path = lib_path()
+        if not os.path.exists(path):
+            try:
+                build(path)
+            except (OSError, RuntimeError,
+                    subprocess.TimeoutExpired) as e:
+                # g++ missing, read-only dir, compile error, ...
+                _build_error = f"{type(e).__name__}: {e}"
+                return None
+        lib = ctypes.CDLL(path)
         lib.fm_murmur3_32.restype = ctypes.c_uint32
         lib.fm_murmur3_32.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_uint32,
@@ -75,43 +106,36 @@ def _load():
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_void_p,
         ]
-        # Guard newer symbols so a stale-but-fresh-looking .so (cached
-        # artifact) degrades to the numpy fallback instead of raising
-        # AttributeError out of every native entry point.
-        if hasattr(lib, "fm_compact_aux"):
-            lib.fm_compact_aux.restype = ctypes.c_int32
-            lib.fm_compact_aux.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-                ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
+        lib.fm_compact_aux.restype = ctypes.c_int32
+        lib.fm_compact_aux.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p,
+        ]
+        lib.fm_gather_rows.restype = None
+        lib.fm_gather_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p,
+        ]
+        for sym in ("fm_parse_criteo_rows", "fm_parse_avazu_rows"):
+            fn = getattr(lib, sym)
+            fn.restype = ctypes.c_int64
+            fn.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
+                ctypes.c_int, ctypes.c_int64, ctypes.c_int64,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p,
             ]
-        if hasattr(lib, "fm_gather_rows"):
-            lib.fm_gather_rows.restype = None
-            lib.fm_gather_rows.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-                ctypes.c_int32, ctypes.c_int, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p,
-            ]
-        for sym in ("fm_parse_criteo_rows", "fm_parse_avazu_rows"):
-            if hasattr(lib, sym):
-                fn = getattr(lib, sym)
-                fn.restype = ctypes.c_int64
-                fn.argtypes = [
-                    ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32,
-                    ctypes.c_int, ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p,
-                ]
-        if hasattr(lib, "fm_parse_libsvm_rows"):
-            lib.fm_parse_libsvm_rows.restype = ctypes.c_int64
-            lib.fm_parse_libsvm_rows.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p,
-            ]
+        lib.fm_parse_libsvm_rows.restype = ctypes.c_int64
+        lib.fm_parse_libsvm_rows.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64, ctypes.c_int,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p,
+        ]
         _lib = lib
         return _lib
 
@@ -119,14 +143,6 @@ def _load():
 def available() -> bool:
     """True if the native library compiled and loaded on this machine."""
     return _load() is not None
-
-
-def gather_available() -> bool:
-    """True iff the fused batch-gather path is actually live (library
-    loaded AND the fm_gather_rows symbol present — a stale cached .so
-    can load without it, silently degrading to the numpy fallback)."""
-    lib = _load()
-    return lib is not None and hasattr(lib, "fm_gather_rows")
 
 
 def build_error() -> str | None:
@@ -254,11 +270,11 @@ def dedup_aux_native(ids: np.ndarray, bucket: int):
 def compact_aux_native(ids: np.ndarray, cap: int):
     """Native counting-sort COMPACT aux (fm_compact_aux); returns
     ``(useg, segstart, segend, order, inv)`` per
-    ops/scatter.compact_aux's contract, or None when the library (or
-    the symbol, for stale builds) is unavailable. Raises ValueError on
-    per-field unique-count overflow, matching the numpy path."""
+    ops/scatter.compact_aux's contract, or None when the library is
+    unavailable. Raises ValueError on per-field unique-count overflow,
+    matching the numpy path."""
     lib = _load()
-    if lib is None or not hasattr(lib, "fm_compact_aux"):
+    if lib is None:
         return None
     ids = np.ascontiguousarray(ids, np.int32)
     b, f = ids.shape
@@ -298,14 +314,14 @@ def gather_rows_native(ids: np.ndarray, vals: np.ndarray | None,
     converting to field-local ids in the same pass when ``bucket > 0``
     and casting int8 labels to f32. Returns ``(ids, vals, labels)`` with
     ``vals = None`` when the source stores none (caller supplies its
-    cached all-ones array), or None when the native library (or the
-    symbol, for stale builds) is unavailable.
+    cached all-ones array), or None when the native library is
+    unavailable.
 
     Bit-identical to the numpy fallback in
     :meth:`fm_spark_tpu.data.packed.PackedDataset.assemble` (int32
     subtraction and int8->f32 cast are exact in both)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "fm_gather_rows"):
+    if lib is None:
         return None
     if ids.dtype != np.int32 or labels.dtype != np.int8:
         return None  # non-standard packed arrays: let numpy handle it
@@ -359,12 +375,9 @@ STREAM_FIELDS = {"criteo": 39, "avazu": 23}
 
 
 def stream_parse_available(dataset: str) -> bool:
-    """True iff the native chunk-row parser for ``dataset`` is live
-    (library loaded AND the symbol present — a stale cached .so must
-    degrade to the pure-Python streaming path, never AttributeError)."""
-    lib = _load()
-    sym = _STREAM_SYMBOLS.get(dataset)
-    return lib is not None and sym is not None and hasattr(lib, sym)
+    """True iff the library loaded and has a chunk-row parser for
+    ``dataset``."""
+    return dataset in _STREAM_SYMBOLS and _load() is not None
 
 
 def parse_stream_chunk(dataset: str, chunk: bytes, *, bucket: int = 0,
@@ -386,7 +399,7 @@ def parse_stream_chunk(dataset: str, chunk: bytes, *, bucket: int = 0,
     """
     lib = _load()
     sym = _STREAM_SYMBOLS.get(dataset)
-    if lib is None or sym is None or not hasattr(lib, sym):
+    if lib is None or sym is None:
         return None
     n = chunk.count(b"\n")
     status = np.empty(n, np.uint8)

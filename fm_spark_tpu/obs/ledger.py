@@ -12,7 +12,7 @@ becomes a query. Three record kinds share one stream:
   leaving a gap — the BENCH_r03–r05 lesson);
 - ``kernel_pricing`` — one bench_kernels.py row (measured ms + the
   bytes-model GB/s that is the higher-is-better ``value``);
-- ``attachment_probe`` — one tpu_watch probe outcome, so "attachment
+- ``attachment_probe`` — one device-probe outcome, so "attachment
   weather" has a first-class record stream;
 - ``serve_bench`` — one bench_serve.py ladder rung (ISSUE 12): QPS/chip
   as the higher-is-better ``value`` with p50/p99 request latency

@@ -23,6 +23,24 @@ class PallasUnavailable(ValueError):
     """
 
 
+def pallas_interpret() -> bool:
+    """THE interpret-mode decision for every Pallas kernel in the repo:
+    ``cpu`` interprets (tests, dry runs), ``tpu`` compiles through
+    Mosaic, and any other platform raises — a kernel that silently
+    interpreted on an accelerator nobody named would be timed as if it
+    were the real thing."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise PallasUnavailable(
+        f"Pallas kernels here compile for 'tpu' and interpret on 'cpu'; "
+        f"the default backend is {platform!r}, which is neither")
+
+
 from fm_spark_tpu.ops.fm import (  # noqa: F401,E402
     fm_scores,
     fm_partial_terms,

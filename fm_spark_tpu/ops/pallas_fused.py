@@ -47,8 +47,8 @@ this module never ``assert``s — every backend/shape constraint raises
 :class:`fm_spark_tpu.ops.PallasUnavailable`, and the build-time
 ``*_supported`` probes let the ``fused_embed='auto'`` lever degrade to
 the XLA path instead of dying on an attachment without a working Pallas
-lowering. Off-TPU backends run every kernel in interpret mode
-(correctness + CI; the on-chip A/B is the bench sweep's job).
+lowering. Interpret or compiled is :func:`fm_spark_tpu.ops.
+pallas_interpret`'s one decision (cpu interprets, tpu compiles).
 """
 
 from __future__ import annotations
@@ -60,7 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fm_spark_tpu.ops import PallasUnavailable
+from fm_spark_tpu.ops import PallasUnavailable, pallas_interpret, vmem
 
 # Forward gather tile: rows per grid program = DMA queue depth
 # (pallas_fm._TILE's measured sweet spot).
@@ -70,49 +70,13 @@ _TILE_FWD = 256
 # decomposition, window alignment, and one-hot matmul shapes.
 _TILE_BWD = 512
 # FFM interaction tile: the [T, F, F·k] block is the VMEM budget driver
-# (avazu shape F=23, k=16 fp32 → 4.3MB in + 4.3MB out at T=128).
+# (avazu shape F=23, k=16 fp32 → 4.7MB per lane-padded buffer at T=128).
 _TILE_FFM = 128
 
 _LANE = 128                   # Mosaic row-DMA lane alignment (pallas_fm)
 _SMEM_ID_LIMIT = 64 * 1024    # scalar-prefetched int32 ids that fit SMEM
-# Combined budget for the backward's two resident blocks (fp32 totals +
-# storage-dtype urows, both [cap+T+8, w]) plus streaming tiles.
-_BWD_VMEM_BUDGET = 14 * 1024 * 1024
-# Budget for the FFM tile pair (rows in + dvs out).
-_FFM_VMEM_BUDGET = 12 * 1024 * 1024
-
-
-def default_interpret() -> bool:
-    """Kernels run compiled on TPU, interpreted everywhere else."""
-    return jax.default_backend() != "tpu"
-
-
-_PROBE: dict[str, str | None] = {}
-
-
-def pallas_probe(backend: str | None = None) -> str | None:
-    """None if a trivial Pallas kernel COMPILES on ``backend`` (default:
-    the current one); otherwise the failure reason, cached per backend.
-    Non-TPU backends always probe available — they run interpret mode,
-    which needs no Mosaic lowering."""
-    backend = backend or jax.default_backend()
-    if backend != "tpu":
-        return None
-    if backend not in _PROBE:
-        try:
-            def _k(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
-
-            fn = pl.pallas_call(
-                _k, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32)
-            )
-            jax.jit(fn).lower(
-                jax.ShapeDtypeStruct((8, 128), jnp.float32)
-            ).compile()
-            _PROBE[backend] = None
-        except Exception as e:  # noqa: BLE001 — the probe's whole job
-            _PROBE[backend] = f"{type(e).__name__}: {str(e)[:200]}"
-    return _PROBE[backend]
+# fp32 contract precision for the one-hot matmuls (see pallas_segsum).
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -121,13 +85,10 @@ def pallas_probe(backend: str | None = None) -> str | None:
 
 
 def fm_fwd_supported(batch: int, width: int) -> str | None:
-    """Reason the fused forward cannot run COMPILED at this shape on the
-    current backend, or None. Interpret mode (non-TPU) is unrestricted."""
-    if jax.default_backend() != "tpu":
+    """Reason the fused forward cannot run COMPILED at this shape, or
+    None. Interpret mode is unrestricted."""
+    if pallas_interpret():
         return None
-    reason = pallas_probe()
-    if reason:
-        return f"Pallas probe failed: {reason}"
     if width % _LANE:
         return (f"table width {width} is not a multiple of {_LANE} "
                 "(Mosaic row-DMA lane alignment); pad the table width")
@@ -138,52 +99,97 @@ def fm_fwd_supported(batch: int, width: int) -> str | None:
     return None
 
 
+def _bwd_vmem_limit(cap: int, width: int, store_bytes: int,
+                    cd_bytes: int = 4) -> int:
+    """``vmem_limit_bytes`` of the fused backward: the two resident
+    [cap+T+8, w] blocks (fp32 totals, storage-dtype urows — single-
+    buffered trivial windows), the streamed tiles, and the per-tile
+    temporaries (one-hot and its transpose, expanded rows, gradient,
+    totals). Raises PallasUnavailable over budget."""
+    t = _TILE_BWD
+    return vmem.limit_for(
+        vmem.buffer_bytes((cap + t + 8, width))
+        + vmem.buffer_bytes((cap + t + 8, width), store_bytes)
+        + vmem.buffer_bytes((t, width), cd_bytes, buffers=2)    # s1s
+        + vmem.buffer_bytes((4, t), buffers=2)                  # coef
+        + vmem.buffer_bytes((1, t), buffers=2)                  # seg
+        + 4 * vmem.buffer_bytes((t + 8, t))
+        + 6 * vmem.buffer_bytes((t + 8, width)),
+        f"resident totals+urows [(cap+{t + 8}), {width}] (lower "
+        "compact_cap or use the XLA path)")
+
+
 def fm_bwd_supported(cap: int, width: int,
                      store_bytes: int = 4) -> str | None:
     """Reason the fused backward cannot serve (cap, width) with a
     ``store_bytes``-wide storage dtype, or None. The VMEM-residency
     budget applies on EVERY backend (interpret included) — it is the
-    design's hard envelope, same contract as pallas_segsum."""
-    t = _TILE_BWD
-    need = (cap + t + 8) * width * (4 + store_bytes)
-    if need > _BWD_VMEM_BUDGET:
-        return (f"resident totals+urows [(cap+{t + 8}), {width}] = "
-                f"{need / 1e6:.1f}MB exceeds the "
-                f"{_BWD_VMEM_BUDGET // 2**20}MB VMEM budget; lower "
-                "compact_cap or use the XLA path")
-    if jax.default_backend() == "tpu":
-        reason = pallas_probe()
-        if reason:
-            return f"Pallas probe failed: {reason}"
-        # No lane-alignment requirement on ``width``: the backward uses
-        # only blocked specs whose trailing block dims equal the array's
-        # (the segtotal_pallas pattern, which compiled and MEASURED at
-        # w=65 on chip, round 5) — the _LANE rule is the row-DMA
-        # gather's constraint, and this kernel does no row DMA.
+    design's hard envelope, same contract as pallas_segsum. No
+    lane-alignment rule on ``width``: the kernel uses only blocked specs
+    whose trailing block dims equal the array's and does no row DMA."""
+    try:
+        _bwd_vmem_limit(cap, width, store_bytes)
+    except PallasUnavailable as e:
+        return str(e)
     return None
+
+
+def _ffm_vmem_limit(num_fields: int, rank: int, cd_bytes: int) -> int:
+    """``vmem_limit_bytes`` of the sel-blocked FFM kernels: the
+    [T, F, F·k] rows tile in and the dvs tile out (both streamed, so
+    double-buffered), and one [T, F, k] sel/selT/dsel triple per owner
+    field. Raises PallasUnavailable over budget."""
+    t = _TILE_FFM
+    tile = (t, num_fields, num_fields * rank)
+    return vmem.limit_for(
+        vmem.buffer_bytes(tile, cd_bytes, buffers=4)
+        + vmem.buffer_bytes(tile, cd_bytes)             # the loaded R
+        + 3 * vmem.buffer_bytes((t, num_fields, rank), cd_bytes),
+        f"sel tile pair [{t}, {num_fields}, {num_fields}·{rank}]")
+
+
+_MOSAIC_VERDICTS: dict = {}
+
+
+def _mosaic_refusal(key, fn, *avals) -> str | None:
+    """What the compiler says to ``fn(*avals)`` on the TPU — its words
+    if it refuses, None if it compiles — asked once per ``key``. In
+    interpret mode there is nothing to compile, so nothing to refuse."""
+    if pallas_interpret():
+        return None
+    if key not in _MOSAIC_VERDICTS:
+        try:
+            jax.jit(fn).lower(*avals).compile()
+            _MOSAIC_VERDICTS[key] = None
+        except Exception as e:  # noqa: BLE001 — the verdict IS the result
+            words = " ".join(f"{type(e).__name__}: {e}".split())
+            _MOSAIC_VERDICTS[key] = words[:400]
+    return _MOSAIC_VERDICTS[key]
 
 
 def ffm_sel_supported(num_fields: int, rank: int,
                       cd_bytes: int = 4) -> str | None:
     """Reason the Pallas sel-blocked FFM kernels cannot serve this
-    (F, k, compute-dtype) shape, or None."""
-    t = _TILE_FFM
-    need = 2 * t * num_fields * num_fields * rank * cd_bytes
-    if need > _FFM_VMEM_BUDGET:
-        return (f"sel tile pair [{t}, {num_fields}, {num_fields}·{rank}]"
-                f" = {need / 1e6:.1f}MB exceeds the "
-                f"{_FFM_VMEM_BUDGET // 2**20}MB VMEM budget")
-    if jax.default_backend() == "tpu":
-        reason = pallas_probe()
-        if reason:
-            return f"Pallas probe failed: {reason}"
-        # Like the fused backward, the FFM kernels use only blocked
-        # specs whose trailing block dims equal the array's, so no
-        # static F·k lane-alignment reject here — if Mosaic still
-        # refuses an exotic shape at compile time, the sweep's
-        # per-variant guard logs the skip and the 'auto' lever's XLA
-        # fallback covers training.
-    return None
+    (F, k, compute-dtype) shape, or None. On the TPU the last word is
+    the compiler's: both kernels are compiled at one tile, because
+    Mosaic (libtpu 0.0.34) refuses their gather-style indexing and the
+    diagonal's ``.at[].set`` scatter whatever the shape, and only asking
+    keeps this probe right when that changes."""
+    try:
+        _ffm_vmem_limit(num_fields, rank, cd_bytes)
+    except PallasUnavailable as e:
+        return str(e)
+    cd = {4: jnp.float32, 2: jnp.bfloat16}[cd_bytes]
+    sds = jax.ShapeDtypeStruct
+    rows = sds((_TILE_FFM, num_fields, num_fields * rank), cd)
+    vals = sds((_TILE_FFM, num_fields), cd)
+
+    def both(r, v, ds):
+        return ffm_sel_scores(r, v), ffm_sel_bwd(r, v, ds)
+
+    reason = _mosaic_refusal(("ffm_sel", num_fields, rank, cd_bytes), both,
+                             rows, vals, sds((_TILE_FFM,), cd))
+    return f"Mosaic refuses the kernels: {reason}" if reason else None
 
 
 # --------------------------------------------------------------------------
@@ -269,7 +275,7 @@ def fm_fused_scores(tables, ids, vals, *, use_linear: bool = True,
     fp32 scores agree to ULP-level tolerance, not bitwise
     (tests/test_pallas_fused.py pins atol=1e-5 at unit-scale operands).
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = pallas_interpret() if interpret is None else interpret
     b, num_fields = ids.shape
     w = tables[0].shape[1]
     if not interpret:
@@ -317,7 +323,7 @@ def _bwd_kernel(first_ref, seg_ref, coef_ref, s1s_ref, neglr_ref, rv_ref,
     # Window math mirrors pallas_segsum._kernel exactly (sublane-aligned
     # start, T+8 rows absorbing the offset) — the bit-exactness anchor.
     first = first_ref[i]
-    first_a = (first // 8) * 8
+    first_a = pl.multiple_of((first // 8) * 8, 8)
     seg = seg_ref[0, 0, :]                                  # [T] int32
     local = seg - first_a
     onehot = (
@@ -327,16 +333,22 @@ def _bwd_kernel(first_ref, seg_ref, coef_ref, s1s_ref, neglr_ref, rv_ref,
     win = pl.ds(first_a, t + 8)
     cd = s1s_ref.dtype
     # Expanded rows re-derived from the RESIDENT urows block by the same
-    # one-hot (0/1 matmul == exact gather for finite rows): the [B, w]
-    # per-field row expansion never exists off-chip either.
+    # one-hot (0/1 matmul == exact gather for finite rows — at fp32
+    # contract precision; the MXU's default pass would round fp32 rows
+    # to bf16): the [B, w] per-field row expansion never exists off-chip
+    # either.
     rows = jnp.dot(
         jnp.swapaxes(onehot, 0, 1),
         urows_ref[win, :].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=_EXACT,
     ).astype(cd)                                            # [T, w]
-    ds = coef_ref[0, 0, :][:, None]
-    x = coef_ref[0, 1, :][:, None]
-    tch = coef_ref[0, 2, :][:, None]
+    # The per-lane coefficients ride as fp32 (exact for values already
+    # rounded to the compute dtype): Mosaic cannot turn a bf16 lane
+    # vector into a sublane column ("unsupported shape cast", v5e).
+    ds = coef_ref[0, 0, :][:, None].astype(cd)
+    x = coef_ref[0, 1, :][:, None].astype(cd)
+    tch = coef_ref[0, 2, :][:, None].astype(cd)
     colmask = jax.lax.broadcasted_iota(jnp.int32, (1, k + 1), 1) < k
     # The gfull_fused expression, verbatim (sparse._gfull_grads):
     #   g = ds·(s1 − mask·xv_full)·x  (+ rv·rows·touched)
@@ -347,7 +359,8 @@ def _bwd_kernel(first_ref, seg_ref, coef_ref, s1s_ref, neglr_ref, rv_ref,
         g = g + rv_ref[...] * rows * tch
     d = neglr_ref[0, 0] * g                                 # f32 deltas
     totals = jnp.dot(onehot, d.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)    # [T+8, w]
+                     preferred_element_type=jnp.float32,
+                     precision=_EXACT)                      # [T+8, w]
     out_ref[win, :] = out_ref[win, :] + totals
 
 
@@ -379,11 +392,10 @@ def fm_bwd_segment_totals(urows, s1s, ds_s, x_s, tch_s, seg_s, neg_lr,
     if w != k + 1:
         raise PallasUnavailable(
             f"fm_bwd_segment_totals: s1s width {w} != k+1 ({k + 1})")
-    reason = fm_bwd_supported(cap, w, jnp.dtype(urows.dtype).itemsize)
-    if reason:
-        raise PallasUnavailable(f"fm_bwd_segment_totals: {reason}")
     t = _TILE_BWD
     cd = s1s.dtype
+    vmem_limit = _bwd_vmem_limit(cap, w, jnp.dtype(urows.dtype).itemsize,
+                                 jnp.dtype(cd).itemsize)
     pad = (-b) % t
     if pad:
         s1s = jnp.pad(s1s, ((0, pad), (0, 0)))
@@ -399,7 +411,7 @@ def fm_bwd_segment_totals(urows, s1s, ds_s, x_s, tch_s, seg_s, neg_lr,
     coef = jnp.stack(
         [ds_s.astype(cd), x_s.astype(cd), tch_s.astype(cd),
          jnp.zeros_like(x_s, cd)], axis=0,
-    ).reshape(4, nb, t).transpose(1, 0, 2)         # [nb, 4, t]
+    ).astype(jnp.float32).reshape(4, nb, t).transpose(1, 0, 2)  # [nb, 4, t]
     neglr = jnp.asarray(neg_lr, jnp.float32).reshape(1, 1)
     use_rv = rv is not None
     rv_arr = (rv.astype(cd) if use_rv else jnp.zeros((w,), cd))[None, :]
@@ -428,6 +440,11 @@ def fm_bwd_segment_totals(urows, s1s, ds_s, x_s, tch_s, seg_s, neg_lr,
         functools.partial(_bwd_kernel, k=k, use_rv=use_rv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cap + t + 8, w), jnp.float32),
+        # "arbitrary" = sequential: every tile read-modify-writes the
+        # one resident accumulator.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(first, seg3d, coef, s1s, neglr, rv_arr, urows_pad)
     return out[:cap]
@@ -471,19 +488,19 @@ def _ffm_bwd_kernel(r_ref, x_ref, ds_ref, out_ref, *, num_fields, rank):
         ).reshape(t, F * kk)
 
 
-def _ffm_check(rows_stacked, interpret):
+def _ffm_check(rows_stacked):
+    """``(b, F, k, compiler_params)`` of one sel-blocked FFM call."""
     b, num_fields, fk = rows_stacked.shape
     rank = fk // num_fields
     if rank * num_fields != fk:
         raise PallasUnavailable(
             f"ffm_sel: packed width {fk} is not divisible by the field "
             f"count {num_fields}")
-    if not interpret:
-        reason = ffm_sel_supported(
-            num_fields, rank, jnp.dtype(rows_stacked.dtype).itemsize)
-        if reason:
-            raise PallasUnavailable(f"ffm_sel: {reason}")
-    return b, num_fields, rank
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=_ffm_vmem_limit(
+            num_fields, rank, jnp.dtype(rows_stacked.dtype).itemsize))
+    return b, num_fields, rank, params
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -493,7 +510,7 @@ def ffm_sel_scores(rows_stacked, vals, *, interpret: bool = False):
     ``scores = 0.5·acc`` (the caller applies the ½, mirroring the
     sel_blocked body). The [B, F, F, k] sel tensor exists only as one
     [T, F, k] pair per owner field per tile."""
-    b, num_fields, rank = _ffm_check(rows_stacked, interpret)
+    b, num_fields, rank, params = _ffm_check(rows_stacked)
     t = _TILE_FFM
     pad = (-b) % t
     if pad:
@@ -511,7 +528,8 @@ def ffm_sel_scores(rows_stacked, vals, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((t, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_stacked.shape[0], 1),
-                                       vals.dtype),
+                                       rows_stacked.dtype),
+        compiler_params=params,
         interpret=interpret,
     )(rows_stacked, vals.astype(rows_stacked.dtype))
     return out[:b, 0]
@@ -523,7 +541,7 @@ def ffm_sel_bwd(rows_stacked, vals, dscores, *, interpret: bool = False):
     sel-blocked backward — ``dsel`` is tile-resident; only the gradient
     set the scatter consumes is written (the same contract as the XLA
     sel_blocked body, now guaranteed rather than fusion-dependent)."""
-    b, num_fields, rank = _ffm_check(rows_stacked, interpret)
+    b, num_fields, rank, params = _ffm_check(rows_stacked)
     t = _TILE_FFM
     pad = (-b) % t
     if pad:
@@ -544,6 +562,7 @@ def ffm_sel_bwd(rows_stacked, vals, dscores, *, interpret: bool = False):
         out_specs=pl.BlockSpec((t, num_fields, fk), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (rows_stacked.shape[0], num_fields, fk), rows_stacked.dtype),
+        compiler_params=params,
         interpret=interpret,
     )(rows_stacked, vals.astype(rows_stacked.dtype),
       dscores.astype(rows_stacked.dtype)[:, None])

@@ -13,7 +13,8 @@ Why the round-4 sketch rejection ("per-tile variable segment counts
 force overlapping output windows or a disjoint [B, w] partials buffer")
 does not hold: a TPU Pallas grid is SEQUENTIAL and the whole [cap+T, w]
 output block stays VMEM-resident under a constant index map (cap=16384,
-w=65 fp32 = 4.3MB), so each tile can read-modify-write the dynamic
+w=65 fp32 = 8.65MB once lane-padded to 128), so each tile can
+read-modify-write the dynamic
 window ``out[first_seg(tile) : +T]`` — boundary segments spanning tiles
 accumulate correctly through the resident block, no clobbering, no
 partials buffer. Within a tile the totals are ONE one-hot matmul on the
@@ -22,11 +23,10 @@ VPU never loops lanes.
 
 Traffic: read B·w (sorted deltas) + write cap·w — versus the XLA
 prefix's read B·w + write B·w + read-at-boundaries. Upside ≈ the
-remaining half of the blocked-prefix cost (PERF.md bounds it from the
-``cumsum`` probe rows at ~25-30ms/39 fields on the degraded
-attachment). Behind ``TrainConfig.segtotal_pallas``; interpret-mode
-semantics pinned in tests/test_pallas_segsum.py; the on-chip A/B prices
-it (bench.py sweep).
+remaining half of the blocked-prefix cost. Behind
+``TrainConfig.segtotal_pallas``; interpret-mode semantics pinned in
+tests/test_pallas_segsum.py; ``chip_smoke.py`` compiles it at config 3's
+shape against its ``jax.numpy`` reference.
 
 Overflow semantics (device-built aux): lanes whose segment index
 reached past ``cap`` are clamped to the trash row ``cap`` outside the
@@ -44,12 +44,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fm_spark_tpu.ops import PallasUnavailable
+from fm_spark_tpu.ops import vmem
 
 # Lanes per grid step. 512 makes the one-hot matmul a [512, 512]·[512, w]
 # MXU op and bounds the per-tile distinct-segment count by construction
 # (<= T), so the dynamic output window never needs more than T rows.
 _TILE = 512
+# The MXU's default pass rounds fp32 operands to bf16 — a 2e-3 relative
+# error on every delta (measured on the v5e, PR 21), where the kernel
+# declares fp32 sums. fp32 contract precision keeps the one-hot matmul
+# the exact gather/sum it stands for.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(first_ref, seg_ref, x_ref, out_ref):
@@ -60,11 +65,11 @@ def _kernel(first_ref, seg_ref, x_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     # The store window starts at first ROUNDED DOWN to a multiple of 8:
-    # Mosaic requires (or strongly prefers) sublane-aligned dynamic
-    # slices, and the one-hot just grows 8 rows to absorb the offset —
-    # local indices land in [0, T+8) instead of [0, T).
+    # Mosaic requires sublane-aligned dynamic slices (and must be TOLD
+    # the start is aligned), and the one-hot just grows 8 rows to absorb
+    # the offset — local indices land in [0, T+8) instead of [0, T).
     first = first_ref[i]
-    first_a = (first // 8) * 8
+    first_a = pl.multiple_of((first // 8) * 8, 8)
     seg = seg_ref[0, 0, :]                                 # [T] int32
     local = seg - first_a                                  # [0, T+8) valid
     onehot = (
@@ -72,7 +77,8 @@ def _kernel(first_ref, seg_ref, x_ref, out_ref):
         == jax.lax.broadcasted_iota(jnp.int32, (_TILE + 8, _TILE), 0)
     ).astype(jnp.float32)                                  # [T+8(seg), T(lane)]
     totals = jnp.dot(onehot, x_ref[...],
-                     preferred_element_type=jnp.float32)   # [T+8, w]
+                     preferred_element_type=jnp.float32,
+                     precision=_EXACT)                     # [T+8, w]
     win = pl.ds(first_a, _TILE + 8)
     out_ref[win, :] = out_ref[win, :] + totals
 
@@ -101,23 +107,21 @@ def segment_totals(sdelta: jax.Array, seg_sorted: jax.Array, cap: int,
     """
     b, w = sdelta.shape
     t = _TILE
-    # The whole [cap+T, w] fp32 accumulator stays VMEM-resident (that
+    # The whole [cap+T+8, w] fp32 accumulator stays VMEM-resident (that
     # residency IS the design — it's what makes the dynamic-window
     # read-modify-write race-free and partials-buffer-free), so its
-    # size is a hard budget: the FM headline shape (cap 16384, w 65)
-    # is 4.4MB; an FFM-width row (w = F·k+1 = 369 at avazu shapes)
-    # would be ~25MB and fail at Mosaic compile time. Reject with an
-    # actionable message instead.
-    out_bytes = (cap + t + 8) * w * 4
-    budget = 8 * 1024 * 1024  # leave room for the tile + one-hot blocks
-    if out_bytes > budget:
-        raise PallasUnavailable(
-            f"segtotal_pallas accumulator [(cap+{t + 8}), {w}] fp32 = "
-            f"{out_bytes / 1e6:.1f}MB exceeds the {budget // 2**20}MB "
-            "VMEM budget (the kernel keeps the whole output resident); "
-            "lower compact_cap or use the blocked-prefix path (drop "
-            "--segtotal-pallas) for wide rows (FFM)"
-        )
+    # lane-padded size is a hard budget: 8.65MB at the FM headline shape
+    # (cap 16384, w 65), single-buffered as a trivial window. Refuse at
+    # build time what the chip's VMEM cannot hold.
+    vmem_limit = vmem.limit_for(
+        vmem.buffer_bytes((cap + t + 8, w))             # resident totals
+        + vmem.buffer_bytes((t, w), buffers=2)          # streamed deltas
+        + vmem.buffer_bytes((1, t), buffers=2)          # segment ids
+        + 3 * vmem.buffer_bytes((t + 8, t))             # iota/compare/one-hot
+        + 3 * vmem.buffer_bytes((t + 8, w)),            # totals + window RMW
+        f"segtotal_pallas accumulator [(cap+{t + 8}), {w}] fp32 (the "
+        "kernel keeps the whole output resident; lower compact_cap or "
+        "drop --segtotal-pallas for wide rows)")
     pad = (-b) % t
     if pad:
         sdelta = jnp.pad(sdelta, ((0, pad), (0, 0)))
@@ -148,6 +152,11 @@ def segment_totals(sdelta: jax.Array, seg_sorted: jax.Array, cap: int,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cap + t + 8, w), jnp.float32),
+        # "arbitrary" = sequential: every tile read-modify-writes the
+        # one resident accumulator.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(first, seg3d, sdelta)
     return out[:cap]

@@ -356,8 +356,8 @@ def compact_gather(table, useg, col: bool = False):
 
 # Block size of the two-level prefix in compact_apply. Measured
 # (bench_micro `cumsum`, round 3): a plain [131072, 65] fp32 jnp.cumsum
-# costs 73ms/39-field on this attachment while the blocked two-level
-# form costs 53ms — and compact_apply never needs the full prefix
+# cost 73ms/39-field on that round's attachment while the blocked two-level
+# form cost 53ms — and compact_apply never needs the full prefix
 # ARRAY, only its values at the 2·cap segment boundaries, so keeping
 # the block-local prefix and block offsets SEPARATE (gathered at the
 # boundary positions) also skips the final full-buffer add pass the
@@ -382,20 +382,17 @@ def compact_apply(table, delta, caux, mode, key, urows, col: bool = False,
     the segment sums with the Pallas sorted-run kernel
     (:mod:`fm_spark_tpu.ops.pallas_segsum`) instead of the blocked
     prefix — one streaming read, no prefix materialization; same values
-    up to fp32 reassociation (tests/test_pallas_segsum.py). Interpret
-    mode off-TPU; the on-chip A/B prices it."""
+    up to fp32 reassociation (tests/test_pallas_segsum.py)."""
     useg, segstart, segend, order, inv = caux
     cap = useg.shape[-1]
     _check_sentinel_range(table.shape[1] if col else table.shape[0], cap)
     sdelta = delta[order].astype(jnp.float32)
     b, w = sdelta.shape
     if segtotal_pallas:
-        from fm_spark_tpu.ops import pallas_segsum
+        from fm_spark_tpu.ops import pallas_interpret, pallas_segsum
 
         segsum = pallas_segsum.segment_totals(
-            sdelta, inv[order], cap,
-            interpret=jax.default_backend() != "tpu",
-        )
+            sdelta, inv[order], cap, interpret=pallas_interpret())
     else:
         del inv
         blk = _CSUM_BLOCK
@@ -492,11 +489,11 @@ def _pallas_pad(x: jax.Array, mult: int, fill=0):
 
 def pallas_gather(table: jax.Array, ids: jax.Array) -> jax.Array:
     """Pipelined-DMA row gather (ops/pallas_fm.py), padding ids to the
-    kernel's tile multiple; interpret mode off-TPU."""
-    from fm_spark_tpu.ops import pallas_fm
+    kernel's tile multiple."""
+    from fm_spark_tpu.ops import pallas_fm, pallas_interpret
 
     b = ids.shape[0]
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     # Clamp pad/sentinel ids in-range: gather is side-effect free and the
     # 2-D sharded path masks non-owned lanes itself.
     safe = jnp.clip(_pallas_pad(ids, pallas_fm._TILE), 0,
@@ -513,12 +510,12 @@ def _pallas_dedup_add(table, ids, delta):
     is 'scatter_add' up to reassociation, but for bf16 tables it is
     systematically MORE accurate than XLA's round-per-duplicate-write
     scatter (closer to 'dedup', which shares the segment-sum)."""
-    from fm_spark_tpu.ops import pallas_fm
+    from fm_spark_tpu.ops import pallas_fm, pallas_interpret
 
     n = table.shape[0]
     sid, summed, run_start, _ = _dedup(ids, delta)
     valid = run_start & (sid >= 0) & (sid < n)
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     return pallas_fm.update_rows_add(
         table,
         _pallas_pad(jnp.where(valid, sid, 0), pallas_fm._TILE),

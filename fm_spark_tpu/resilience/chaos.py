@@ -2013,7 +2013,6 @@ def build_fleet_stack(cfg: FleetDrillConfig, base_dir: str) -> dict:
         work_dir=os.path.join(base_dir, "work"), journal=journal,
         buckets=cfg.buckets, latency_budget_ms=cfg.latency_budget_ms,
         reload_poll_s=cfg.reload_poll_s,
-        compile_cache_dir=os.path.join(base_dir, "compile_cache"),
         spawn_timeout_s=cfg.spawn_timeout_s,
         autoscaler=autoscaler)
     fleet.start()

@@ -1,7 +1,7 @@
 """The retry/timeout/backoff state machine for a flaky device attachment.
 
-What used to be ad-hoc (bench.py's hand-rolled parent retry loop,
-tpu_watch.sh's inlined bash backoff) is here one tested object:
+What used to be ad-hoc (bench.py's hand-rolled parent retry loop, a
+bash poll loop's inlined backoff) is here one tested object:
 
 - **Bounded exponential backoff + deterministic jitter**
   (:class:`BackoffPolicy`): delay doubles per consecutive failure, is

@@ -64,12 +64,35 @@ from fm_spark_tpu.utils.logging import EventLog
 TransportFailure = netfaults.TransportFailure
 
 __all__ = ["ConnectionPool", "Fleet", "HostSpec", "ReplicaAddr",
-           "ReplicaHandle", "TransportFailure", "replica_main"]
+           "ReplicaHandle", "TransportFailure", "refuse_on_tpu",
+           "replica_main"]
 
 #: Parent-side health cadence and thresholds.
 DEFAULT_HEALTH_POLL_S = 0.25
 SUSPECT_AFTER_FAILURES = 2
 SPAWN_TIMEOUT_S = 120.0
+
+
+def refuse_on_tpu(launcher: str) -> None:
+    """The local fleet launchers' guard: on a TPU, exit with one message
+    instead of crash-looping replicas. A chip belongs to one process at
+    a time, and nothing here assigns one chip to one replica: every
+    replica process opens EVERY local chip, so the second fails or
+    hangs in backend init (as does the first, when the launcher itself
+    holds the chip). Asking which platform this is takes the chip, which
+    is fine for a process about to exit."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        raise SystemExit(
+            f"{launcher}: refused on {dev.device_kind} — a TPU chip "
+            "belongs to one process at a time, and fleet replicas are "
+            "separate processes that each open every local chip, so "
+            "they cannot start beside each other (or beside a launcher "
+            "that holds the chip). Serve from one process (cli serve "
+            "without --fleet), or run the fleet with JAX_PLATFORMS=cpu; "
+            "one chip per replica is not implemented yet.")
 
 
 def _json_body(doc) -> bytes:
@@ -340,7 +363,6 @@ class Fleet:
                  work_dir: str, journal=None,
                  buckets: str = "1,4", latency_budget_ms: float = 2.0,
                  reload_poll_s: float = 0.2,
-                 compile_cache_dir: "str | None" = None,
                  health_poll_s: float = DEFAULT_HEALTH_POLL_S,
                  spawn_timeout_s: float = SPAWN_TIMEOUT_S,
                  replica_env: "dict | None" = None,
@@ -357,7 +379,6 @@ class Fleet:
         self.buckets = buckets
         self.latency_budget_ms = float(latency_budget_ms)
         self.reload_poll_s = float(reload_poll_s)
-        self.compile_cache_dir = compile_cache_dir
         self.health_poll_s = float(health_poll_s)
         self.spawn_timeout_s = float(spawn_timeout_s)
         self.replica_env = dict(replica_env or {})
@@ -405,7 +426,14 @@ class Fleet:
             daemon=True)
         self._monitor.start()
         if wait_ready:
-            self.wait_ready()
+            try:
+                self.wait_ready()
+            except BaseException:
+                # A fleet that never became ready must not leave its
+                # replicas behind (seen on the v5e: the orphan kept the
+                # chip after the launcher had exited).
+                self.close()
+                raise
         return self
 
     def wait_ready(self, min_ready: "int | None" = None,
@@ -452,8 +480,6 @@ class Fleet:
         if self.chain_dir:
             cmd += ["--chain-dir", self.chain_dir,
                     "--reload-poll-s", str(self.reload_poll_s)]
-        if self.compile_cache_dir:
-            cmd += ["--compile-cache", self.compile_cache_dir]
         if self.obs_root:
             cmd += ["--obs-dir", self.obs_root]
         env = dict(os.environ)
@@ -926,7 +952,6 @@ def replica_main(argv=None) -> int:
     ap.add_argument("--buckets", default="1,4")
     ap.add_argument("--latency-budget-ms", type=float, default=2.0)
     ap.add_argument("--journal", default=None)
-    ap.add_argument("--compile-cache", default=None)
     ap.add_argument("--nnz", type=int, default=None,
                     help="request width (default: spec.num_fields)")
     ap.add_argument("--obs-dir", default=None,
@@ -945,10 +970,9 @@ def replica_main(argv=None) -> int:
     from fm_spark_tpu.serve.reload import ReloadFollower
     from fm_spark_tpu.utils import compile_cache
 
-    if args.compile_cache:
-        compile_cache.enable(args.compile_cache)
-    else:
-        compile_cache.enable_from_env()
+    # Replicas inherit JAX_COMPILATION_CACHE_DIR (or the checkout's
+    # default) from the parent, so the fleet shares one warm cache.
+    compile_cache.enable()
 
     journal = (EventLog(args.journal) if args.journal else None)
 

@@ -396,13 +396,12 @@ class FMTrainer:
     """
 
     def __init__(self, spec, config: TrainConfig, n_chips: int = 1):
-        # Warm-start hook: FM_SPARK_COMPILE_CACHE=<dir|1> enables the
-        # persistent XLA compilation cache for any library user of the
-        # trainer (the CLI's --compile-cache flag reaches the same
-        # switch); a no-op when the env var is unset.
+        # Warm start for any library user of the trainer: the
+        # persistent XLA compilation cache is on without a flag
+        # (utils/compile_cache says where it lives).
         from fm_spark_tpu.utils import compile_cache
 
-        compile_cache.enable_from_env()
+        compile_cache.enable()
         self.spec = spec
         self.config = config
         self.optimizer = make_optimizer(config)

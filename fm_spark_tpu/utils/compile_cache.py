@@ -1,33 +1,33 @@
-"""Persistent XLA compilation cache: the warm-start fast path.
+"""Persistent XLA compilation cache: on by default, placed from outside.
 
-Why this exists (ISSUE 1 / VERDICT r5 "What's weak" #1): on this
-session's flaky TPU attachment, backend init + the first XLA compile of
-the fused train step costs minutes — longer than a flapping attachment
-stays healthy — so BENCH_r03–r05 all timed out with null artifacts even
-though the step itself runs at 1.14× the target. The step *programs*
-are deterministic functions of (spec, TrainConfig, batch shape), so a
-SECOND process should never pay XLA again: jax's persistent compilation
-cache serializes every compiled executable to disk keyed by the lowered
-HLO + compile options + platform version, and a warm process
-deserializes in milliseconds instead of recompiling.
+The step programs are deterministic functions of (spec, TrainConfig,
+batch shape), so a SECOND process should never pay XLA again: jax's
+persistent compilation cache serializes every compiled executable to
+disk keyed by the lowered HLO + compile options + platform version, and
+a warm process deserializes instead of recompiling. On the TPU the first
+compile of each fused step is the larger part of a cold run, so every
+entry point (``cli train`` / ``serve`` / ``predict``, ``FMTrainer``,
+``bench.py``'s child, fleet replicas, ``chip_smoke.py``) calls
+:func:`enable` without being asked.
 
-This module is the repo's single switch for that cache:
+Where the cache lives:
 
-- :func:`enable` points jax at a repo-local cache directory and drops
-  the min-size/min-compile-time thresholds to zero so EVERY executable
-  is cached (the defaults skip sub-second compiles — exactly the wrong
-  call for a bench that must survive short attachment windows, and for
-  the CPU tests that pin this behavior).
-- :func:`enable_from_env` is the zero-flag wiring for production loops:
-  ``FM_SPARK_COMPILE_CACHE=<dir>`` (or ``=1`` for the default repo-local
-  dir) turns the cache on without touching any call site.
-- :func:`cache_stats` exposes hit/miss counts (via jax's monitoring
-  events) plus on-disk entry count and bytes, so tests can assert the
-  warm-start contract — "a warm process performs ZERO fresh XLA
-  compilations" — instead of trusting wall-clock.
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads that variable itself at
+  import, and this module sets NO directory in code — whoever launched
+  the process (a test, a fleet parent, the machine's image) placed it.
+- unset: ``<checkout>/.jax_compile_cache`` (:data:`DEFAULT_DIR`). A
+  fixed path, never a temporary, pid- or time-named one: a cache that
+  moves between runs never hits.
 
-Call :func:`enable` BEFORE the first jit compile; enabling later still
-covers all subsequent compiles (earlier ones are simply not cached).
+:func:`enable` also drops jax's min-size / min-compile-time thresholds
+to zero so EVERY executable is cached (the defaults skip sub-second
+compiles, and the warm-start contract is "a warm process performs ZERO
+fresh XLA compilations"), and :func:`cache_stats` exposes hit/miss
+counts (jax's monitoring events) plus the on-disk footprint so tests,
+``PredictEngine.warmup`` and the smoke assert that contract instead of
+trusting wall-clock. jax's own ``JAX_ENABLE_COMPILATION_CACHE=false``
+still turns the cache off; nothing here overrides it.
+
 The cache composes with the AOT entries (:func:`fm_spark_tpu.sparse.
 precompile_field_sparse_step` and friends): an AOT ``.compile()``
 populates the same cache the later jit dispatch reads.
@@ -39,23 +39,19 @@ import os
 import threading
 
 __all__ = [
-    "DEFAULT_ENV",
+    "DEFAULT_DIR",
+    "ENV",
     "cache_stats",
-    "default_cache_dir",
     "enable",
-    "enable_from_env",
     "is_enabled",
     "reset_stats",
 ]
 
-#: Environment switch read by :func:`enable_from_env`: a directory path,
-#: or ``1``/``true`` for :func:`default_cache_dir`.
-DEFAULT_ENV = "FM_SPARK_COMPILE_CACHE"
+#: jax's own variable; read by jax at import, never written here.
+ENV = "JAX_COMPILATION_CACHE_DIR"
 
 # Repo root = two levels above the package (utils/ -> fm_spark_tpu/ ->
-# repo). Repo-local by design: the cache travels with the checkout, so
-# tpu_watch.sh's CPU-side pre-warm and a later on-chip bench see the
-# same directory without any coordination.
+# repo): the default cache travels with the checkout.
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -71,20 +67,6 @@ _lock = threading.Lock()
 _state = {"dir": None, "hits": 0, "requests": 0, "listener": False}
 
 
-def default_cache_dir() -> str:
-    """The cache directory used when none is given: ``$FM_SPARK_COMPILE_
-    CACHE`` if it names a path, else ``<repo>/.jax_compile_cache``.
-    Boolean spellings (on OR off) are switches, never paths — an
-    operator who exported the falsy form and then passes an explicit
-    ``--compile-cache`` flag gets the repo-local default, not a
-    directory literally named ``0``."""
-    env = os.environ.get(DEFAULT_ENV, "").strip()
-    if env and env.lower() not in ("1", "true", "yes", "on",
-                                   "0", "false", "no", "off"):
-        return env
-    return DEFAULT_DIR
-
-
 def _on_event(event: str, **_kw) -> None:
     if event == _HIT_EVENT:
         with _lock:
@@ -94,22 +76,33 @@ def _on_event(event: str, **_kw) -> None:
             _state["requests"] += 1
 
 
-def enable(cache_dir: str | None = None) -> str:
-    """Enable jax's persistent compilation cache at ``cache_dir``
-    (default: :func:`default_cache_dir`). Idempotent; returns the
-    resolved absolute path. Safe to call before OR after backend init —
-    only compiles issued after the call are covered."""
-    path = os.path.abspath(cache_dir or default_cache_dir())
+def enable() -> str:
+    """Turn the persistent compilation cache on and return its
+    directory (see the module docstring for where that is). Idempotent;
+    safe before OR after backend init — only compiles issued after the
+    first call are covered."""
     import jax
+    from jax._src import compilation_cache, monitoring
 
-    jax.config.update("jax_compilation_cache_dir", path)
+    placed = bool(os.environ.get(ENV, "").strip())
+    path = jax.config.jax_compilation_cache_dir if placed else DEFAULT_DIR
+    if not path:
+        raise RuntimeError(
+            f"{ENV} was set after jax was imported, so jax never read "
+            "it and would keep no cache; set it in the environment the "
+            "process starts with")
+    path = os.path.abspath(path)
+    with _lock:
+        if _state["dir"] == path:
+            return path
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", path)
     # Cache EVERYTHING: the default thresholds skip small/fast compiles,
     # but warm-start correctness (zero fresh compilations) needs every
     # executable the step dispatch will ask for — including the tiny
     # device_put/convert helpers that precede the fused step.
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_enable_compilation_cache", True)
     os.makedirs(path, exist_ok=True)
     # Provenance breadcrumb through the durable seam (ISSUE 20, the
     # ``cache`` path class): which process last enabled the cache, and
@@ -120,60 +113,25 @@ def enable(cache_dir: str | None = None) -> str:
 
     durable.atomic_write_json(
         os.path.join(path, "cache_meta.json"),
-        {"dir": path, "pid": os.getpid(),
-         "jax_version": getattr(jax, "__version__", None)},
+        {"dir": path, "pid": os.getpid(), "jax_version": jax.__version__},
         path_class="cache", best_effort=True)
-    try:
-        # jax latches "is the cache used?" at the FIRST compile of the
-        # process; a process that compiled anything before enable()
-        # (e.g. a training script that warmed up before opting in)
-        # would silently never write an entry. Resetting the latch
-        # makes enable() effective at any point; the file cache lazily
-        # re-initializes from the same directory on the next compile.
-        # Private API, best-effort — same policy as _install_listener.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # jax latches "is the cache used?" at the FIRST compile of the
+    # process; a process that compiled anything before enable() would
+    # silently never write an entry. Resetting the latch makes enable()
+    # effective at any point; the file cache lazily re-initializes from
+    # the configured directory on the next compile.
+    compilation_cache.reset_cache()
     with _lock:
         _state["dir"] = path
-    _install_listener()
+        listen = not _state["listener"]
+        _state["listener"] = True
+    if listen:
+        monitoring.register_event_listener(_on_event)
     return path
-
-
-def enable_from_env() -> str | None:
-    """Enable the cache iff ``FM_SPARK_COMPILE_CACHE`` is set (a path,
-    or ``1`` for the default dir; the conventional falsy spellings
-    ``0/false/no/off`` mean OFF, not "a directory named 0"); returns
-    the dir or None. The no-flag wiring: training loops call this so
-    an operator can warm-start any entry point without new CLI
-    plumbing."""
-    val = os.environ.get(DEFAULT_ENV, "").strip()
-    if not val or val.lower() in ("0", "false", "no", "off"):
-        return None
-    return enable()
 
 
 def is_enabled() -> bool:
     return _state["dir"] is not None
-
-
-def _install_listener() -> None:
-    """Register the monitoring listener once. Private-API use
-    (``jax._src.monitoring``) is deliberate and best-effort, same policy
-    as utils/cpuguard.py: if the module moves, hit/miss counters stay at
-    zero and :func:`cache_stats` still reports the on-disk truth."""
-    with _lock:
-        if _state["listener"]:
-            return
-        _state["listener"] = True
-    try:
-        from jax._src import monitoring
-
-        monitoring.register_event_listener(_on_event)
-    except Exception:
-        pass
 
 
 def reset_stats() -> None:
@@ -194,7 +152,8 @@ def cache_stats() -> dict:
     ``misses`` = compile requests served by a fresh XLA compilation this
     process; the warm-start contract is ``misses == 0`` on a populated
     cache. ``entries`` counts serialized executables (the ``*-cache``
-    files of jax's LRU file cache; ``-atime`` bookkeeping is excluded).
+    files of jax's LRU file cache; its ``-atime`` bookkeeping and the
+    breadcrumb are not executables).
     """
     with _lock:
         d = _state["dir"]
@@ -204,7 +163,7 @@ def cache_stats() -> dict:
     if d and os.path.isdir(d):
         for root, _dirs, files in os.walk(d):
             for f in files:
-                if f.endswith("-atime"):
+                if not f.endswith("-cache"):
                     continue
                 entries += 1
                 try:

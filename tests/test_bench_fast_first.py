@@ -9,7 +9,7 @@ One subprocess covers the whole contract, kill included:
 2. a SIGTERM mid-sweep leaves that artifact intact and parseable — an
    interrupted run never reports null when any leg completed;
 3. the parent, having salvaged a result line, exits 0 (so callers
-   chained on success, e.g. tpu_watch's one-time queue, still advance).
+   chained on success still advance).
 
 Model ``fm_kaggle`` is the smallest registered shape (39 × 32768 × 33
 tables ≈ 170 MB fp32), and its default sweep has no Pallas legs — the
@@ -33,12 +33,12 @@ def test_fast_first_incremental_artifact_survives_sigterm(tmp_path):
     proc = subprocess.Popen(
         [sys.executable, BENCH, "--fast-first",
          "--model", "fm_kaggle", "--batch", "128", "--steps", "2",
-         "--compile-cache", str(tmp_path / "cc"),
          "--artifacts-dir", str(art),
          "--attempts", "1", "--attempt-timeout", "560",
          "--total-deadline", "580", "--init-timeout", "180"],
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                       "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")},
     )
     try:
         # Wait for the FIRST leg's keep-best artifact (the fast-first

@@ -69,10 +69,9 @@ def _health_events(art, model):
 
 def test_sweep_survives_init_hang_then_device_loss_and_resumes(tmp_path):
     art = tmp_path / "art"
-    cc = str(tmp_path / "cc")
     common = ["--fast-first", "--model", "fm_kaggle",
               "--batch", "128", "--steps", "2",
-              "--compile-cache", cc, "--artifacts-dir", str(art)]
+              "--artifacts-dir", str(art)]
 
     # Phase 1: child 1's backend init hangs (watchdog exits it rc=3),
     # the parent retries, child 2 loses the device on sweep leg 2 and
@@ -172,7 +171,8 @@ def test_sweep_survives_init_hang_then_device_loss_and_resumes(tmp_path):
 
     # Phase 2: --resume-sweep restart with a truncated artifact (as if
     # the window died after leg 1) runs ONLY the remaining legs, warm
-    # through the shared compile cache.
+    # through the compile cache both runs inherit (tests/conftest.py
+    # places one for the session).
     sweep_path = art / "sweep_fm_kaggle.jsonl"
     records = sweep_path.read_text().strip().splitlines()
     n_total = len(records)
@@ -396,8 +396,7 @@ def test_sigterm_mid_sweep_salvages_with_faults_active(tmp_path):
     art = tmp_path / "art"
     proc = _run_bench(
         ["--fast-first", "--model", "fm_kaggle", "--batch", "128",
-         "--steps", "2", "--compile-cache", str(tmp_path / "cc"),
-         "--artifacts-dir", str(art),
+         "--steps", "2", "--artifacts-dir", str(art),
          "--attempts", "1", "--attempt-timeout", "300",
          "--total-deadline", "400"],
         env={

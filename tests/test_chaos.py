@@ -278,7 +278,7 @@ def test_cross_path_recovery_under_compound_faults(tmp_path,
     the clean run — the exactly-once cursor really is path-portable
     under compound faults."""
     if not _native_stream_ok():
-        pytest.skip("libfmfast.so native stream parser unavailable")
+        pytest.skip("native stream parser unavailable")
     from fm_spark_tpu import models
     from fm_spark_tpu.checkpoint import Checkpointer
     from fm_spark_tpu.data.native_stream import make_stream_batches

@@ -1,13 +1,13 @@
-"""Warm-start contract (ISSUE 1): the persistent compile cache and the
-AOT lower/compile entries.
+"""Warm-start contract: the persistent compile cache, where it lives,
+and the AOT lower/compile entries.
 
 The load-bearing test is the CROSS-PROCESS one: a cold process
 populates the cache dir, and a second process compiling the same
 winner-variant step performs ZERO fresh XLA compilations (every compile
-request is a cache hit) — the property that turns a flaky attachment's
-short healthy window into a measurement instead of a compile stall.
-Subprocesses are required: in-process, jit's own dispatch cache would
-short-circuit before the persistent cache is ever consulted.
+request is a cache hit). Subprocesses are required: in-process, jit's
+own dispatch cache would short-circuit before the persistent cache is
+ever consulted. Children are pointed at a directory the way any launcher
+would point them: through ``JAX_COMPILATION_CACHE_DIR``.
 """
 
 import json
@@ -37,15 +37,24 @@ def _small_fm_spec(**kw):
 # The winner-variant lever stack (minus segtotal_pallas, whose CPU
 # interpret mode would dominate the test's runtime without changing
 # what is being pinned): bf16 storage + dedup_sr + host compact + gfull.
+# The child records every jax.config.update it sees: with the variable
+# set, the program must set no cache directory in code.
 _CHILD = """
 import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
+import jax
+_updates = []
+_real_update = jax.config.update
+def _spy(name, value):
+    _updates.append(name)
+    return _real_update(name, value)
+jax.config.update = _spy
 from fm_spark_tpu.utils import compile_cache
 from fm_spark_tpu import models
 from fm_spark_tpu.train import TrainConfig
 from fm_spark_tpu.sparse import precompile_field_sparse_step
 
-compile_cache.enable(sys.argv[1])
+compile_cache.enable()
 spec = models.FieldFMSpec(num_features=3 * 32, rank=2, num_fields=3,
                           bucket=32, init_std=0.01,
                           param_dtype="bfloat16",
@@ -54,27 +63,32 @@ config = TrainConfig(learning_rate=0.05, lr_schedule="constant",
                      optimizer="sgd", sparse_update="dedup_sr",
                      host_dedup=True, compact_cap=32, gfull_fused=True)
 precompile_field_sparse_step(spec, config, 64)
-print(json.dumps(compile_cache.cache_stats()))
+print(json.dumps({**compile_cache.cache_stats(),
+                  "dir_set_in_code":
+                      "jax_compilation_cache_dir" in _updates}))
 """
 
 
 def _run_child(cache_dir) -> dict:
     out = subprocess.run(
-        [sys.executable, "-c", _CHILD, str(cache_dir)],
+        [sys.executable, "-c", _CHILD],
         capture_output=True, text=True, timeout=420, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(cache_dir)},
     )
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def test_cold_populates_then_warm_compiles_nothing(tmp_path):
-    """Cold run: cache misses, entries written. Warm run (new process,
-    same step): zero fresh XLA compilations — the warm-start
+    """Cold run: cache misses, entries written — in the directory the
+    VARIABLE names, with no directory set in code. Warm run (new
+    process, same step): zero fresh XLA compilations — the warm-start
     acceptance criterion, asserted via cache stats."""
     cold = _run_child(tmp_path / "cc")
     assert cold["enabled"]
     assert cold["dir"] == str(tmp_path / "cc")
+    assert not cold["dir_set_in_code"]
     assert cold["misses"] > 0
     assert cold["entries"] > 0
     assert cold["bytes"] > 0
@@ -91,8 +105,7 @@ def test_cold_populates_then_warm_compiles_nothing(tmp_path):
 @pytest.fixture
 def cache_config_guard():
     """Restore jax's cache config + the module's state after a test
-    that enables the cache in-process (the suite must not keep writing
-    executables into a deleted tmp dir)."""
+    that re-places the cache in-process."""
     prev = {
         "jax_compilation_cache_dir":
             jax.config.jax_compilation_cache_dir,
@@ -109,9 +122,13 @@ def cache_config_guard():
     compile_cache.reset_stats()
 
 
-def test_enable_and_stats_in_process(tmp_path, cache_config_guard):
-    d = compile_cache.enable(str(tmp_path / "cc"))
-    assert os.path.isdir(d)
+def test_enable_and_stats_in_process(tmp_path, cache_config_guard,
+                                     monkeypatch):
+    # What jax itself does at import when the variable names a dir.
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "cc"))
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cc"))
+    d = compile_cache.enable()
+    assert d == str(tmp_path / "cc") and os.path.isdir(d)
     assert compile_cache.is_enabled()
     compile_cache.reset_stats()
 
@@ -126,20 +143,49 @@ def test_enable_and_stats_in_process(tmp_path, cache_config_guard):
     assert s["misses"] + s["hits"] == s["requests"]
 
 
-def test_enable_from_env(tmp_path, cache_config_guard, monkeypatch):
-    monkeypatch.delenv(compile_cache.DEFAULT_ENV, raising=False)
-    # The no-op path must not flip the enabled state on its own.
-    assert compile_cache.enable_from_env() is None
-    # Conventional falsy spellings mean OFF — never "a dir named 0".
-    for off in ("0", "false", "no", "OFF"):
-        monkeypatch.setenv(compile_cache.DEFAULT_ENV, off)
-        assert compile_cache.enable_from_env() is None
-    monkeypatch.setenv(compile_cache.DEFAULT_ENV, str(tmp_path / "envcc"))
-    assert compile_cache.enable_from_env() == str(tmp_path / "envcc")
+def test_unset_variable_means_the_fixed_checkout_path(
+        tmp_path, cache_config_guard, monkeypatch):
+    """No variable: the cache is ``<checkout>/.jax_compile_cache`` —
+    DEFAULT_DIR, a fixed path, so a second run finds the first's
+    entries. (Redirected for the call itself, so the suite never writes
+    into the checkout.)"""
+    assert compile_cache.DEFAULT_DIR == os.path.join(
+        REPO, ".jax_compile_cache")
+    monkeypatch.delenv(compile_cache.ENV)
+    monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                        str(tmp_path / ".jax_compile_cache"))
+    assert compile_cache.enable() == compile_cache.DEFAULT_DIR
+    assert jax.config.jax_compilation_cache_dir == compile_cache.DEFAULT_DIR
+    assert os.path.isfile(
+        os.path.join(compile_cache.DEFAULT_DIR, "cache_meta.json"))
+    # Idempotent: a second call neither moves nor re-arms anything.
+    assert compile_cache.enable() == compile_cache.DEFAULT_DIR
+
+
+def test_variable_set_after_import_is_an_error_not_a_silent_no_cache(
+        tmp_path, cache_config_guard, monkeypatch):
+    """jax reads the variable at import only. Setting it later would
+    leave the process with no cache at all; the contract forbids
+    repairing that in code, so it must be loud."""
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "late"))
+    jax.config.update("jax_compilation_cache_dir", None)
+    compile_cache._state["dir"] = None
+    with pytest.raises(RuntimeError, match="after jax was imported"):
+        compile_cache.enable()
+
+
+def test_entry_points_keep_the_cache_on_without_a_flag(
+        cache_config_guard):
+    """FMTrainer (and through it every training entry point) arms the
+    cache with no flag and no opt-in variable."""
+    from fm_spark_tpu.train import FMTrainer
+
+    compile_cache._state["dir"] = None
+    FMTrainer(models.FMSpec(num_features=32, rank=2),
+              TrainConfig(optimizer="sgd"))
     assert compile_cache.is_enabled()
-    # "1" means the repo-local default dir.
-    monkeypatch.setenv(compile_cache.DEFAULT_ENV, "1")
-    assert compile_cache.default_cache_dir() == compile_cache.DEFAULT_DIR
+    assert compile_cache.cache_stats()["dir"] == os.environ[
+        compile_cache.ENV]
 
 
 def test_aot_compiled_step_matches_jit_step(rng):

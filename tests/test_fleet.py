@@ -539,13 +539,15 @@ def test_fleet_sigkill_drill_loses_nothing_and_readmits(tmp_path):
     fleet = Fleet(
         model_dir, n_replicas=2, work_dir=str(tmp_path / "work"),
         journal=journal, buckets="1,4",
-        compile_cache_dir=str(tmp_path / "cache"),
         spawn_timeout_s=300.0,
         # The drill plan rides the REPLICA environment: the 4th scored
         # request across the fleet (shared cross-process fault state)
-        # kills its replica mid-handling.
+        # kills its replica mid-handling. So does the compile cache's
+        # placement (the respawned replica warms from it).
         replica_env={faults.ENV_PLAN: "replica_kill@4=exit:9",
-                     faults.ENV_STATE: state})
+                     faults.ENV_STATE: state,
+                     "JAX_COMPILATION_CACHE_DIR":
+                         str(tmp_path / "cache")})
     fleet.start()
     door = FrontDoor(fleet, admission=AdmissionController(
         "interactive:32:8000,batch:16:8000,background:8:9000",
@@ -706,3 +708,15 @@ def test_concurrent_chain_followers_converge_nontombstoned(tmp_path):
         assert swaps and swaps[-1]["step"] == 5
         assert chaos.audit_serve_events(
             events, tombstoned_steps={2}) == []
+
+
+def test_fleet_that_never_gets_ready_leaves_no_replica_behind(tmp_path):
+    """A failed start() must stop what it started: on a TPU an orphaned
+    replica keeps the chip after the launcher has exited (seen on the
+    v5e, PR 21). The model directory is missing, so no replica can ever
+    become ready."""
+    fleet = Fleet(str(tmp_path / "no_such_model"), n_replicas=2,
+                  work_dir=str(tmp_path / "work"), spawn_timeout_s=1.0)
+    with pytest.raises(RuntimeError, match="fleet not ready"):
+        fleet.start()
+    assert all(rep.proc.poll() is not None for rep in fleet.replicas)

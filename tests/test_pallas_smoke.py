@@ -67,13 +67,12 @@ def test_registry_covers_every_public_pallas_call():
                         and sub.func.attr == "pallas_call"):
                     callers.add(node.name)
         # Public functions that are direct callers, or call a PRIVATE
-        # direct caller (one hop — the _fwd_field pattern). The
-        # availability probe is a probe, not a kernel.
+        # direct caller (one hop — the _fwd_field pattern).
         private_callers = {c for c in callers if c.startswith("_")}
         for node in tree.body:
             if not isinstance(node, ast.FunctionDef):
                 continue
-            if node.name.startswith("_") or node.name == "pallas_probe":
+            if node.name.startswith("_"):
                 continue
             names = {sub.func.id for sub in ast.walk(node)
                      if isinstance(sub, ast.Call)

@@ -1,6 +1,5 @@
 """Noise-aware regression sentinel (ISSUE 9): deterministic synthetic
-series pinning each verdict, the real r01–r05 replay through the
-backfill tool, and the keep-best gate — a ``regressed`` /
+series pinning each verdict, and the keep-best gate — a ``regressed`` /
 ``attachment_transient`` verdict must NEVER overwrite MEASURED.json."""
 
 import importlib.util
@@ -211,123 +210,6 @@ def test_observe_judges_before_appending(tmp_path):
     assert recs[-1]["sentinel"]["verdict"] == "flat"
     # The judged value was NOT part of its own history.
     assert block["n_history"] == len(STABLE)
-
-
-# ------------------------------------------------------ r01–r05 replay
-
-
-def _load_backfill():
-    spec = importlib.util.spec_from_file_location(
-        "ledger_backfill_tool",
-        os.path.join(REPO, "tools", "ledger_backfill.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def backfilled(tmp_path_factory):
-    """The real repo artifacts replayed into a fresh ledger once."""
-    mod = _load_backfill()
-    path = str(tmp_path_factory.mktemp("ledger") / "ledger.jsonl")
-    appended = mod.backfill(path, REPO)
-    return mod, path, appended
-
-
-def test_backfill_replays_r01_r05_pattern(backfilled):
-    """THE acceptance pin: the nulled r03–r05 rounds land as
-    ``attachment_transient`` — classified weather, not gaps — and the
-    r02 sweep's five variant rates are the band they precede."""
-    mod, path, appended = backfilled
-    by_run = {}
-    for rec in appended:
-        by_run.setdefault(rec["run_id"], []).append(rec)
-    for n in (3, 4, 5):
-        (rec,) = by_run[f"backfill-bench-r{n:02d}"]
-        assert rec["value"] is None
-        assert rec["fingerprint"]["attachment_health"] == "down"
-        assert rec["sentinel"]["verdict"] == "attachment_transient", (
-            f"r{n:02d} must classify attachment_transient, got "
-            f"{rec['sentinel']}")
-    # r01 (backend init Unavailable) is the same weather shape.
-    (r01,) = by_run["backfill-bench-r01"]
-    assert r01["sentinel"]["verdict"] == "attachment_transient"
-    # r02 parsed: five variant records, real values, healthy weather.
-    r02 = by_run["backfill-bench-r02"]
-    assert len(r02) == 5
-    assert all(r["value"] > 0 for r in r02)
-
-
-def test_backfill_measured_headline_replays_as_improved(backfilled):
-    """The genuine round-5 lever improvement (1.059M → 1.422M) must
-    read as signal against the r02 band — the sentinel agrees with
-    the recorded history, not just with hand-picked examples."""
-    mod, path, appended = backfilled
-    (headline,) = [r for r in appended
-                   if r["run_id"] == "backfill-measured-headline"]
-    assert headline["value"] == pytest.approx(1422410.5)
-    assert headline["sentinel"]["verdict"] == "improved"
-
-
-def test_backfill_is_idempotent(backfilled):
-    mod, path, appended = backfilled
-    assert appended, "first backfill must append"
-    assert mod.backfill(path, REPO) == []
-    # Still exactly one copy of every record on disk.
-    recs = PerfLedger(path).records()
-    assert len(recs) == len(appended)
-
-
-def test_backfill_refuses_a_live_ledger(tmp_path):
-    """Backfill is day-one seeding ONLY: cohort history is append
-    order, so 2026-07 values appended behind live measurements would
-    become the band's most-recent entries and drag it backwards."""
-    mod = _load_backfill()
-    led = PerfLedger(str(tmp_path / "l.jsonl"))
-    led.append({"kind": "bench_leg", "leg": "legA", "run_id": "live-1",
-                "value": 123.0,
-                "fingerprint": measurement_fingerprint(
-                    variant="v", model="fm")})
-    assert mod.backfill(led.path, REPO) == []
-    assert len(PerfLedger(led.path).records()) == 1
-
-
-def test_backfill_ignores_non_cohort_kinds(tmp_path):
-    """attachment_probe / kernel_pricing records never enter a bench
-    cohort — a tpu_watch poll that beat the operator to the ledger
-    must not forfeit the day-one seed."""
-    mod = _load_backfill()
-    led = PerfLedger(str(tmp_path / "l.jsonl"))
-    led.append({"kind": "attachment_probe", "leg": "attachment",
-                "run_id": "watch-1", "value": 1.0,
-                "fingerprint": measurement_fingerprint(
-                    variant="probe", model="tpu_watch")})
-    appended = mod.backfill(led.path, REPO)
-    assert appended, "probe records must not block the seed"
-    assert len(PerfLedger(led.path).records()) == 1 + len(appended)
-
-
-def test_backfill_covers_multichip_artifacts(backfilled):
-    mod, path, appended = backfilled
-    multi = [r for r in appended if r["kind"] == "multichip_dryrun"]
-    assert len(multi) == 5
-    # The later dryruns carry the parsed projected aggregate.
-    assert any(isinstance(r["value"], float) and r["value"] > 1e6
-               for r in multi)
-
-
-def test_backfill_cli_reports_verdict_counts(tmp_path, capsys):
-    mod = _load_backfill()
-    rc = mod.main(["--ledger", str(tmp_path / "l.jsonl")])
-    assert rc == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["appended"] > 0
-    assert doc["verdicts"]["attachment_transient"] >= 4
-    rc = mod.main(["--ledger", str(tmp_path / "l.jsonl")])
-    assert rc == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["appended"] == 0
 
 
 # ------------------------------------------------------ keep-best gate

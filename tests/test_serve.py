@@ -94,13 +94,11 @@ def _counter(name):
     return obs.registry().counter(name).value
 
 
-# NOTE: every test that arms the persistent compile cache runs it in a
-# SUBPROCESS — the same policy (and reason) as tests/test_compile_cache:
-# in-process, jit's dispatch cache would mask the persistent cache, and
-# on this container an in-process-armed cache additionally makes later
-# drill-suite compiles segfault inside jaxlib (pre-existing, reproduced
-# on the PR-10 tree with no serving code loaded). Subprocesses keep the
-# warm-start assertions honest AND the suite ordering-safe.
+# NOTE: every test that pins cold-vs-warm compile-cache behaviour runs
+# it in a SUBPROCESS with a cache directory of its own — the same policy
+# (and reason) as tests/test_compile_cache: in-process, jit's dispatch
+# cache would mask the persistent cache, and the session's shared cache
+# (tests/conftest.py) is warm from whatever ran before.
 
 
 # ------------------------------------------------------------- the engine
@@ -204,15 +202,13 @@ _ZERO_COMPILE_CHILD = """
 import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["FM_SPARK_OBS_DIR"] = "none"
-from fm_spark_tpu.utils.cpuguard import force_cpu_platform
-force_cpu_platform()
 import numpy as np
 import jax
 from fm_spark_tpu import models
 from fm_spark_tpu.serve import PredictEngine
 from fm_spark_tpu.utils import compile_cache
 
-compile_cache.enable(sys.argv[1])
+compile_cache.enable()
 spec = models.FieldFMSpec(num_features=4 * 64, rank=4, num_fields=4,
                           bucket=64, init_std=0.1)
 params = spec.init(jax.random.key(0))
@@ -246,10 +242,10 @@ def test_request_path_zero_compile_requests_after_warmup(tmp_path):
     the train-side warm-start tests."""
     def run():
         out = subprocess.run(
-            [sys.executable, "-c", _ZERO_COMPILE_CHILD,
-             str(tmp_path / "cc")],
+            [sys.executable, "-c", _ZERO_COMPILE_CHILD],
             capture_output=True, text=True, timeout=240, cwd=REPO,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")})
         assert out.returncode == 0, out.stderr[-2000:]
         return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -608,8 +604,10 @@ def test_sigkill_during_reload_drill_subprocess(tmp_path):
                             cwd=REPO, env=env,
                             stderr=subprocess.DEVNULL)
     try:
-        # Wait until the child is actually serving, THEN publish the
-        # new generation its poll will die reloading.
+        # Wait until the child is actually serving (its second line;
+        # the first names the device), THEN publish the new generation
+        # its poll will die reloading.
+        assert '"device"' in proc.stdout.readline()
         line = proc.stdout.readline()
         assert '"serving": true' in line, line
         ck.save(2, _params(spec, scale=3.0), {}, None, force=True)
@@ -766,10 +764,10 @@ def _run_bench_serve(tmp_path, *extra):
         [sys.executable, os.path.join(REPO, "bench_serve.py"),
          "--smoke", "--art-dir", str(tmp_path / "art"),
          "--measured-path", str(tmp_path / "MEASURED.json"),
-         "--compile-cache", str(tmp_path / "cc"),
          "--requests", "12", "--out", str(out_path), *extra],
         capture_output=True, text=True, timeout=300, cwd=REPO,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc"),
              "FM_SPARK_OBS_DIR": "none"})
     assert out.returncode == 0, out.stderr[-2000:]
     with open(out_path) as f:

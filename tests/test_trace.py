@@ -464,6 +464,7 @@ def test_fleet_tracing_end_to_end(tmp_path):
     obs_root = str(tmp_path / "obs")
 
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            # The kill plan rides the environment into the REPLICAS
            # (the parent never arms the replica_kill point): the 4th
            # handled request across the fleet dies mid-flight.
@@ -473,8 +474,7 @@ def test_fleet_tracing_end_to_end(tmp_path):
     proc = subprocess.Popen(
         [sys.executable, "-m", "fm_spark_tpu.cli", "serve",
          "--fleet", "2", "--model", model_dir, "--buckets", "1,4",
-         "--obs-dir", obs_root, "--compile-cache",
-         str(tmp_path / "cache"), "--frontdoor-port", "0",
+         "--obs-dir", obs_root, "--frontdoor-port", "0",
          "--trace-sample", "1.0", "--latency-budget-ms", "0",
          "--reload-poll-s", "0"],
         stdout=subprocess.PIPE, stderr=open(stderr_path, "w"),

@@ -1,24 +1,22 @@
-"""Reproducible build for the native kernels: fasthash.cpp → libfmfast.so.
+"""Reproducible build for the native kernels: fasthash.cpp → libfmfast-<hash>.so.
 
-The checked-in shared library would otherwise be an opaque binary with
-no recorded recipe — this script IS the recipe (compiler flags pinned
-below, the same line ``fm_spark_tpu/native/__init__.py`` uses for its
-lazy on-import rebuild) plus a drift detector:
+The recipe itself (compiler, flags, the content-hashed output name)
+lives in ``fm_spark_tpu/native/__init__.py``, which builds lazily on
+first use; this script runs the same build by hand and is the drift
+detector for the exported surface:
 
-    python tools/build_native.py            # (re)build in place
+    python tools/build_native.py            # build (if absent) + list
     python tools/build_native.py --check    # build to a temp dir and
                                             # diff exported fm_* symbols
                                             # against EXPECTED_SYMBOLS
-                                            # and the shipped .so
     python tools/build_native.py --print-symbols
 
 ``--check`` exits nonzero when the source exports a symbol set that
 differs from :data:`EXPECTED_SYMBOLS` (someone added an entry point
-without registering it here — the ctypes bindings guard symbols
-individually, so a stale .so degrades silently instead of failing; this
-check is what turns red) or when the SHIPPED .so is missing one (a
-stale cached artifact). Tier-1 wiring: tests/test_native_stream.py runs
-``--check`` and skips cleanly when no compiler is present.
+without registering it here, or dropped one the ctypes bindings name —
+the loader would then raise at first use). Tier-1 wiring:
+tests/test_native_stream.py runs ``--check`` and skips cleanly when no
+compiler is present.
 """
 
 import argparse
@@ -30,14 +28,11 @@ import sys
 import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SRC = os.path.join(REPO, "fm_spark_tpu", "native", "fasthash.cpp")
-SO = os.path.join(REPO, "fm_spark_tpu", "native", "libfmfast.so")
+sys.path.insert(0, REPO)
 
-#: Pinned compiler + flags — keep in sync with native/__init__.py _build().
-COMPILER = "g++"
-FLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
+from fm_spark_tpu import native  # noqa: E402
 
-#: The extern "C" surface the ctypes bindings may bind. Adding an entry
+#: The extern "C" surface the ctypes bindings bind. Adding an entry
 #: point to fasthash.cpp without listing it here fails --check.
 EXPECTED_SYMBOLS = (
     "fm_murmur3_32",
@@ -51,21 +46,6 @@ EXPECTED_SYMBOLS = (
     "fm_compact_aux",
     "fm_gather_rows",
 )
-
-
-def compiler_available() -> bool:
-    return shutil.which(COMPILER) is not None
-
-
-def build(out_path: str) -> None:
-    """Compile SRC → out_path with the pinned flags (raises on failure)."""
-    cmd = [COMPILER, *FLAGS, SRC, "-o", out_path]
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"{' '.join(cmd)} failed (rc={proc.returncode}):\n"
-            f"{proc.stderr[-2000:]}"
-        )
 
 
 def exported_symbols(so_path: str) -> list[str]:
@@ -87,11 +67,11 @@ def exported_symbols(so_path: str) -> list[str]:
 
 
 def check() -> int:
-    """Build fresh, diff symbols vs EXPECTED_SYMBOLS and the shipped .so."""
+    """Build fresh and diff its symbols against EXPECTED_SYMBOLS."""
     rc = 0
     with tempfile.TemporaryDirectory(prefix="fm_build_native_") as tmp:
         fresh = os.path.join(tmp, "libfmfast.so")
-        build(fresh)
+        native.build(fresh)
         got = set(exported_symbols(fresh))
         want = set(EXPECTED_SYMBOLS)
         if got != want:
@@ -101,14 +81,6 @@ def check() -> int:
             for sym in sorted(got - want):
                 print(f"UNREGISTERED export: {sym} (add it to "
                       "EXPECTED_SYMBOLS)", file=sys.stderr)
-        if os.path.exists(SO):
-            shipped = set(exported_symbols(SO))
-            for sym in sorted(want - shipped):
-                rc = 1
-                print(f"shipped libfmfast.so is STALE: missing {sym} "
-                      "(rerun tools/build_native.py)", file=sys.stderr)
-        else:
-            print("note: no shipped libfmfast.so (first use will build it)")
     if rc == 0:
         print(f"symbol check OK: {len(want)} exported fm_* symbols")
     return rc
@@ -118,27 +90,30 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--check", action="store_true",
                     help="build to a temp dir and diff exported symbols "
-                         "instead of overwriting the shipped .so")
+                         "against EXPECTED_SYMBOLS")
     ap.add_argument("--print-symbols", action="store_true",
                     dest="print_symbols",
-                    help="list the shipped library's fm_* exports")
+                    help="list the built library's fm_* exports")
     args = ap.parse_args()
+    so = native.lib_path()
     if args.print_symbols:
-        if not os.path.exists(SO):
-            print(f"error: {SO} does not exist (run tools/build_native.py "
+        if not os.path.exists(so):
+            print(f"error: {so} does not exist (run tools/build_native.py "
                   "first)", file=sys.stderr)
             return 2
-        for sym in exported_symbols(SO):
+        for sym in exported_symbols(so):
             print(sym)
         return 0
-    if not compiler_available():
-        print(f"error: {COMPILER} not found on PATH", file=sys.stderr)
+    if shutil.which(native.COMPILER) is None:
+        print(f"error: {native.COMPILER} not found on PATH",
+              file=sys.stderr)
         return 2
     if args.check:
         return check()
-    build(SO)
-    print(f"built {SO}")
-    for sym in exported_symbols(SO):
+    if not os.path.exists(so):
+        native.build(so)
+    print(f"built {so}")
+    for sym in exported_symbols(so):
         print(f"  {sym}")
     return 0
 

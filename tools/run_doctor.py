@@ -856,8 +856,8 @@ def findings(diag: dict, legs: list[dict]) -> list[str]:
                  if diag["fresh_compiles"] else "")
         out.append(
             f"compile-dominated: {ph['compile+warmup'] / wall:.0%} of "
-            f"wall-clock in compile/warmup{fresh} — warm the "
-            "persistent cache (--compile-cache)")
+            f"wall-clock in compile/warmup{fresh} — a second run "
+            "reads them from the persistent compile cache")
     if ph["faults/backoff"] / wall > 0.10 or diag["fault_kinds"].get(
             "circuit_open") or diag["fault_kinds"].get("permanent_fault"):
         out.append(
