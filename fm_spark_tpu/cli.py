@@ -902,61 +902,85 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         # batches that would never train (exact-resume cursor).
         batches = StackedBatches(batches, steps_per_call,
                                  total=tconfig.num_steps - start)
+    from fm_spark_tpu import obs
     from fm_spark_tpu.resilience import faults
 
     batches, close_prefetch = wrap_prefetch(batches, prefetch)
+    # The loop's hot intervals (obs.interval: always-live ring, profiler
+    # annotation, trace.jsonl). Per iteration a parent ``train/step``
+    # and inside it next_batch (the wait on the prefetch queue), prep
+    # (pad, shard, host-to-device placement), dispatch (the jitted call
+    # returning) and, at log cadence, loss_fetch (the fence: the host
+    # waiting for the device). The rest of train/step is its self time.
     try:
         if multi:
             i = start
             while i < tconfig.num_steps:
-                # Deterministic mid-run device loss for the elastic
-                # shrink tests (resilience/faults.py); a single is-None
-                # check when no fault plan is active.
-                faults.inject("train_step")
                 m = min(steps_per_call, tconfig.num_steps - i)
-                stacked = batches.next_batch()
-                if is_deepfm:
-                    params, opt, loss = mstep(
-                        params, opt, jnp.int32(i), jnp.int32(m),
-                        *prep(stacked))
-                else:
-                    params, loss = mstep(params, jnp.int32(i),
-                                         jnp.int32(m), *prep(stacked))
-                note_loss(loss)
-                i += m
-                since += m * stacked[2].shape[1]
-                # Windowed cadences: a multiple of the interval inside
-                # (i-m, i] fires, so stride-advanced (and off-aligned
-                # resumed) counters never silently skip.
-                if (i // log_every) > ((i - m) // log_every) or (
-                    i >= tconfig.num_steps
-                ):
-                    logger.log(i, samples=since, loss=fetch_loss(loss))
-                    since = 0
-                maybe_eval(i, lambda: to_canonical(params), window=m)
-                if checkpointer is not None and checkpointer.due_window(i, m):
-                    check_poison()
-                    # Same layout contract as the per-step loop:
-                    # --ckpt-sharded saves the live sharded arrays (no
-                    # host gather) and records the layout for resume.
-                    checkpointer.save(i, ckpt_params(), ckpt_opt(),
-                                      pipe_state(), extra=ckpt_extra)
+                with obs.interval("train/step", step=i, steps=m):
+                    # Deterministic mid-run device loss for the elastic
+                    # shrink tests (resilience/faults.py); a single
+                    # is-None check when no fault plan is active.
+                    faults.inject("train_step")
+                    with obs.interval("train/next_batch", step=i):
+                        stacked = batches.next_batch()
+                    with obs.interval("train/prep", step=i):
+                        placed = prep(stacked)
+                    with obs.interval("train/dispatch", step=i):
+                        if is_deepfm:
+                            params, opt, loss = mstep(
+                                params, opt, jnp.int32(i), jnp.int32(m),
+                                *placed)
+                        else:
+                            params, loss = mstep(params, jnp.int32(i),
+                                                 jnp.int32(m), *placed)
+                    note_loss(loss)
+                    i += m
+                    since += m * stacked[2].shape[1]
+                    # Windowed cadences: a multiple of the interval
+                    # inside (i-m, i] fires, so stride-advanced (and
+                    # off-aligned resumed) counters never silently skip.
+                    if (i // log_every) > ((i - m) // log_every) or (
+                        i >= tconfig.num_steps
+                    ):
+                        with obs.interval("train/loss_fetch", step=i - m):
+                            loss_now = fetch_loss(loss)
+                        logger.log(i, samples=since, loss=loss_now)
+                        since = 0
+                    maybe_eval(i, lambda: to_canonical(params), window=m)
+                    if (checkpointer is not None
+                            and checkpointer.due_window(i, m)):
+                        check_poison()
+                        # Same layout contract as the per-step loop:
+                        # --ckpt-sharded saves the live sharded arrays
+                        # (no host gather) and records the layout for
+                        # resume.
+                        checkpointer.save(i, ckpt_params(), ckpt_opt(),
+                                          pipe_state(), extra=ckpt_extra)
         else:
             for i in range(start, tconfig.num_steps):
-                faults.inject("train_step")
-                batch = batches.next_batch()
-                params, opt, loss = step(params, opt, jnp.int32(i),
-                                         *prep(batch))
-                note_loss(loss)
-                since += len(batch[2])
-                if (i + 1) % log_every == 0 or i == tconfig.num_steps - 1:
-                    logger.log(i + 1, samples=since, loss=fetch_loss(loss))
-                    since = 0
-                maybe_eval(i + 1, lambda: to_canonical(params))
-                if checkpointer is not None and checkpointer.due(i + 1):
-                    check_poison()
-                    checkpointer.save(i + 1, ckpt_params(), ckpt_opt(),
-                                      pipe_state(), extra=ckpt_extra)
+                with obs.interval("train/step", step=i):
+                    faults.inject("train_step")
+                    with obs.interval("train/next_batch", step=i):
+                        batch = batches.next_batch()
+                    with obs.interval("train/prep", step=i):
+                        placed = prep(batch)
+                    with obs.interval("train/dispatch", step=i):
+                        params, opt, loss = step(params, opt, jnp.int32(i),
+                                                 *placed)
+                    note_loss(loss)
+                    since += len(batch[2])
+                    if ((i + 1) % log_every == 0
+                            or i == tconfig.num_steps - 1):
+                        with obs.interval("train/loss_fetch", step=i):
+                            loss_now = fetch_loss(loss)
+                        logger.log(i + 1, samples=since, loss=loss_now)
+                        since = 0
+                    maybe_eval(i + 1, lambda: to_canonical(params))
+                    if checkpointer is not None and checkpointer.due(i + 1):
+                        check_poison()
+                        checkpointer.save(i + 1, ckpt_params(), ckpt_opt(),
+                                          pipe_state(), extra=ckpt_extra)
         if checkpointer is not None:
             if start < tconfig.num_steps:
                 check_poison()

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from fm_spark_tpu import obs
+
 
 def train_test_split(ids, vals, labels, test_fraction=0.2, seed=0):
     """Deterministic shuffled split (the lineage's example-driver idiom)."""
@@ -374,6 +376,15 @@ class StackedBatches:
         return getattr(self._source, "guard", None)
 
 
+def _batch_rows(batch) -> int:
+    """Examples in a batch tuple (``labels`` is element 2; a stacked
+    batch counts every step's), 0 for a shape this cannot read."""
+    try:
+        return int(np.size(batch[2]))
+    except (TypeError, IndexError, KeyError):
+        return 0
+
+
 class Prefetcher:
     """Background-thread batch prefetch with a bounded queue.
 
@@ -413,18 +424,24 @@ class Prefetcher:
     def _produce(self):
         try:
             while not self._stop.is_set():
-                batch = self._source.next_batch()
-                if self._device_put:
-                    import jax
+                # Hot intervals (obs.interval, always live): produce is
+                # what one batch costs the feed, put_wait the time the
+                # producer stood at a full queue — the feed's slack.
+                with obs.interval("feed/produce") as made:
+                    batch = self._source.next_batch()
+                    if self._device_put:
+                        import jax
 
-                    batch = jax.device_put(batch)
+                        batch = jax.device_put(batch)
+                    made.set(rows=_batch_rows(batch))
                 state = self._source.state() if self._has_state else None
-                while not self._stop.is_set():
-                    try:
-                        self._q.put((batch, state, None), timeout=0.1)
-                        break
-                    except Exception:  # queue.Full
-                        continue
+                with obs.interval("feed/put_wait", rows=made.attrs["rows"]):
+                    while not self._stop.is_set():
+                        try:
+                            self._q.put((batch, state, None), timeout=0.1)
+                            break
+                        except Exception:  # queue.Full
+                            continue
         except StopIteration:
             self._q.put((None, None, StopIteration()))
         except BaseException as e:  # surface producer crashes to consumer

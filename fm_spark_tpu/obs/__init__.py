@@ -22,9 +22,12 @@ This module is the instrumentation facade the rest of the codebase
 calls. Everything is a cheap no-op until :func:`configure` runs —
 library code instruments unconditionally and pays (almost) nothing in
 un-observed processes (the ≤1% disabled-path contract,
-tests/test_obs_overhead.py). The metrics registry is the exception: it
-is always live (memory only), so counters/gauges accumulate even
-without a run directory.
+tests/test_obs_overhead.py). Two things are the exception, always live
+and memory only: the metrics registry, so counters/gauges accumulate
+even without a run directory, and the ring of hot intervals
+(:func:`interval`, :func:`record_interval`, :func:`intervals`) that the
+training loop, the prefetch producer and the serve coalescer record
+into.
 """
 
 from __future__ import annotations
@@ -49,7 +52,9 @@ from fm_spark_tpu.obs.sentinel import (
     keepbest_allowed,
 )
 from fm_spark_tpu.obs.trace import (
+    Interval,
     NOOP_SPAN,
+    RING_CAPACITY,
     Span,
     TRACE_HEADER,
     TraceContext,
@@ -60,8 +65,10 @@ from fm_spark_tpu.obs import trace as _trace_mod
 __all__ = [
     "FAULT_KINDS",
     "FlightRecorder",
+    "Interval",
     "MetricsRegistry",
     "PerfLedger",
+    "RING_CAPACITY",
     "Sentinel",
     "SentinelPolicy",
     "Span",
@@ -81,12 +88,15 @@ __all__ = [
     "gauge",
     "histogram",
     "install_signal_dump",
+    "interval",
+    "intervals",
     "introspect",
     "keepbest_allowed",
     "measurement_fingerprint",
     "mint_trace",
     "new_run_id",
     "read_spool",
+    "record_interval",
     "registry",
     "run_dir",
     "run_id",
@@ -234,6 +244,32 @@ def emit_span(name: str, t_start: float, dur_s: float, **attrs) -> None:
     tr = _state["tracer"]
     if tr is not None:
         tr.emit_span(name, t_start, dur_s, **attrs)
+
+
+def interval(name: str, **ids) -> Interval:
+    """A hot interval (context manager): ALWAYS recorded into the
+    in-memory ring, also a ``jax.profiler.TraceAnnotation`` while a
+    profiler session is on, also a ``trace.jsonl`` record when a run
+    directory is configured (:class:`fm_spark_tpu.obs.trace.Interval`).
+    Only for the few sites inside the loops every run executes;
+    everything else keeps :func:`span` and its free no-op."""
+    return Interval(name, ids, _state["tracer"])
+
+
+def record_interval(name: str, t0: float, t1: float,
+                    parent_id: str | None = None, **ids) -> Interval:
+    """A hot interval the caller timed itself on
+    ``time.perf_counter()``: ring and ``trace.jsonl``, no profiler
+    annotation."""
+    return _trace_mod.record_interval(name, t0, t1, ids, _state["tracer"],
+                                      parent_id)
+
+
+def intervals() -> list[Interval]:
+    """Snapshot of the ring of finished hot intervals, oldest first
+    (the last :data:`RING_CAPACITY`; ``registry().reset()`` and
+    :func:`configure` leave it alone)."""
+    return _trace_mod.intervals()
 
 
 def mint_trace(sample: float = 1.0) -> TraceContext | None:

@@ -23,20 +23,32 @@ Usage::
 
     @obs.traced("ingest/chunk_parse")
     def parse_chunk(...): ...
+
+Hot intervals (ISSUE 24) are the always-live twin: a few named call
+sites inside the loops every run executes (``train/*``, ``feed/*``,
+``serve/*``) record through :class:`Interval` into one bounded
+in-memory ring whether or not a run directory is configured — to spans
+what the registry is to metrics. One call feeds three sinks: the ring
+(:func:`intervals`), the profiler's trace (a
+``jax.profiler.TraceAnnotation`` while a profiler session is on) and,
+when configured, the :class:`Tracer` (``trace.jsonl``, flight ring).
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 import os
 import random
 import re
+import sys
 import threading
 import time
 
-__all__ = ["NOOP_SPAN", "Span", "TraceContext", "TRACE_HEADER",
-           "Tracer", "mint_trace"]
+__all__ = ["Interval", "NOOP_SPAN", "RING_CAPACITY", "Span",
+           "TraceContext", "TRACE_HEADER", "Tracer", "intervals",
+           "mint_trace", "record_interval"]
 
 _SEQ = itertools.count(1)
 _TLS = threading.local()
@@ -120,6 +132,18 @@ def _stack() -> list:
     return st
 
 
+def _unstack(st: list, span) -> None:
+    if st and st[-1] is span:
+        st.pop()
+    else:
+        # Mis-nested manual open/close: drop this span wherever it
+        # sits rather than corrupting the siblings' parentage.
+        try:
+            st.remove(span)
+        except ValueError:
+            pass
+
+
 class _NoopSpan:
     """Shared do-nothing span: the disabled fast path (no allocation)."""
 
@@ -170,16 +194,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_s = time.perf_counter() - self._t0
-        st = _stack()
-        if st and st[-1] is self:
-            st.pop()
-        else:
-            # Mis-nested manual open/close: drop this span wherever it
-            # sits rather than corrupting the siblings' parentage.
-            try:
-                st.remove(self)
-            except ValueError:
-                pass
+        _unstack(_stack(), self)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self.tracer._finish(self)
@@ -240,7 +255,7 @@ class Tracer:
         sp.dur_s = float(dur_s)
         self._finish(sp)
 
-    def _finish(self, span: Span) -> None:
+    def _finish(self, span: "Span | Interval") -> None:
         fields = {
             "name": span.name,
             "span_id": span.span_id,
@@ -258,3 +273,143 @@ class Tracer:
                 self.flight.record("span", **fields)
         except Exception:
             pass
+
+
+# ----------------------------------------------------------- hot intervals
+
+#: Finished intervals the ring keeps (the oldest fall off): ~6 MB at
+#: worst; a 20 s scoring window makes ~8,000, 360 FFM steps ~3,000.
+RING_CAPACITY = 65536
+
+_RING: collections.deque = collections.deque(maxlen=RING_CAPACITY)
+#: ``time.perf_counter()`` to wall clock, fixed at import: a hot interval
+#: reads one clock, and its ``t_start`` in ``trace.jsonl`` is derived.
+_WALL_OFFSET = time.time() - time.perf_counter()  # fmlint: disable=wallclock-duration -- the offset between the two clocks, not a duration: it turns a perf_counter stamp into the wall-clock timestamp trace.jsonl carries
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _find_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is ALREADY imported (obs
+    never imports it), else None."""
+    global _annotation
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class Interval:
+    """One finished (or open) hot interval, and the context manager that
+    times it: ``name``, ``t0`` / ``t1`` (``time.perf_counter()``
+    seconds), the recording ``thread``, its process-unique ``span_id``
+    (a :class:`Span`'s format, made when first read: most are never
+    read), the ``parent_id`` that caused it (the thread's innermost open span
+    or interval; for a request, its batch) and ``attrs``, the few
+    integers that identify the work (``step``, ``rows``, ``bucket``,
+    ``requests``); ``profiled`` says a profiler session was on at entry.
+
+    Unlike :class:`Span` it is live without ``obs.configure()``: on exit
+    — by an exception too, ``BaseException`` included — the object
+    itself is appended to the ring. Inside a profiler session it is also
+    a ``TraceAnnotation`` under the same name (outside one that is a
+    flag test in C++), and a configured :class:`Tracer` gets the same
+    record.
+    """
+
+    __slots__ = ("name", "attrs", "tracer", "t0", "t1", "thread",
+                 "parent_id", "profiled", "_id", "_ann", "_st")
+
+    def __init__(self, name: str, attrs: dict, tracer: "Tracer | None"):
+        self.name = name
+        self.attrs = attrs
+        self.tracer = tracer
+        self.t0 = self.t1 = 0.0
+        self.thread = threading.get_ident()
+        self.parent_id = None
+        #: A profiler session was on when this interval was entered (it
+        #: is in the xplane too). A reader can tell from it in which
+        #: step a session started or stopped: that step paid for it.
+        self.profiled = False
+        self._id = (os.getpid(), next(_SEQ))
+        self._ann = None
+        self._st = None
+
+    @property
+    def span_id(self) -> str:
+        sid = self._id
+        if type(sid) is tuple:
+            sid = self._id = f"{sid[0]:x}-{sid[1]:x}"
+        return sid
+
+    @property
+    def ts(self) -> float:
+        """Wall-clock start (what ``trace.jsonl`` calls ``t_start``)."""
+        return self.t0 + _WALL_OFFSET
+
+    @property
+    def dur_s(self) -> float:
+        return self.t1 - self.t0
+
+    def set(self, **attrs) -> "Interval":
+        self.attrs.update(attrs)
+        return self
+
+    def __enter__(self) -> "Interval":
+        st = self._st = _stack()
+        self.parent_id = st[-1].span_id if st else None
+        st.append(self)
+        ann = _annotation or _find_annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self.name, **self.attrs)
+            self._ann.__enter__()
+            self.profiled = True
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        _unstack(self._st, self)
+        self._st = None
+        if exc_type is not None:
+            self.attrs.setdefault("error", exc_type.__name__)
+        self._finish()
+        return False
+
+    def _finish(self) -> None:
+        _RING.append(self)
+        # The ring outlives the run directory: a kept record must not
+        # keep a closed tracer (its sink, its flight spool) alive.
+        tracer, self.tracer = self.tracer, None
+        if tracer is not None and tracer.enabled:
+            tracer._finish(self)
+
+
+def record_interval(name: str, t0: float, t1: float, attrs: dict,
+                    tracer: "Tracer | None" = None,
+                    parent_id: str | None = None) -> Interval:
+    """Record an interval the CALLER timed (``t0`` / ``t1`` on
+    ``time.perf_counter()``) into the ring and the tracer. No profiler
+    annotation: the profiler takes none after the fact. ``parent_id``
+    defaults to the thread's innermost open span."""
+    iv = Interval(name, attrs, tracer)
+    iv.t0, iv.t1 = float(t0), float(t1)
+    if parent_id is None:
+        st = _stack()
+        parent_id = st[-1].span_id if st else None
+    iv.parent_id = parent_id
+    iv._finish()
+    return iv
+
+
+def intervals() -> list:
+    """Snapshot of the ring, oldest first, without stopping writers
+    (``deque.append`` is atomic; copying one that a writer touches
+    mid-copy raises ``RuntimeError`` and is simply tried again)."""
+    while True:
+        try:
+            return list(_RING)
+        except RuntimeError:
+            continue

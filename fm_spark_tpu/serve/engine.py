@@ -275,14 +275,14 @@ class PredictEngine:
                 vals.astype(self._vals_dtype, copy=False))
 
     def _execute(self, gen: Generation, ids: np.ndarray,
-                 vals: np.ndarray,
-                 exec_info: "dict | None" = None) -> np.ndarray:
-        """One padded-bucket dispatch on ``gen``; returns the first
-        ``n`` scores as host floats. The ONLY dispatch path — spans,
-        SLO watchdog, and the zero-compile property all live here.
-        ``exec_info`` (out-param) receives the shared batch span's id
-        + perf-clock bounds so the coalescer's per-request link spans
-        can decompose wait/execute/split."""
+                 vals: np.ndarray, requests: int = 1):
+        """One padded-bucket dispatch on ``gen``; returns ``(scores,
+        batch)``: the first ``n`` scores as host floats and the
+        finished ``serve/batch`` hot interval (pad, dispatch, device,
+        the copy back), whose id and bounds let the coalescer
+        decompose each request into queue / execute / split. The ONLY
+        dispatch path — spans, SLO watchdog, and the zero-compile
+        property all live here."""
         n = ids.shape[0]
         bucket = self._bucket_for(n)
         compiled = self._compiled.get(bucket)
@@ -291,26 +291,23 @@ class PredictEngine:
                 f"bucket {bucket} not compiled — call warmup() before "
                 "serving (the request path never compiles)")
         pad = bucket - n
-        if pad:
-            ids = np.concatenate(
-                [ids, np.zeros((pad, self.nnz), self._ids_dtype)])
-            vals = np.concatenate(
-                [vals, np.zeros((pad, self.nnz), self._vals_dtype)])
-        t0 = time.perf_counter()
-        with obs.span("serve/batch", rows=n, bucket=bucket,
-                      gen_step=gen.step) as bsp:
+        with obs.interval("serve/batch", rows=n, bucket=bucket, pad=pad,
+                          requests=requests, gen_step=gen.step) as batch:
+            if pad:
+                ids = np.concatenate(
+                    [ids, np.zeros((pad, self.nnz), self._ids_dtype)])
+                vals = np.concatenate(
+                    [vals, np.zeros((pad, self.nnz), self._vals_dtype)])
+            t0 = time.perf_counter()
             with watchdog.phase("serve_request"):
                 out = np.asarray(compiled(gen.params, ids, vals))
-        t1 = time.perf_counter()
-        if exec_info is not None:
-            exec_info.update(span_id=getattr(bsp, "span_id", None),
-                             t0=t0, t1=t1)
+            t1 = time.perf_counter()
         obs.histogram("serve/batch_ms").observe((t1 - t0) * 1e3)
         obs.counter("serve.batches_total").add(1)
         obs.counter("serve.rows_total").add(n)
         if pad:
             obs.counter("serve.padded_rows_total").add(pad)
-        return out[:n]
+        return out[:n], batch
 
     def score(self, ids, vals) -> np.ndarray:
         """Direct (non-coalesced) bucketed scoring — the offline batch
@@ -320,9 +317,9 @@ class PredictEngine:
         gen = self._gen
         cap = self.buckets[-1]
         if ids.shape[0] <= cap:
-            return self._execute(gen, ids, vals)
+            return self._execute(gen, ids, vals)[0]
         return np.concatenate([
-            self._execute(gen, ids[lo:lo + cap], vals[lo:lo + cap])
+            self._execute(gen, ids[lo:lo + cap], vals[lo:lo + cap])[0]
             for lo in range(0, ids.shape[0], cap)
         ])
 
@@ -372,40 +369,45 @@ class PredictEngine:
 
     def _gather(self) -> list[_Request] | None:
         """Block for the first request, then accumulate under the
-        latency budget / until bucket-max; ``None`` = stop."""
-        first = self._carry
-        self._carry = None  # fmlint: disable=thread-lock-discipline -- coalescer-thread-local carry: only the single worker thread (_run/_gather) ever touches it
-        if first is None:
-            first = self._queue.get()
-        if first is _STOP:
-            return None
-        batch = [first]
-        rows = first.n
-        cap = self.buckets[-1]
-        deadline = time.monotonic() + self.latency_budget_s
-        if first.deadline is not None:
-            deadline = min(deadline, first.deadline)
-        while rows < cap:
-            remaining = deadline - time.monotonic()
-            if remaining <= 0:
-                break
-            try:
-                nxt = self._queue.get(timeout=remaining)
-            except queue.Empty:
-                break
-            if nxt is _STOP:
-                # Finish this batch, then stop: queued requests are
-                # answered, never dropped.
-                self._queue.put(_STOP)
-                break
-            if rows + nxt.n > cap:
-                self._carry = nxt  # fmlint: disable=thread-lock-discipline -- heads the next batch; coalescer-thread-local (single worker thread)
-                break
-            batch.append(nxt)
-            rows += nxt.n
-            if nxt.deadline is not None:
-                deadline = min(deadline, nxt.deadline)
-        return batch
+        latency budget / until bucket-max; ``None`` = stop. The
+        ``serve/gather`` hot interval spans the whole call; ``idle_s``
+        is the part spent blocked before the first request arrived."""
+        with obs.interval("serve/gather") as gathered:
+            first = self._carry
+            self._carry = None  # fmlint: disable=thread-lock-discipline -- coalescer-thread-local carry: only the single worker thread (_run/_gather) ever touches it
+            if first is None:
+                first = self._queue.get()
+            gathered.set(idle_s=time.perf_counter() - gathered.t0)
+            if first is _STOP:
+                return None
+            batch = [first]
+            rows = first.n
+            cap = self.buckets[-1]
+            deadline = time.monotonic() + self.latency_budget_s
+            if first.deadline is not None:
+                deadline = min(deadline, first.deadline)
+            while rows < cap:
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    break
+                try:
+                    nxt = self._queue.get(timeout=remaining)
+                except queue.Empty:
+                    break
+                if nxt is _STOP:
+                    # Finish this batch, then stop: queued requests are
+                    # answered, never dropped.
+                    self._queue.put(_STOP)
+                    break
+                if rows + nxt.n > cap:
+                    self._carry = nxt  # fmlint: disable=thread-lock-discipline -- heads the next batch; coalescer-thread-local (single worker thread)
+                    break
+                batch.append(nxt)
+                rows += nxt.n
+                if nxt.deadline is not None:
+                    deadline = min(deadline, nxt.deadline)
+            gathered.set(rows=rows, requests=len(batch))
+            return batch
 
     def _run(self) -> None:
         while True:
@@ -434,14 +436,14 @@ class PredictEngine:
             # dispatch — and every response split from it — scores on
             # the same params (the no-torn-swap contract).
             gen = self._gen  # fmlint: disable=thread-lock-discipline -- single atomic reference read per micro-batch IS the protocol (no-torn-swap contract)
-            ids = (batch[0].ids if len(batch) == 1 else
-                   np.concatenate([r.ids for r in batch]))
-            vals = (batch[0].vals if len(batch) == 1 else
-                    np.concatenate([r.vals for r in batch]))
-            exec_info: dict = {}
+            with obs.interval("serve/assemble", requests=len(batch)):
+                ids = (batch[0].ids if len(batch) == 1 else
+                       np.concatenate([r.ids for r in batch]))
+                vals = (batch[0].vals if len(batch) == 1 else
+                        np.concatenate([r.vals for r in batch]))
             try:
-                out = self._execute(gen, ids, vals,
-                                    exec_info=exec_info)
+                out, executed = self._execute(gen, ids, vals,
+                                              requests=len(batch))
             except BaseException as e:  # noqa: BLE001 — every queued
                 # caller must be answered (exactly once), even by the
                 # failure; HangDetected and injected faults land here.
@@ -499,37 +501,47 @@ class PredictEngine:
                 for r in batch:
                     r.future._set_exception(e)
                 continue
-            off = 0
-            t_done = time.perf_counter()
+            # Every request's queue / execute / split, from the stamps
+            # of its batch's ``serve/batch`` interval: ``serve/queue``
+            # (submit to execute start, parented to that batch) for all
+            # of them in the always-live ring, and for a traced request
+            # the ``serve/coalesce`` link span built from the same
+            # three numbers.
+            exec_sid = executed.span_id
+            t_exec0, t_exec1 = executed.t0, executed.t1
             hist = obs.histogram("serve/request_ms")
-            exec_sid = exec_info.get("span_id")
-            t_exec0 = exec_info.get("t0", t_done)
-            t_exec1 = exec_info.get("t1", t_done)
-            for r in batch:
-                r.future._set(out[off:off + r.n])
-                off += r.n
-                lat_ms = (t_done - r.t_submit) * 1e3
-                hist.observe(lat_ms,
-                             exemplar=(r.trace.trace_id
-                                       if r.trace is not None
-                                       else None))
-                if r.trace is not None:
-                    # One link span per coalesced request: the
-                    # request's queue-to-split window, joined to the
-                    # SHARED ``serve/batch`` span via ``exec_span``
-                    # (N requests, one execute — the coalescing
-                    # topology stays visible in the merged trace).
-                    obs.emit_span(
-                        "serve/coalesce", r.t_wall,
-                        t_done - r.t_submit,
-                        trace=r.trace.trace_id,
-                        remote_parent=r.trace.parent_span_id,
-                        exec_span=exec_sid,
-                        queue_ms=round(
-                            (t_exec0 - r.t_submit) * 1e3, 3),
-                        exec_ms=round((t_exec1 - t_exec0) * 1e3, 3),
-                        split_ms=round((t_done - t_exec1) * 1e3, 3),
-                        rows=r.n)
+            with obs.interval("serve/split", requests=len(batch)):
+                off = 0
+                t_done = time.perf_counter()
+                for r in batch:
+                    r.future._set(out[off:off + r.n])
+                    off += r.n
+                    lat_ms = (t_done - r.t_submit) * 1e3
+                    hist.observe(lat_ms,
+                                 exemplar=(r.trace.trace_id
+                                           if r.trace is not None
+                                           else None))
+                    obs.record_interval("serve/queue", r.t_submit,
+                                        t_exec0, parent_id=exec_sid,
+                                        rows=r.n)
+                    if r.trace is not None:
+                        # One link span per coalesced request: the
+                        # request's queue-to-split window, joined to
+                        # the SHARED ``serve/batch`` span via
+                        # ``exec_span`` (N requests, one execute — the
+                        # coalescing topology stays visible in the
+                        # merged trace).
+                        obs.emit_span(
+                            "serve/coalesce", r.t_wall,
+                            t_done - r.t_submit,
+                            trace=r.trace.trace_id,
+                            remote_parent=r.trace.parent_span_id,
+                            exec_span=exec_sid,
+                            queue_ms=round(
+                                (t_exec0 - r.t_submit) * 1e3, 3),
+                            exec_ms=round((t_exec1 - t_exec0) * 1e3, 3),
+                            split_ms=round((t_done - t_exec1) * 1e3, 3),
+                            rows=r.n)
 
     def close(self) -> None:
         """Stop the coalescer after answering everything queued."""
