@@ -167,11 +167,19 @@ def _train(ctx, extra, model_out=None) -> dict:
     want = -(-39 // n)          # padded fields per chip: 10 of 40 on four
     check(len(fields) == n and set(fields.values()) == {want},
           f"each of {n} chips should hold {want} field slots: {fields}")
+    if n == 1:
+        # The one-chip loop holds its tables row-major (lane-padded):
+        # any other layout is two whole-table copies a table a step.
+        check(placed["table_layouts"] == [[0, 1]],
+              f"tables are not row-major on the chip: "
+              f"{placed['table_layouts']}")
     return {
         "argv": argv[1:],
         "losses": losses,
         "fields_per_device": fields,
         "param_bytes_per_device": placed["param_bytes_per_device"],
+        "table_layouts": placed["table_layouts"],
+        "table_device_bytes": placed["table_device_bytes"],
         "memory_after_placement": placed["memory"],
         "memory_after_last_step": first(docs, "memory_after_fit"),
     }

@@ -660,11 +660,18 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
                 *_data_prep(b[:4]), place_compact_aux(b[4], mesh),
             )
     else:
+        from fm_spark_tpu.sparse import pad_field_tables
+
         built = cap.single_step(spec, tconfig)
         step = built if is_deepfm else adapt(built)
-        params, opt = canonical, opt0
-        prep = host
-        to_canonical = lambda p: p
+        # The loop holds its tables lane-padded (row-major on the chip
+        # by default: no table is transposed in and out of a step),
+        # padded once here, each canonical table let go as its padded
+        # one arrives. What leaves the loop — evals, checkpoints, the
+        # returned model — is canonical again: ``to_canonical`` cuts
+        # exactly the tables padded here (the identity where none was).
+        params, to_canonical = pad_field_tables(canonical)
+        opt, prep = opt0, host
 
     return step, params, opt, prep, to_canonical, mesh
 
@@ -764,10 +771,13 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                                      layout="sharded")
     # Where the tables landed, and what each device's memory looked like
     # once they had (chip_smoke.py checks both on the chip).
+    from fm_spark_tpu import obs
     from fm_spark_tpu.utils import device as device_lib
 
-    print(json.dumps({"placement": device_lib.placement(params)}),
-          flush=True)
+    placed = device_lib.placement(params)
+    print(json.dumps({"placement": placed}), flush=True)
+    obs.event("table_layout", table_layouts=placed["table_layouts"],
+              table_device_bytes=placed["table_device_bytes"])
 
     sharded_eval = None
     if (sharded and eval_source is not None and tconfig.eval_every > 0):
@@ -902,7 +912,6 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         # batches that would never train (exact-resume cursor).
         batches = StackedBatches(batches, steps_per_call,
                                  total=tconfig.num_steps - start)
-    from fm_spark_tpu import obs
     from fm_spark_tpu.resilience import faults
 
     batches, close_prefetch = wrap_prefetch(batches, prefetch)
@@ -992,7 +1001,11 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         close_prefetch()
     print(json.dumps({"memory_after_fit": device_lib.memory()}),
           flush=True)
-    return to_canonical(params)
+    if sharded:
+        return to_canonical(params)
+    # Nothing reads the loop's own tables after this: each padded one
+    # goes as its canonical one arrives (never two generations).
+    return to_canonical(params, release=True)
 
 
 def _fit_field_sparse_elastic(spec, tconfig, batches, checkpointer,

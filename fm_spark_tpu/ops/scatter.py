@@ -331,6 +331,18 @@ def device_compact_aux(ids_col, cap: int):
     return (useg, segstart, segend, order, inv), nseg
 
 
+def _to_table_width(rows, table, col: bool = False):
+    """``rows`` ([n, w] values about to be written into ``table``)
+    zero-padded to the table's width. The one-chip training loop holds
+    a narrow row table lane-padded (sparse.pad_field_tables: zero
+    columns that stay zero, because only zeros are ever written there);
+    the arithmetic before a write runs at the model's width and only the
+    write pays the lanes. ``rows`` itself for a table no wider (and for
+    a ``col`` table, ``[w, n]``, never padded)."""
+    extra = 0 if col else table.shape[1] - rows.shape[1]
+    return jnp.pad(rows, ((0, 0), (0, extra))) if extra else rows
+
+
 def compact_gather(table, useg, col: bool = False):
     """Forward half of the compact path: gather each unique id's row
     once — ``cap`` ascending lanes against the big table (sentinels clip
@@ -420,7 +432,7 @@ def _compact_write(table, segsum, useg, mode, key, urows, col):
     totals) and :func:`compact_apply_totals` (the fused Pallas
     backward's totals) so the write semantics can never drift."""
     if mode == "dedup":
-        upd = segsum.astype(table.dtype)
+        upd = _to_table_width(segsum.astype(table.dtype), table, col)
         if col:
             return table.at[:, useg].add(
                 upd.T, mode="drop",
@@ -433,7 +445,8 @@ def _compact_write(table, segsum, useg, mode, key, urows, col):
     if key is None or urows is None:
         raise ValueError("dedup_sr needs key= and urows=")
     new_rows = urows.astype(jnp.float32) + segsum
-    vals = stochastic_round(new_rows, table.dtype, key)
+    vals = _to_table_width(
+        stochastic_round(new_rows, table.dtype, key), table, col)
     if col:
         return table.at[:, useg].set(
             vals.T, mode="drop",
@@ -470,12 +483,14 @@ def _aux_apply(table, delta, aux, mode, key, old_rows):
         indices_are_sorted=True,
     )
     if mode == "dedup":
-        return table.at[useg].add(summed.astype(table.dtype), mode="drop")
+        return table.at[useg].add(
+            _to_table_width(summed.astype(table.dtype), table), mode="drop")
     new_rows = (
         old_rows[ord_first].astype(jnp.float32) + summed.astype(jnp.float32)
     )
     return table.at[useg].set(
-        stochastic_round(new_rows, table.dtype, key), mode="drop"
+        _to_table_width(stochastic_round(new_rows, table.dtype, key), table),
+        mode="drop",
     )
 
 
@@ -563,22 +578,24 @@ def apply_row_updates(
             raise ValueError("dedup_sr needs key= and old_rows=")
         return _aux_apply(table, delta, aux, mode, key, old_rows)
     if use_pallas and mode in ("scatter_add", "dedup"):
-        return _pallas_dedup_add(table, ids, delta)
+        return _pallas_dedup_add(table, ids, _to_table_width(delta, table))
     if mode == "scatter_add":
         # mode="drop" is XLA's default scatter OOB semantics, made
         # explicit: the 2-D field-sharded step routes non-owned lanes to
         # an out-of-bounds sentinel index that MUST be dropped.
-        return table.at[ids].add(delta.astype(table.dtype), mode="drop")
+        return table.at[ids].add(
+            _to_table_width(delta.astype(table.dtype), table), mode="drop")
 
     sid, summed, run_start, order = _dedup(ids, delta)
     oob = jnp.where(run_start, sid, n)  # non-run-start lanes are dropped
     if mode == "dedup":
         upd = jnp.where(run_start[:, None], summed, 0.0)
-        return table.at[oob].add(upd.astype(table.dtype), mode="drop")
+        return table.at[oob].add(
+            _to_table_width(upd.astype(table.dtype), table), mode="drop")
 
     if key is None or old_rows is None:
         raise ValueError("dedup_sr needs key= and old_rows=")
     # One representative old row per segment (duplicates share the row).
     new_rows = old_rows[order].astype(jnp.float32) + summed.astype(jnp.float32)
     vals = stochastic_round(new_rows, table.dtype, key)
-    return table.at[oob].set(vals, mode="drop")
+    return table.at[oob].set(_to_table_width(vals, table), mode="drop")

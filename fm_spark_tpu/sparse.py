@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from fm_spark_tpu.ops import losses as losses_lib
+from fm_spark_tpu.ops.vmem import LANES
 from fm_spark_tpu.train import TrainConfig
 
 
@@ -119,7 +120,8 @@ def _check_host_dedup(config: TrainConfig, loss: str):
                          "exclusive")
 
 
-def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False):
+def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False,
+                        width=None):
     """COMPACT forward table access (``config.compact_cap`` > 0): gather
     each field's ``cap`` unique rows once from the big table, expand
     per-lane rows from the small [cap, w] buffer via the inverse map
@@ -133,13 +135,17 @@ def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False):
     raise — expand to ZERO rows (absent-feature drop semantics) instead
     of whatever the clipped expansion gather returns. The host builder
     guarantees ``inv < cap``, so its callers skip the extra [B, w]
-    multiply."""
+    multiply.
+
+    ``width``: the model's row width where a table may be held wider
+    (:func:`pad_field_tables`); the ``cap`` whole rows are gathered,
+    then cut, so ``urows`` and ``rows`` are ``width`` wide."""
     from fm_spark_tpu.ops import scatter as scatter_lib
 
     useg, inv = aux[0], aux[4]
     cap = useg.shape[-1]
     urows = [
-        scatter_lib.compact_gather(t, useg[f], col=col)
+        scatter_lib.compact_gather(t, useg[f], col=col)[:, :width]
         for f, t in enumerate(tables)
     ]
     rows = []
@@ -226,7 +232,7 @@ def _fold_overflow(loss, ovf, config: TrainConfig):
     return jnp.where(ovf > 0, jnp.float32(-jnp.inf), loss)
 
 
-def _rows_for(compact, tables, aux, cd, gat, ids, col=False,
+def _rows_for(compact, tables, aux, cd, gat, ids, width, col=False,
               device_cap: int = 0):
     """The fused bodies' shared forward table access: the compact
     cap-lane path (host- or device-built aux) or the plain per-lane
@@ -234,17 +240,25 @@ def _rows_for(compact, tables, aux, cd, gat, ids, col=False,
     None on the plain path; ``aux`` is echoed (host) or freshly built
     (device) so the update half consumes one object either way. One
     definition so the three fused factories (FM/FFM/DeepFM) can never
-    drift."""
+    drift. ``urows`` and ``rows`` are ``width`` wide, the model's,
+    whatever the tables are: a lane-padded table
+    (:func:`pad_field_tables`) gives up whole rows (asking the gather
+    for the leading columns only, ``table[idx, :width]``, compiles to a
+    serial loop of one dynamic-slice a row on the TPU, 10-50x slower:
+    PERF.md §6, PR 27) and they are cut here, so the bodies' arithmetic
+    never sees the padding; the writes (ops/scatter) pad it back."""
     if device_cap > 0:
         aux, ovf = _device_compact_aux_all(ids, device_cap,
                                            len(tables))
         urows, rows = _compact_gather_all(tables, aux, cd, col=col,
-                                          mask_overflow=True)
+                                          mask_overflow=True, width=width)
         return urows, rows, aux, ovf
     if compact:
-        urows, rows = _compact_gather_all(tables, aux, cd, col=col)
+        urows, rows = _compact_gather_all(tables, aux, cd, col=col,
+                                          width=width)
         return urows, rows, aux, None
-    return None, _gather_all(gat, tables, ids, cd), aux, None
+    rows = [r[:, :width] for r in _gather_all(gat, tables, ids, cd)]
+    return None, rows, aux, None
 
 
 def _updates_for(compact, tables, ids, g_fulls, rows, urows,
@@ -616,6 +630,109 @@ def _gather_all(gat, tables, ids, cd):
     return [gat(tables[f], ids[:, f]).astype(cd) for f in range(len(tables))]
 
 
+# --------------------------------------------------------------------------
+# Where the per-field tables sit on the chip.
+#
+# The TPU's default layout for a tall narrow ``f32[N, w]`` puts dimension
+# 0 minor unless w is a whole number of 128-lane tiles, and XLA's gather
+# and scatter read rows: a step handed such tables transposes each into
+# a temporary before its gather and transposes the updated table back
+# for its result, two whole-table copies a table a step (PERF.md §5: 12%
+# of config 3's step, 50% of avazu's). Stating a row-major layout on the
+# jit (``jax.experimental.layout.Format``) removes them, but an
+# executable READ BACK from the persistent compile cache returns its
+# results in the default layout again (jax 0.9.0 / libtpu 0.0.34; PERF.md
+# §6), and every entry point runs with that cache on. So the one-chip
+# loop holds such tables in the one shape whose DEFAULT layout is
+# row-major: the width padded with zero columns to whole lanes. That is
+# byte for byte what a row-major ``[N, w]`` occupies (its rows pad to
+# 128 lanes too); gathered rows are cut to the model's w columns
+# (_rows_for) and every write pads its rows back with zeros
+# (ops/scatter), so the arithmetic between runs at the model's width and
+# the padding stays zero. Where the device's default is row-major
+# already (the CPU; a width of whole lanes; a ``table_layout='col'``
+# ``[w, N]`` table) nothing is padded.
+# --------------------------------------------------------------------------
+
+def _is_row_table(path, leaf) -> bool:
+    """A per-field table indexed by row: a rank-2 leaf under ``vw``."""
+    return path[0].key == "vw" and len(leaf.shape) == 2
+
+
+@functools.lru_cache(maxsize=None)
+def _default_is_row_major(shape, dtype, device) -> bool:
+    """Does ``device`` lay a ``dtype[shape]`` array out row-major when
+    nobody says how? Asked of the compiler (a program that only makes
+    such an array), so it holds for a described device too."""
+    made = jax.jit(
+        lambda: jnp.zeros(shape, dtype),
+        out_shardings=jax.sharding.SingleDeviceSharding(device),
+    ).lower().compile()
+    layout = made.output_formats.layout
+    return layout is None or (
+        tuple(layout.major_to_minor) == tuple(range(len(shape))))
+
+
+def pad_field_tables(params, device=None):
+    """A one-chip parameter tree as the training loop holds it, and the
+    way back: ``(padded, unpad)``. Every row table that ``device`` would
+    not lay out row-major by default is padded with zero columns to a
+    whole number of lanes; every other leaf comes back as it is.
+    ``unpad(tree)`` gives the canonical tree (what ``spec.init`` gives,
+    checkpoints hold and the models score) of ``padded`` or of what a
+    step made of it: exactly the tables padded here, cut to the width
+    they came with (``release=True``: the caller is done with ``tree``,
+    and each padded table goes as soon as its cut is on the device).
+    The tables passed in are CONSUMED: each is deleted as soon as its
+    padded one is on the device, so no second copy of the tables stands
+    beside the first. ``device`` None is where a table is (the default
+    device for NumPy arrays and bare shapes). Works on shapes
+    (``jax.ShapeDtypeStruct``) as on arrays."""
+    widths = {}                 # path of each table padded -> its width
+
+    def pad(path, leaf):
+        if not _is_row_table(path, leaf) or leaf.shape[1] % LANES == 0:
+            return leaf
+        on = device
+        if on is None:
+            on = (next(iter(leaf.devices()))
+                  if isinstance(leaf, jax.Array) else
+                  jax.config.jax_default_device or jax.local_devices()[0])
+        if _default_is_row_major(tuple(leaf.shape), jnp.dtype(leaf.dtype),
+                                 on):
+            return leaf
+        widths[path] = leaf.shape[1]
+        extra = -leaf.shape[1] % LANES
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(
+                (leaf.shape[0], leaf.shape[1] + extra), leaf.dtype,
+                sharding=leaf.sharding)
+        if not isinstance(leaf, jax.Array):
+            return jnp.pad(leaf, ((0, 0), (0, extra)))
+        # Buffers are allocated as work is queued, ahead of the device:
+        # wait for whatever makes the table (an init still in flight
+        # holds its own temporaries) before asking for its padded one,
+        # and for that one before letting the table go.
+        leaf.block_until_ready()
+        padded = jnp.pad(leaf, ((0, 0), (0, extra))).block_until_ready()
+        leaf.delete()
+        return padded
+
+    def unpad(tree, release=False):
+        def cut(path, leaf):
+            if path not in widths:
+                return leaf
+            table = leaf[:, :widths[path]]
+            if release:
+                table.block_until_ready()
+                leaf.delete()
+            return table
+
+        return jax.tree_util.tree_map_with_path(cut, tree)
+
+    return jax.tree_util.tree_map_with_path(pad, params), unpad
+
+
 def make_field_sparse_sgd_body(spec, config: TrainConfig):
     """Unjitted fused-step body for :class:`FieldFMSpec` (see the jitted
     wrapper :func:`make_field_sparse_sgd_step`); exposed separately so
@@ -679,8 +796,8 @@ def make_field_sparse_sgd_body(spec, config: TrainConfig):
             # per-lane rows expanded from the small buffers (the
             # [B]-lane work never touches table-sized operands).
             urows, rows, aux, ovf = _rows_for(
-                compact, params["vw"], aux, cd, gat, ids, col=col,
-                device_cap=device_cap,
+                compact, params["vw"], aux, cd, gat, ids,
+                spec.table_width, col=col, device_cap=device_cap,
             )                                           # F × [B, k+1]
         else:
             urows = None
@@ -901,7 +1018,7 @@ def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
         w0 = params["w0"]
         vals_c = vals.astype(cd)
         urows, rows, aux, ovf = _rows_for(
-            compact, params["vw"], aux, cd, gat, ids,
+            compact, params["vw"], aux, cd, gat, ids, spec.table_width,
             device_cap=config.compact_cap if config.compact_device else 0,
         )                                               # F × [B, F·k+1]
         rstk = None
@@ -1086,7 +1203,7 @@ def make_field_deepfm_sparse_body(spec, config: TrainConfig):
         w0 = params["w0"]
         vals_c = vals.astype(cd)
         urows, rows, aux, ovf = _rows_for(
-            compact, params["vw"], aux, cd, gat, ids,
+            compact, params["vw"], aux, cd, gat, ids, spec.table_width,
             device_cap=config.compact_cap if config.compact_device else 0,
         )                                           # F × [B, k+1]
         if config.gfull_fused:
@@ -1378,20 +1495,25 @@ def _stack_abstract(tree, n: int):
     """Prepend a ``[n, ...]`` stack axis to every leaf (the multistep
     roll's batch layout, data/pipeline.StackedBatches)."""
     return jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct((n, *s.shape), s.dtype), tree
+        lambda s: jax.ShapeDtypeStruct((n, *s.shape), s.dtype,
+                                       sharding=s.sharding), tree
     )
 
 
 def lower_field_sparse_step(spec, config: TrainConfig, batch_size: int,
-                            steps_per_call: int = 1):
+                            steps_per_call: int = 1, device=None):
     """Lower the single-chip fused step for ``spec``'s family — or the
-    ``steps_per_call`` fori roll — against abstract shapes.
+    ``steps_per_call`` fori roll — against abstract shapes, for
+    ``device`` (None: the default device; a described device of a
+    topology lowers for a chip that is not attached).
 
     Returns a ``jax.stages.Lowered``; ``.compile()`` produces the
     executable (and, with the persistent cache enabled, persists it).
     Dispatches FieldFM / FieldFFM / FieldDeepFM exactly like the
-    training loop's builders, so the compiled program is the one the
-    loop's first dispatch would otherwise build on the critical path.
+    training loop's builders, tables padded as the loop holds them on
+    that device (:func:`pad_field_tables`), so the compiled program is
+    the one the loop's first dispatch would otherwise build on the
+    critical path.
     """
     from fm_spark_tpu.models.field_deepfm import FieldDeepFMSpec
     from fm_spark_tpu.models.field_ffm import FieldFFMSpec
@@ -1400,24 +1522,34 @@ def lower_field_sparse_step(spec, config: TrainConfig, batch_size: int,
         raise ValueError(
             f"steps per call must be >= 1, got {steps_per_call}"
         )
-    params_abs = jax.eval_shape(spec.init, jax.random.key(0))
-    batch_abs = abstract_field_batch(spec, batch_size)
-    aux_abs = abstract_host_aux(config, batch_size, spec.num_fields)
-    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    sharding = (None if device is None
+                else jax.sharding.SingleDeviceSharding(device))
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+
+    def on_device(tree):
+        return jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype), tree)
+
+    params_abs, _ = pad_field_tables(
+        on_device(jax.eval_shape(spec.init, jax.random.key(0))), device)
+    batch_abs = on_device(abstract_field_batch(spec, batch_size))
+    aux_abs = on_device(
+        abstract_host_aux(config, batch_size, spec.num_fields))
+    i32 = sds((), jnp.int32)
     multi = steps_per_call > 1
 
     if isinstance(spec, FieldDeepFMSpec):
         if multi:
             mstep = make_field_deepfm_multistep(spec, config,
                                                 steps_per_call)
-            opt_abs = jax.eval_shape(mstep.init_opt_state, params_abs)
+            opt_abs = on_device(
+                jax.eval_shape(mstep.init_opt_state, params_abs))
             return mstep.lower(
                 params_abs, opt_abs, i32, i32,
                 *_stack_abstract(batch_abs, steps_per_call),
                 _stack_abstract(aux_abs, steps_per_call),
             )
         body, init_opt = make_field_deepfm_sparse_body(spec, config)
-        opt_abs = jax.eval_shape(init_opt, params_abs)
+        opt_abs = on_device(jax.eval_shape(init_opt, params_abs))
         step = functools.partial(jax.jit, donate_argnums=(0, 1))(body)
         return step.lower(params_abs, opt_abs, i32, *batch_abs, aux_abs)
 

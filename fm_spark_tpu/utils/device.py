@@ -36,7 +36,10 @@ def placement(params) -> dict:
     parameter bytes each addressable device holds, and — for the field
     families' ``vw`` tables, a per-field list on one chip and one
     stacked ``[F_pad, bucket, w]`` array on a mesh — how many field
-    slots each device holds."""
+    slots each device holds, the distinct on-device layouts of those
+    tables (``major_to_minor``; ``[0, 1]`` is row-major, what the
+    one-chip loop holds: sparse.pad_field_tables) and the bytes they
+    occupy on their devices, lane padding included."""
     import jax
 
     leaves = jax.tree_util.tree_leaves(params)
@@ -46,12 +49,17 @@ def placement(params) -> dict:
             nbytes[shard.device.id] = (
                 nbytes.get(shard.device.id, 0) + shard.data.nbytes)
     fields: dict[int, int] = {}
+    layouts: set[tuple[int, ...]] = set()
+    table_bytes = 0
     vw = params.get("vw") if isinstance(params, dict) else None
-    for table in ([] if vw is None else
-                  vw if isinstance(vw, (list, tuple)) else [vw]):
+    for table in jax.tree_util.tree_leaves(vw):
+        layout = table.format.layout
+        if layout is not None:
+            layouts.add(tuple(layout.major_to_minor))
         for shard in table.addressable_shards:
             held = 1 if table.ndim == 2 else shard.data.shape[0]
             fields[shard.device.id] = fields.get(shard.device.id, 0) + held
+            table_bytes += shard.data.on_device_size_in_bytes()
     return {
         "platforms": sorted({d.platform for leaf in leaves
                              for d in leaf.devices()}),
@@ -59,5 +67,7 @@ def placement(params) -> dict:
                                    for k, v in sorted(nbytes.items())},
         "fields_per_device": {str(k): v
                               for k, v in sorted(fields.items())},
+        "table_layouts": sorted(map(list, layouts)),
+        "table_device_bytes": table_bytes,
         "memory": memory(),
     }
