@@ -217,12 +217,10 @@ CONFIGS = {
             " rank-16 + 3-layer 400-wide MLP on Criteo shapes, on the CTR"
             " fast path: field-partitioned embedding with fused sparse"
             " scatter updates; dense Adam covers only the MLP + bias"
-            " (no table-sized gradients or moment state). Measured"
-            " (1,654,599 samples/s/chip, 2026-07-31): --param-dtype"
-            " bfloat16 --compute-dtype bfloat16 --sparse-update dedup_sr"
-            " --host-dedup --compact-cap 16384; do NOT add --gfull-fused/"
-            "--segtotal-pallas here — both measured LOSERS at rank 16's"
-            " narrow update rows (PERF.md).",
+            " (no table-sized gradients or moment state). The head's"
+            " products run at the declared compute_dtype (float32:"
+            " precision HIGHEST on the TPU). Measured at these defaults"
+            " by the benchmark's cell deepfm_r16.train (PERF.md).",
             model="field_deepfm", dataset="criteo", rank=16, num_fields=39,
             bucket=1 << 18, strategy="field_sparse", num_steps=1_000_000,
             batch_size=16384, learning_rate=1e-3, lr_schedule="constant",

@@ -97,11 +97,23 @@ class FieldDeepFMSpec(base.ModelSpec):
                 for f in range(self.num_fields)]
 
     def deep_scores(self, mlp, h: jax.Array) -> jax.Array:
-        """The MLP head over ``h = concat(xv) [B, F*rank]`` → ``[B]``."""
+        """The MLP head over ``h = concat(xv) [B, F*rank]`` → ``[B]``.
+
+        The one precision rule of the head (forward, the ``jax.vjp``
+        pullback, eval, predict and the sharded step all come through
+        here): its products run at the precision ``compute_dtype``
+        declares. float32 asks for float32 products
+        (``precision=HIGHEST``; on the TPU the default for float32
+        operands is one bfloat16 pass); bfloat16 compute keeps the MXU's
+        single pass.
+        """
         cd = self.cdtype
+        precision = (jax.lax.Precision.HIGHEST if cd == jnp.float32
+                     else None)
         n_hidden = len(self.mlp_dims)
         for li, layer in enumerate(mlp):
-            h = h @ layer["kernel"].astype(cd) + layer["bias"].astype(cd)
+            h = jnp.dot(h, layer["kernel"].astype(cd),
+                        precision=precision) + layer["bias"].astype(cd)
             if li < n_hidden:
                 h = jax.nn.relu(h)
         return h[:, 0]

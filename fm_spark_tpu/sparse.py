@@ -1229,7 +1229,9 @@ def make_field_deepfm_sparse_body(spec, config: TrainConfig):
         wsum = jnp.maximum(jnp.sum(weights), 1.0)
 
         def head_loss(dense, h_in):
-            sc = fm_scores + spec.deep_scores(dense["mlp"], h_in)
+            with jax.named_scope("deep/forward"):
+                deep = spec.deep_scores(dense["mlp"], h_in)
+            sc = fm_scores + deep
             if spec.use_bias:
                 sc = sc + dense["w0"].astype(cd)
             per = per_example_loss(sc, labels) * weights
@@ -1241,7 +1243,9 @@ def make_field_deepfm_sparse_body(spec, config: TrainConfig):
         (loss, scores), vjp = jax.vjp(
             head_loss, dense_subtree(params), h, has_aux=False
         )
-        g_dense, g_h = vjp((jnp.ones_like(loss), jnp.zeros_like(scores)))
+        with jax.named_scope("deep/backward"):
+            g_dense, g_h = vjp(
+                (jnp.ones_like(loss), jnp.zeros_like(scores)))
 
         def batch_loss(sc):
             return jnp.sum(per_example_loss(sc, labels) * weights) / wsum
@@ -1283,17 +1287,18 @@ def make_field_deepfm_sparse_body(spec, config: TrainConfig):
         )
 
         # Dense side: optax on {"w0", "mlp"} only (+ L2 per group).
-        if config.reg_bias:
-            g_dense["w0"] = g_dense["w0"] + config.reg_bias * w0
-        if config.reg_factors:
-            g_dense["mlp"] = jax.tree_util.tree_map(
-                lambda g, p: g + config.reg_factors * p,
-                g_dense["mlp"], params["mlp"],
+        with jax.named_scope("deep/adam"):
+            if config.reg_bias:
+                g_dense["w0"] = g_dense["w0"] + config.reg_bias * w0
+            if config.reg_factors:
+                g_dense["mlp"] = jax.tree_util.tree_map(
+                    lambda g, p: g + config.reg_factors * p,
+                    g_dense["mlp"], params["mlp"],
+                )
+            updates, opt_state = dense_opt.update(
+                g_dense, opt_state, dense_subtree(params)
             )
-        updates, opt_state = dense_opt.update(
-            g_dense, opt_state, dense_subtree(params)
-        )
-        new_dense = optax.apply_updates(dense_subtree(params), updates)
+            new_dense = optax.apply_updates(dense_subtree(params), updates)
         return (
             {"w0": new_dense["w0"], "vw": new_vw, "mlp": new_dense["mlp"]},
             opt_state,

@@ -97,7 +97,6 @@ def _assert_params_close(got, ref, F):
                                    rtol=2e-4, atol=1e-6)
 
 
-@pytest.mark.slow
 def test_fused_step_matches_autodiff_optax():
     F, bucket = 4, 32
     spec = _spec(F, bucket)
