@@ -41,6 +41,11 @@ class ModelSpec:
     # per-field-offset global ids first (cli._field_local).
     field_local_ids = False
 
+    # The parameter keys whose per-field leaves a spec reads by row
+    # through models/rows.gather, and a scorer may therefore hold packed
+    # (serve/tables.py). The flat specs read theirs through ops/fm: none.
+    row_tables = ()
+
     def __post_init__(self):
         if self.task not in ("classification", "regression"):
             raise ValueError(f"unknown task {self.task!r}")

@@ -24,7 +24,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from fm_spark_tpu.models import base
+from fm_spark_tpu.models import base, rows as rows_lib
 from fm_spark_tpu.models.field_fm import FieldFMSpec
 
 
@@ -58,6 +58,7 @@ class FieldDeepFMSpec(base.ModelSpec):
     # take FIELD-LOCAL ids (see FieldFMSpec).
     fused_linear = True
     field_local_ids = True
+    row_tables = ("vw",)
 
     @property
     def table_width(self) -> int:
@@ -93,7 +94,7 @@ class FieldDeepFMSpec(base.ModelSpec):
     def gather_rows(self, params: dict, ids: jax.Array):
         """One gather per field → list of F ``[B, rank+1]`` rows."""
         cd = self.cdtype
-        return [params["vw"][f][ids[:, f]].astype(cd)
+        return [rows_lib.gather(params["vw"][f], ids[:, f]).astype(cd)
                 for f in range(self.num_fields)]
 
     def deep_scores(self, mlp, h: jax.Array) -> jax.Array:

@@ -22,7 +22,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from fm_spark_tpu.models import base
+from fm_spark_tpu.models import base, rows as rows_lib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +42,7 @@ class FieldFFMSpec(base.ModelSpec):
 
     # Tables take FIELD-LOCAL ids (see FieldFMSpec).
     field_local_ids = True
+    row_tables = ("vw",)
 
     def __post_init__(self):
         super().__post_init__()
@@ -79,7 +80,7 @@ class FieldFFMSpec(base.ModelSpec):
         """One gather per field → list of F ``[B, F·k+1]`` rows."""
         cd = self.cdtype
         return [
-            params["vw"][f][ids[:, f]].astype(cd)
+            rows_lib.gather(params["vw"][f], ids[:, f]).astype(cd)
             for f in range(self.num_fields)
         ]
 

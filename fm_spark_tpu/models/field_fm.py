@@ -21,7 +21,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from fm_spark_tpu.models import base
+from fm_spark_tpu.models import base, rows as rows_lib
 from fm_spark_tpu.ops import fm as fm_ops
 
 
@@ -79,6 +79,15 @@ class FieldFMSpec(base.ModelSpec):
     def table_width(self) -> int:
         return self.rank + 1 if self.fused_linear else self.rank
 
+    @property
+    def row_tables(self) -> tuple[str, ...]:
+        """The parameter keys whose per-field leaves are read by row
+        through :func:`rows.gather` (a scorer may hold those packed); a
+        ``col`` table is read by column and is not one."""
+        if self.table_layout == "col":
+            return ()
+        return ("vw",) if self.fused_linear else ("v", "w")
+
     def init(self, rng: jax.Array) -> dict:
         keys = jax.random.split(rng, self.num_fields)
         factors = [
@@ -116,7 +125,8 @@ class FieldFMSpec(base.ModelSpec):
                 tables[f][:, ids[:, f]].astype(cd).T
                 for f in range(self.num_fields)
             ]
-        return [tables[f][ids[:, f]].astype(cd) for f in range(self.num_fields)]
+        return [rows_lib.gather(tables[f], ids[:, f]).astype(cd)
+                for f in range(self.num_fields)]
 
     def scores(self, params: dict, ids: jax.Array, vals: jax.Array) -> jax.Array:
         if ids.shape[1] != self.num_fields:
@@ -137,7 +147,8 @@ class FieldFMSpec(base.ModelSpec):
                 )
             else:
                 lin = sum(
-                    params["w"][f][ids[:, f]].astype(cd) * vals_c[:, f]
+                    rows_lib.gather(params["w"][f], ids[:, f]).astype(cd)
+                    * vals_c[:, f]
                     for f in range(self.num_fields)
                 )
             score = score + lin

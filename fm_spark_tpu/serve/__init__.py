@@ -44,8 +44,6 @@ from fm_spark_tpu.serve.frontdoor import (
     LocalBackend,
     parse_classes,
 )
-from fm_spark_tpu.serve.reload import ReloadFollower
-
 __all__ = [
     "DEFAULT_BUCKETS",
     "AdmissionController",
@@ -58,3 +56,20 @@ __all__ = [
     "ServeFuture",
     "parse_classes",
 ]
+
+
+def __getattr__(name):
+    # ReloadFollower comes on first use (PEP 562): serve.reload imports
+    # fm_spark_tpu.checkpoint and with it orbax, 13-25 s of a scorer's
+    # start-up (PERF.md §5) that a process following no checkpoint
+    # chain never needs.
+    if name == "ReloadFollower":
+        from fm_spark_tpu.serve.reload import ReloadFollower
+
+        globals()[name] = ReloadFollower
+        return ReloadFollower
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def __dir__():
+    return sorted(set(globals()) | set(__all__))

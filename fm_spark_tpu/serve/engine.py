@@ -1,7 +1,8 @@
 """AOT micro-batched predict engine: the low-latency request path.
 
-The training side runs at 1.4M samples/s/chip, but until ISSUE 12 the
-repo could only ``predict`` in offline batch mode. This engine is the
+The training side runs at 0.76M samples/s/chip on the v5e (config 3,
+one chip: PERF.md §5), but until ISSUE 12 the repo could only
+``predict`` in offline batch mode. This engine is the
 millions-of-users half: a warm process answers scoring requests with
 **zero fresh XLA compiles on the request path**, because every
 executable it will ever dispatch is AOT ``lower().compile()``-d at
@@ -62,12 +63,23 @@ DEFAULT_BUCKETS = (1, 8, 64, 512)
 class Generation:
     """One immutable served model generation. The engine holds exactly
     one reference; a swap replaces the reference, never the contents —
-    the single-assignment atomicity the no-torn-swap invariant rides."""
+    the single-assignment atomicity the no-torn-swap invariant rides.
 
-    __slots__ = ("params", "step", "gen_id")
+    ``params`` is the tree the bucket executables take: the model's
+    parameters on the device in SERVING form (:mod:`~fm_spark_tpu.serve.
+    tables`: narrow tables packed or lane-padded where the device would
+    otherwise copy them on every dispatch). ``shapes`` is the canonical
+    tree's ``jax.ShapeDtypeStruct``s — what a checkpoint of this model
+    restores into. No canonical copy is kept; ``tables.unpack(params,
+    shapes)`` is the way back. ``held`` counts what was installed
+    (tables packed, padded, as they were; resident bytes)."""
 
-    def __init__(self, params, step: int, gen_id: int):
+    __slots__ = ("params", "shapes", "held", "step", "gen_id")
+
+    def __init__(self, params, shapes, held, step: int, gen_id: int):
         self.params = params
+        self.shapes = shapes
+        self.held = held
         self.step = int(step)
         self.gen_id = int(gen_id)
 
@@ -165,11 +177,7 @@ class PredictEngine:
         self._predict = jax.jit(
             lambda p, i, v: self.spec.predict(p, i, v))
         self._compiled: dict[int, object] = {}
-        self._gen = Generation(jax.device_put(params), step, gen_id=0)
-        # The live /healthz endpoint (ISSUE 14) reads this gauge; a
-        # fresh engine that never swaps must still report what it
-        # serves, not None.
-        obs.gauge("serve/generation_step").set(self._gen.step)
+        self._install(params, step, gen_id=0)
         self._queue: queue.Queue = queue.Queue()
         self._carry: _Request | None = None
         self._worker: threading.Thread | None = None
@@ -184,23 +192,38 @@ class PredictEngine:
         same read the batch worker performs per micro-batch)."""
         return self._gen
 
+    def _install(self, params, step: int, gen_id: int) -> Generation:
+        """Put canonical ``params`` on the device in serving form
+        (:func:`tables.install`; the caller's arrays are not consumed),
+        off the request path, then make them THE generation by a single
+        reference store — the one way a generation comes to be, at
+        construction and at every swap. The gauges say what this engine
+        serves now: the live /healthz endpoint (ISSUE 14) reads the
+        step, and a fresh engine that never swaps must still report it."""
+        from fm_spark_tpu.serve import tables
+
+        gen = Generation(*tables.install(self.spec, params), step, gen_id)
+        self._gen = gen  # fmlint: disable=thread-lock-discipline -- THE swap: one atomic reference store; worker reads the reference once per batch (no-torn-swap contract, chaos-audited)
+        obs.gauge("serve/generation_step").set(gen.step)
+        for name, value in gen.held.items():
+            obs.gauge(f"serve/{name}").set(value)
+        return gen
+
     def swap_generation(self, params, step: int) -> Generation:
         """Install a new generation via a single reference assignment.
 
         The caller (the reload follower) does all loading/verification
-        OFF the request path first; by the time this runs, the new
-        params are fully materialized, so a concurrent batch sees
-        either the old reference or the new one — never a mixture (the
-        no-torn-swap contract, audited in chaos drills). Requests
-        already batched against the old generation finish on it."""
+        OFF the request path first, and :meth:`_install` has the new
+        params fully materialized in serving form before its store, so
+        a concurrent batch sees either the old reference or the new one
+        — never a mixture (the no-torn-swap contract, audited in chaos
+        drills). Requests already batched against the old generation
+        finish on it."""
         old = self._gen
-        gen = Generation(self._jax.device_put(params), step,
-                         gen_id=old.gen_id + 1)
-        self._gen = gen  # fmlint: disable=thread-lock-discipline -- THE swap: one atomic reference store; worker reads the reference once per batch (no-torn-swap contract, chaos-audited)
+        gen = self._install(params, step, gen_id=old.gen_id + 1)
         obs.counter("serve.swaps_total").add(1)
-        obs.gauge("serve/generation_step").set(gen.step)
         obs.event("serve_swap", step=gen.step, gen_id=gen.gen_id,
-                  from_step=old.step)
+                  from_step=old.step, **gen.held)
         if self.journal is not None:
             self.journal.emit("serve_swap", step=gen.step,
                               gen_id=gen.gen_id, from_step=old.step)
@@ -251,7 +274,8 @@ class PredictEngine:
             "fresh_compiles": stats1["misses"] - stats0["misses"],
         }
         obs.event("serve_warmup", **{k: out[k] for k in
-                                     ("seconds", "fresh_compiles")})
+                                     ("seconds", "fresh_compiles")},
+                  **gen.held)
         return out
 
     # ------------------------------------------------------------ execute

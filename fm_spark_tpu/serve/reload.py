@@ -40,8 +40,10 @@ class ReloadFollower:
     ``opt_state_example`` pins the checkpoint's optimizer-state
     structure (``{}`` for the pure-SGD field_sparse families; the
     caller builds the optax example for families that carry one).
-    ``params_example`` defaults to the engine's own current params —
-    chain generations must share the serving model's structure.
+    ``params_example`` defaults to the canonical shapes of what the
+    engine serves (it holds its tables in another form, never the
+    canonical tree) — chain generations must share the serving model's
+    structure.
     """
 
     def __init__(self, engine, directory: str, *,
@@ -53,7 +55,7 @@ class ReloadFollower:
         self.chain = ChainFollower(directory, journal=journal)
         self._params_example = (params_example if params_example
                                 is not None
-                                else engine.generation().params)
+                                else engine.generation().shapes)
         self._opt_example = ({} if opt_state_example is None
                              else opt_state_example)
         self._stop = threading.Event()

@@ -970,3 +970,58 @@ def test_cli_serve_metrics_port_live_round_trip(tmp_path):
 
 def test_default_buckets_sane():
     assert DEFAULT_BUCKETS == (1, 8, 64, 512)
+
+
+# ------------------------------------------- what importing the package loads
+
+_IMPORT_PROBE = """
+import json, sys
+from fm_spark_tpu.serve import PredictEngine
+import fm_spark_tpu.serve as serve
+heavy = ("orbax.checkpoint", "fm_spark_tpu.checkpoint",
+         "fm_spark_tpu.serve.reload")
+out = {"loaded_by_the_engine": [m for m in heavy if m in sys.modules],
+       "listed": "ReloadFollower" in dir(serve)}
+from fm_spark_tpu.serve import ReloadFollower
+from fm_spark_tpu.serve.reload import ReloadFollower as there
+out["the_class"] = (ReloadFollower is there and isinstance(there, type)
+                    and serve.ReloadFollower is there)
+out["loaded_by_the_follower"] = [m for m in heavy if m in sys.modules]
+out["in_all"] = "ReloadFollower" in serve.__all__
+out["all_resolves"] = all(hasattr(serve, name) for name in serve.__all__)
+try:
+    serve.no_such_name
+    out["unknown"] = "no error"
+except AttributeError as e:
+    out["unknown"] = str(e)
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def import_probe():
+    """One fresh interpreter: what ``from fm_spark_tpu.serve import
+    PredictEngine`` loads, and what asking for the follower then does."""
+    out = subprocess.run(
+        [sys.executable, "-c", _IMPORT_PROBE], capture_output=True,
+        text=True, timeout=300, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("key,want", [
+    # A scorer that follows no checkpoint chain never loads the
+    # checkpoint library (13-25 s of its start-up on the chip's host).
+    ("loaded_by_the_engine", []),
+    ("listed", True),
+    ("the_class", True),
+    ("loaded_by_the_follower", ["orbax.checkpoint", "fm_spark_tpu.checkpoint",
+                                "fm_spark_tpu.serve.reload"]),
+    ("in_all", True),
+    ("all_resolves", True),
+    ("unknown", "module 'fm_spark_tpu.serve' has no attribute 'no_such_name'"),
+])
+def test_importing_the_engine_loads_no_checkpoint_library(
+        import_probe, key, want):
+    assert import_probe[key] == want

@@ -173,7 +173,7 @@ def test_reload_follower_counters_are_race_safe(tmp_path):
     from fm_spark_tpu.serve.reload import ReloadFollower
 
     class _Gen:
-        params = {"w": 1.0}
+        shapes = {"w": 1.0}        # the canonical tree the chain restores into
         step = 0
 
     class _Engine:
