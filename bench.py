@@ -133,7 +133,7 @@ def _renormalize_results(results, prev_chips, n_chips):
 
 def default_variants(model, batch):
     """The default sweep's staged A/B grid: ``(head, tail)`` lists of
-    ``(label, (param_dtype, compute_dtype, table_layout), TrainConfig)``.
+    ``(label, (param_dtype, compute_dtype), TrainConfig)``.
 
     ``head`` goes BEFORE the fp32/scatter_add reference variant, ordered
     by salvage value (a flaky attachment dying mid-sweep keeps the
@@ -141,8 +141,8 @@ def default_variants(model, batch):
     2026-07-31 — floor-cap + gfull + segtotal, PERF.md round-5 table),
     the cap-ladder legs as the ongoing A/B, the two single-lever
     legs, the round-3 winner closing the 2x2 grid, and the secondary
-    probes (devaux = the multi-chip-composable denominator; colT =
-    thrice-neutral, kept for drift detection). ``tail`` goes after it
+    probe (devaux = the multi-chip-composable denominator). ``tail``
+    goes after it
     (the dtype ladder).
 
     Module-level (not inlined in inner_main) so tests can pin the
@@ -170,9 +170,9 @@ def default_variants(model, batch):
                     host_dedup=True, compact_cap=cap)
         return [], [
             (f"bfloat16/dedup_sr/compact{cap}/cd-bf16",
-             ("bfloat16", "bfloat16", None), TrainConfig(**base)),
+             ("bfloat16", "bfloat16"), TrainConfig(**base)),
             (f"bfloat16/dedup_sr/compact{cap}/cd-bf16/gfull/segtotal",
-             ("bfloat16", "bfloat16", None),
+             ("bfloat16", "bfloat16"),
              TrainConfig(**base, gfull_fused=True, segtotal_pallas=True)),
         ]
     if model == "ffm":
@@ -189,7 +189,7 @@ def default_variants(model, batch):
         ffm_base = dict(learning_rate=0.05, lr_schedule="constant",
                         optimizer="sgd")
         return [
-            ("float32/scatter_add/cd-bf16", ("float32", "bfloat16", None),
+            ("float32/scatter_add/cd-bf16", ("float32", "bfloat16"),
              TrainConfig(**ffm_base, sparse_update="scatter_add")),
             # Round-5 staged A/B (unpriced — needs a chip window): the
             # sel-blocked body never materializes the [B, F, F, k]
@@ -198,7 +198,7 @@ def default_variants(model, batch):
             # measured +23% — so the expected effect is of that order
             # if the step is still sel-bandwidth-bound).
             ("float32/scatter_add/cd-bf16/selblk",
-             ("float32", "bfloat16", None),
+             ("float32", "bfloat16"),
              TrainConfig(**ffm_base, sparse_update="scatter_add",
                          sel_blocked=True)),
             # ISSUE 8: the sel-blocked body as Pallas kernels — the
@@ -208,11 +208,11 @@ def default_variants(model, batch):
             # no-Pallas attachment skips rather than silently pricing
             # the XLA body under this label.
             ("float32/scatter_add/cd-bf16/selblk-pallas",
-             ("float32", "bfloat16", None),
+             ("float32", "bfloat16"),
              TrainConfig(**ffm_base, sparse_update="scatter_add",
                          sel_blocked=True, fused_embed="require")),
         ], [
-            ("bfloat16/dedup_sr", ("bfloat16", "bfloat16", None),
+            ("bfloat16/dedup_sr", ("bfloat16", "bfloat16"),
              TrainConfig(**ffm_base, sparse_update="dedup_sr")),
         ]
     if model == "fm_kaggle":
@@ -226,14 +226,14 @@ def default_variants(model, batch):
         kbase = dict(learning_rate=0.05, lr_schedule="constant",
                      optimizer="sgd")
         return [
-            ("float32/scatter_add/cd-bf16", ("float32", "bfloat16", None),
+            ("float32/scatter_add/cd-bf16", ("float32", "bfloat16"),
              TrainConfig(**kbase, sparse_update="scatter_add")),
             (f"bfloat16/dedup_sr/compact{cap}/cd-bf16",
-             ("bfloat16", "bfloat16", None),
+             ("bfloat16", "bfloat16"),
              TrainConfig(**kbase, sparse_update="dedup_sr",
                          host_dedup=True, compact_cap=cap)),
         ], [
-            ("bfloat16/dedup_sr", ("bfloat16", "bfloat16", None),
+            ("bfloat16/dedup_sr", ("bfloat16", "bfloat16"),
              TrainConfig(**kbase, sparse_update="dedup_sr")),
         ]
     # FM headline (PERF.md "the compact lever": scatter cost is
@@ -271,15 +271,15 @@ def default_variants(model, batch):
             (f"bfloat16/dedup_sr/compact{floor_cap}/cd-bf16/gfull"
              "/segtotal",
              dict(compact_cap=floor_cap, gfull_fused=True,
-                  segtotal_pallas=True), None))
+                  segtotal_pallas=True)))
     if tight < cap:
         ranked.append(
             (f"bfloat16/dedup_sr/compact{tight}/cd-bf16/gfull/segtotal",
              dict(compact_cap=tight, gfull_fused=True,
-                  segtotal_pallas=True), None))
+                  segtotal_pallas=True)))
     ranked += [
         (f"bfloat16/dedup_sr/compact{cap}/cd-bf16/gfull/segtotal",
-         dict(gfull_fused=True, segtotal_pallas=True), None),
+         dict(gfull_fused=True, segtotal_pallas=True)),
     ]
     # Fused Pallas backward (ISSUE 8, ROADMAP item 4): the challenger
     # for the sel/dsel/dv HBM traffic the round-5 cd-bf16 probe priced
@@ -294,13 +294,13 @@ def default_variants(model, batch):
     # path under a fused label — the fallback-never-keep-bests rule.
     ranked.insert(1, (
         f"bfloat16/dedup_sr/compact{floor_cap}/cd-bf16/fusedbwd",
-        dict(compact_cap=floor_cap, fused_embed="require"), None))
+        dict(compact_cap=floor_cap, fused_embed="require")))
     ranked += [
         (f"bfloat16/dedup_sr/compact{cap}/cd-bf16/gfull",
-         dict(gfull_fused=True), None),
+         dict(gfull_fused=True)),
         (f"bfloat16/dedup_sr/compact{cap}/cd-bf16/segtotal",
-         dict(segtotal_pallas=True), None),
-        (f"bfloat16/dedup_sr/compact{cap}/cd-bf16", {}, None),
+         dict(segtotal_pallas=True)),
+        (f"bfloat16/dedup_sr/compact{cap}/cd-bf16", {}),
         # devaux = the multi-chip-composable denominator (in-step aux
         # build; the only compact form that composes with scale-out —
         # PERF.md round 3). Measured at the floor cap WITH the composed
@@ -311,16 +311,15 @@ def default_variants(model, batch):
          "/gfull/segtotal",
          dict(host_dedup=False, compact_device=True,
               compact_cap=floor_cap,
-              gfull_fused=True, segtotal_pallas=True), None),
-        (f"bfloat16/dedup_sr/compact{cap}/cd-bf16/colT", {}, "col"),
+              gfull_fused=True, segtotal_pallas=True)),
     ]
     head = [
-        (label, ("bfloat16", "bfloat16", layout),
+        (label, ("bfloat16", "bfloat16"),
          TrainConfig(**{**base, **extra}))
-        for label, extra, layout in ranked
+        for label, extra in ranked
     ]
     tail = [
-        (f"{dt}/{su}/compact{cap}", (dt, None, None),
+        (f"{dt}/{su}/compact{cap}", (dt, None),
          TrainConfig(learning_rate=0.05, lr_schedule="constant",
                      optimizer="sgd", sparse_update=su,
                      host_dedup=True, compact_cap=cap))
@@ -716,15 +715,11 @@ def inner_main(args):
         # per-field buckets, rank 16.
         num_fields, bucket = 23, 1 << 14
         rank = args.rank or DEFAULT_RANK["ffm"]
-        if args.table_layout != "row":
-            raise SystemExit("--table-layout col is a FieldFM lever")
     elif args.model == "deepfm":
         # Config 5's shape (configs.criteo1tb_deepfm): 39 fields,
         # 262144 buckets, rank 16, 3x400 MLP head on dense Adam.
         num_fields, bucket = 39, 1 << 18
         rank = args.rank or DEFAULT_RANK["deepfm"]
-        if args.table_layout != "row":
-            raise SystemExit("--table-layout col is a FieldFM lever")
     elif args.model == "fm_kaggle":
         # Config 2's shape (configs.criteo_kaggle_fm_r32): 39 fields,
         # 32768 per-field buckets, rank 32 — per-field tables are SMALL
@@ -739,7 +734,7 @@ def inner_main(args):
     steps_warmup = 3
     steps_timed = args.steps
 
-    def make_spec(param_dtype, compute_dtype=None, table_layout=None):
+    def make_spec(param_dtype, compute_dtype=None):
         if args.model == "ffm":
             return models.FieldFFMSpec(
                 num_features=num_fields * bucket, rank=rank,
@@ -760,7 +755,6 @@ def inner_main(args):
             num_fields=num_fields, bucket=bucket, init_std=0.01,
             param_dtype=param_dtype,
             compute_dtype=compute_dtype or args.compute_dtype,
-            table_layout=table_layout or args.table_layout,
         )
 
     rng = np.random.default_rng(0)
@@ -780,7 +774,6 @@ def inner_main(args):
                       or args.use_pallas
                       or args.host_dedup or args.param_dtype != "float32"
                       or args.compute_dtype != "float32"
-                      or args.table_layout != "row"
                       or args.compact_cap
                       or args.compact_device or args.gfull_fused
                       or args.segtotal_pallas
@@ -800,14 +793,13 @@ def inner_main(args):
            else "/hostdedup" if args.host_dedup else "")
         + ("/devaux" if args.compact_device else "")
         + ("/cd-bf16" if args.compute_dtype == "bfloat16" else "")
-        + ("/colT" if args.table_layout == "col" else "")
         + ("/gfull" if args.gfull_fused else "")
         + ("/segtotal" if args.segtotal_pallas else "")
         + (f"/fused-{args.fused_embed}" if args.fused_embed != "off"
            else "")
         + (f"/tier-{args.embed_tier}" if args.embed_tier != "off"
            else ""),
-        (args.param_dtype, None, None),
+        (args.param_dtype, None),
         TrainConfig(learning_rate=0.05, lr_schedule="constant",
                     optimizer="sgd", sparse_update=args.sparse_update,
                     use_pallas=args.use_pallas, host_dedup=args.host_dedup,
@@ -1693,12 +1685,6 @@ def main():
                     choices=["float32", "bfloat16"],
                     help="forward/backward buffer dtype (the [B, w] "
                          "passes; storage stays --param-dtype)")
-    ap.add_argument("--table-layout", default="row", dest="table_layout",
-                    choices=["row", "col"],
-                    help="physical table orientation; col = transposed "
-                         "[width, bucket] (no minor-dim lane padding -> "
-                         "~2x fewer physical table bytes; needs the "
-                         "compact path)")
     ap.add_argument("--sparse-update", default="scatter_add",
                     choices=["scatter_add", "dedup", "dedup_sr"])
     ap.add_argument("--use-pallas", action="store_true", dest="use_pallas",
@@ -1882,16 +1868,10 @@ def main():
     _MODEL_NAME = args.model
     _LEDGER_PATH = os.path.join(_artifacts_dir(args), "obs",
                                 "ledger.jsonl")
-    # Config errors must fail HERE, not in the child: the parent treats
-    # a child death as a retryable attachment flake and would burn the
-    # whole --total-deadline re-spawning a guaranteed failure.
-    if args.model == "ffm" and args.table_layout != "row":
-        raise SystemExit("--table-layout col is a FieldFM lever")
     argv = [
         "--model", args.model,
         "--param-dtype", args.param_dtype,
         "--compute-dtype", args.compute_dtype,
-        "--table-layout", args.table_layout,
         "--sparse-update", args.sparse_update,
         "--batch", str(args.batch),
         "--steps", str(args.steps),

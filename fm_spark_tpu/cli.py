@@ -660,17 +660,22 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
                 *_data_prep(b[:4]), place_compact_aux(b[4], mesh),
             )
     else:
-        from fm_spark_tpu.sparse import pad_field_tables
+        from fm_spark_tpu.models import rows
+        from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
         built = cap.single_step(spec, tconfig)
         step = built if is_deepfm else adapt(built)
-        # The loop holds its tables lane-padded (row-major on the chip
-        # by default: no table is transposed in and out of a step),
-        # padded once here, each canonical table let go as its padded
-        # one arrives. What leaves the loop — evals, checkpoints, the
-        # returned model — is canonical again: ``to_canonical`` cuts
-        # exactly the tables padded here (the identity where none was).
-        params, to_canonical = pad_field_tables(canonical)
+        # The loop holds its tables in the form models/rows.py chooses
+        # for a holder that writes (lane-padded where the chip would
+        # otherwise transpose each in and out of a step), formed once
+        # here, each canonical table let go as its held one arrives.
+        # What leaves the loop — evals, checkpoints, the returned model
+        # — is canonical again (the identity where nothing changed form).
+        params, shapes, _ = rows.hold(canonical, FUSED_TABLE_KEYS,
+                                      writes=True, consume=True)
+        to_canonical = lambda p, release=False: rows.canonical(
+            p, shapes, release
+        )
         opt, prep = opt0, host
 
     return step, params, opt, prep, to_canonical, mesh
@@ -1413,7 +1418,6 @@ def cmd_train(args) -> int:
         sparse_update=args.sparse_update,
         param_dtype=args.param_dtype,
         compute_dtype=args.compute_dtype,
-        table_layout=args.table_layout,
         use_pallas=True if args.use_pallas else None,
     )
     tconfig = cfg.train_config(
@@ -2466,12 +2470,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "passes (storage stays --param-dtype; reductions "
                         "and the compact cumsum stay fp32 — the measured "
                         "+6%% lever, quality pinned in QUALITY.md)")
-    t.add_argument("--table-layout", default=None, dest="table_layout",
-                   choices=["row", "col"],
-                   help="FieldFM physical table orientation; col = "
-                        "transposed [width, bucket] storage (bitwise-"
-                        "equivalent; needs --compact-cap; measured a "
-                        "wash on this chip — see PERF.md)")
     t.add_argument("--use-pallas", action="store_true", dest="use_pallas",
                    help="route fused-step row gather/update through the "
                         "Pallas pipelined-DMA kernels (TPU; interpret mode "

@@ -49,9 +49,6 @@ class RunConfig:
     # param_dtype); the bench-measured +6% lever, quality pinned by
     # bench_quality.py's bf16_compact_cdbf16 variant.
     compute_dtype: str = "float32"
-    # FieldFM physical table orientation ("row" | "col"); col = transposed
-    # [width, bucket] storage, bitwise-equivalent, compact-path only.
-    table_layout: str = "row"
     mlp_dims: tuple = (400, 400, 400)
     # Training recipe (TrainConfig subset).
     num_steps: int = 1000
@@ -94,13 +91,6 @@ class RunConfig:
         """Build the model spec; ``num_features`` overrides the hashed size
         (required for dense-id datasets like MovieLens)."""
         n = num_features if num_features is not None else self.num_features
-        if self.table_layout != "row" and self.model != "field_fm":
-            # Never silently ignore an explicit layout request: only
-            # FieldFMSpec implements transposed storage.
-            raise ValueError(
-                f"table_layout={self.table_layout!r} is a field_fm "
-                f"option (config {self.name!r} is model {self.model!r})"
-            )
         common = dict(
             num_features=n, rank=self.rank, task=self.task, loss=self.loss,
             init_std=0.01, param_dtype=self.param_dtype,
@@ -112,8 +102,7 @@ class RunConfig:
             if num_features is not None and num_features != self.num_features:
                 raise ValueError("field_fm shapes are fixed by num_fields*bucket")
             return models.FieldFMSpec(
-                **common, num_fields=self.num_fields, bucket=self.bucket,
-                table_layout=self.table_layout,
+                **common, num_fields=self.num_fields, bucket=self.bucket
             )
         if self.model == "field_ffm":
             if num_features is not None and num_features != self.num_features:
@@ -173,24 +162,9 @@ CONFIGS = {
             " automatically, and --row-shards adds bucket row-sharding"
             " (2-D feat×row mesh). The generic 'row' strategy materializes"
             " dense gradients (optax path) — correctness fallback, not the"
-            " at-scale path. Measured-best single-chip flags (PERF.md"
-            " round-5 table, 1.422M samples/s/chip = 1.138x the Spark"
-            " baseline): --param-dtype bfloat16 --compute-dtype bfloat16"
-            " --sparse-update dedup_sr --host-dedup --compact-cap 12288"
-            " (cap must bound YOUR batch's max per-field unique count;"
-            " 12288 bounds the bench's Zipf batch at B=131072 — use"
-            " 16384 when in doubt)"
-            " --gfull-fused --segtotal-pallas (the last two priced ~+8%"
-            " each on-chip and compose; equivalence ULP-pinned in"
-            " tests/test_gfull.py and tests/test_pallas_segsum.py)."
-            " Multi-chip / multi-host / --row-shards: swap --host-dedup"
-            " for --compact-device (the in-step aux build; ~11% slower"
-            " on ONE chip, the only form that composes with scale-out —"
-            " PERF.md round 3), and add the round-4 levers"
-            " --collective-dtype bfloat16 (halves the dominant ICI"
-            " term; quality cost 1e-5 AUC, QUALITY.md) and"
-            " --score-sharded (exact; removes the replicated score"
-            " math). Weak scaling: size with --batch-per-chip 131072.",
+            " at-scale path. Measured at these defaults by the benchmark's"
+            " cells fm_r64.train (one chip) and fm_r64.train_4chip"
+            " (PERF.md). Weak scaling: size with --batch-per-chip 131072.",
             model="field_fm", dataset="criteo", rank=64, num_fields=39,
             bucket=1 << 18, strategy="field_sparse", num_steps=1_000_000,
             batch_size=1 << 17, learning_rate=0.05, lr_schedule="constant",
@@ -199,14 +173,9 @@ CONFIGS = {
             name="avazu_ffm_r16",
             description="Config 4 (BASELINE.json:10): FFM rank-16, Avazu CTR,"
             " 23 fields (avazu.py), per-field hashed; field-partitioned"
-            " packed tables + fused sparse-SGD fast path. Measured winner"
-            " (816,553 samples/s/chip, 2026-07-31): add --compute-dtype"
-            " bfloat16 and keep fp32 params + scatter_add — the bf16"
-            " compute buffers halve the [B, F, F, k] sel traffic; dedup/"
-            "compact LOSE at this table size (PERF.md). Staged, unpriced:"
-            " --sel-blocked never materializes the sel tensors at all"
-            " (the bench --model ffm sweep prices it on the next healthy"
-            " chip window; equivalence-pinned either way).",
+            " packed tables + fused sparse-SGD fast path. Measured at these"
+            " defaults (bucket raised to 2^17) by the benchmark's cell"
+            " ffm_r16.train (PERF.md).",
             model="field_ffm", dataset="avazu", rank=16, num_fields=23,
             bucket=1 << 14, strategy="field_sparse", num_steps=100_000,
             batch_size=8192, learning_rate=0.05, lr_schedule="constant",

@@ -43,7 +43,7 @@ class ModelSpec:
 
     # The parameter keys whose per-field leaves a spec reads by row
     # through models/rows.gather, and a scorer may therefore hold packed
-    # (serve/tables.py). The flat specs read theirs through ops/fm: none.
+    # (models/rows.hold). The flat specs read theirs through ops/fm: none.
     row_tables = ()
 
     def __post_init__(self):

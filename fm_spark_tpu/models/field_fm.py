@@ -46,18 +46,6 @@ class FieldFMSpec(base.ModelSpec):
     # key on this flag).
     field_local_ids = True
 
-    # Physical table orientation: "row" = [bucket, width] (default),
-    # "col" = TRANSPOSED [width, bucket]. TPU tiling pads the minor dim
-    # to 128 lanes, so a width-65 row-layout table physically occupies
-    # ~2x its nominal bytes — and the measured big-table gather cost
-    # tracks PHYSICAL operand bytes (PERF.md round-2 "transpose" probe:
-    # column-gather from the col layout is ~2.3x cheaper at bf16, with
-    # donated scatter cost unchanged). The col layout pairs with the
-    # compact sparse path, which transposes only the tiny [w, cap]
-    # unique-row buffer back to row orientation, leaving every downstream
-    # computation unchanged.
-    table_layout: str = "row"
-
     def __post_init__(self):
         super().__post_init__()
         if self.num_fields <= 0 or self.bucket <= 0:
@@ -67,13 +55,6 @@ class FieldFMSpec(base.ModelSpec):
                 f"num_features ({self.num_features}) must equal "
                 f"num_fields*bucket ({self.num_fields * self.bucket})"
             )
-        if self.table_layout not in ("row", "col"):
-            raise ValueError(
-                f"table_layout must be 'row' or 'col', got "
-                f"{self.table_layout!r}"
-            )
-        if self.table_layout == "col" and not self.fused_linear:
-            raise ValueError("table_layout='col' requires fused_linear=True")
 
     @property
     def table_width(self) -> int:
@@ -82,10 +63,7 @@ class FieldFMSpec(base.ModelSpec):
     @property
     def row_tables(self) -> tuple[str, ...]:
         """The parameter keys whose per-field leaves are read by row
-        through :func:`rows.gather` (a scorer may hold those packed); a
-        ``col`` table is read by column and is not one."""
-        if self.table_layout == "col":
-            return ()
+        through :func:`rows.gather` (a scorer may hold those packed)."""
         return ("vw",) if self.fused_linear else ("v", "w")
 
     def init(self, rng: jax.Array) -> dict:
@@ -97,17 +75,13 @@ class FieldFMSpec(base.ModelSpec):
         ]
         if self.fused_linear:
             # Column `rank` is the linear weight w, zero-initialized like
-            # the reference. Col layout: identical values, transposed
-            # storage — row/col models from the same key are bitwise
-            # equivalent under transpose.
+            # the reference.
             vw = [
                 jnp.concatenate(
                     [v, jnp.zeros((self.bucket, 1), self.pdtype)], axis=1
                 )
                 for v in factors
             ]
-            if self.table_layout == "col":
-                vw = [t.T for t in vw]
             return {"w0": jnp.zeros((), jnp.float32), "vw": vw}
         return {
             "w0": jnp.zeros((), jnp.float32),
@@ -120,11 +94,6 @@ class FieldFMSpec(base.ModelSpec):
         """One gather per field → list of F ``[B, width]`` rows (compute dtype)."""
         cd = self.cdtype
         tables = params["vw"] if self.fused_linear else params["v"]
-        if self.table_layout == "col":
-            return [
-                tables[f][:, ids[:, f]].astype(cd).T
-                for f in range(self.num_fields)
-            ]
         return [rows_lib.gather(tables[f], ids[:, f]).astype(cd)
                 for f in range(self.num_fields)]
 
@@ -168,7 +137,6 @@ class FieldFMSpec(base.ModelSpec):
         kwargs.pop("num_fields")
         kwargs.pop("bucket")
         kwargs.pop("fused_linear")
-        kwargs.pop("table_layout")
         return FMSpec(**kwargs)
 
     def to_flat_params(self, params: dict) -> dict:
@@ -176,8 +144,6 @@ class FieldFMSpec(base.ModelSpec):
         if self.fused_linear:
             k = self.rank
             vw = params["vw"]
-            if self.table_layout == "col":
-                vw = [t.T for t in vw]
             return {
                 "w0": params["w0"],
                 "w": jnp.concatenate([t[:, k] for t in vw]),

@@ -119,6 +119,14 @@ def load_model(path: str):
         spec_kwargs["max_target"] = math.inf
     if "mlp_dims" in spec_kwargs:
         spec_kwargs["mlp_dims"] = tuple(spec_kwargs["mlp_dims"])
+    # Every FieldFM model saved before the field went carries
+    # ``"table_layout": "row"``, the storage every model has now.
+    if spec_kwargs.pop("table_layout", "row") != "row":
+        raise ValueError(
+            f"{path}/spec.json has \"table_layout\": "
+            f"{meta['spec']['table_layout']!r}: transposed table storage "
+            "was removed; only 'row' models load"
+        )
     spec = _FAMILIES[meta["family"]](**spec_kwargs)
     # Rebuild the nested pytree from an example structure.
     example = jax.eval_shape(spec.init, jax.random.key(0))

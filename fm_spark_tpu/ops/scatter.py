@@ -331,38 +331,24 @@ def device_compact_aux(ids_col, cap: int):
     return (useg, segstart, segend, order, inv), nseg
 
 
-def _to_table_width(rows, table, col: bool = False):
+def _to_table_width(rows, table):
     """``rows`` ([n, w] values about to be written into ``table``)
     zero-padded to the table's width. The one-chip training loop holds
-    a narrow row table lane-padded (sparse.pad_field_tables: zero
+    a narrow row table lane-padded (models/rows.py says why: zero
     columns that stay zero, because only zeros are ever written there);
     the arithmetic before a write runs at the model's width and only the
-    write pays the lanes. ``rows`` itself for a table no wider (and for
-    a ``col`` table, ``[w, n]``, never padded)."""
-    extra = 0 if col else table.shape[1] - rows.shape[1]
+    write pays the lanes. ``rows`` itself for a table no wider."""
+    extra = table.shape[1] - rows.shape[1]
     return jnp.pad(rows, ((0, 0), (0, extra))) if extra else rows
 
 
-def compact_gather(table, useg, col: bool = False):
+def compact_gather(table, useg):
     """Forward half of the compact path: gather each unique id's row
     once — ``cap`` ascending lanes against the big table (sentinels clip
     to the last row; those rows are never referenced by ``inv``).
     Per-lane rows are then ``urows[inv]`` against this [cap, w] buffer,
-    which gathers at the small-operand fast rate (PERF.md fact 2).
-
-    ``col`` = the table is stored TRANSPOSED ([w, bucket] — FieldFMSpec
-    ``table_layout='col'``): column-gather then transpose the tiny
-    [w, cap] buffer back to row orientation, so callers see identical
-    shapes either way. The col gather is ~2x cheaper at big-table shapes
-    because the scan tracks PHYSICAL bytes and the col layout has no
-    minor-dim lane padding (PERF.md "transpose" probe)."""
-    _check_sentinel_range(table.shape[1] if col else table.shape[0],
-                          useg.shape[-1])
-    if col:
-        n = table.shape[1]
-        return table.at[:, jnp.clip(useg, 0, n - 1)].get(
-            indices_are_sorted=True
-        ).T
+    which gathers at the small-operand fast rate (PERF.md fact 2)."""
+    _check_sentinel_range(table.shape[0], useg.shape[-1])
     return table.at[useg].get(mode="clip", indices_are_sorted=True)
 
 
@@ -377,7 +363,7 @@ def compact_gather(table, useg, col: bool = False):
 _CSUM_BLOCK = 512
 
 
-def compact_apply(table, delta, caux, mode, key, urows, col: bool = False,
+def compact_apply(table, delta, caux, mode, key, urows,
                   segtotal_pallas: bool = False):
     """Update half of the compact path (see :func:`compact_aux`): per-
     segment sums via a two-level blocked fp32 prefix over the sorted
@@ -386,9 +372,7 @@ def compact_apply(table, delta, caux, mode, key, urows, col: bool = False,
     cross-segment residue beyond the prefix's own reassociation), then
     ONE write per unique id: ``add`` for ``dedup``, stochastic-rounded
     ``set`` of ``urows + sum`` for ``dedup_sr`` (``urows`` doubles as
-    the old-row operand — no second gather). ``col`` = transposed table
-    storage (see :func:`compact_gather`): the cap-sized update
-    transposes before the column write; values are identical.
+    the old-row operand — no second gather).
 
     ``segtotal_pallas`` (TrainConfig.segtotal_pallas, round 5): compute
     the segment sums with the Pallas sorted-run kernel
@@ -397,7 +381,7 @@ def compact_apply(table, delta, caux, mode, key, urows, col: bool = False,
     up to fp32 reassociation (tests/test_pallas_segsum.py)."""
     useg, segstart, segend, order, inv = caux
     cap = useg.shape[-1]
-    _check_sentinel_range(table.shape[1] if col else table.shape[0], cap)
+    _check_sentinel_range(table.shape[0], cap)
     sdelta = delta[order].astype(jnp.float32)
     b, w = sdelta.shape
     if segtotal_pallas:
@@ -421,10 +405,10 @@ def compact_apply(table, delta, caux, mode, key, urows, col: bool = False,
             return bl[pos // blk, pos % blk] + off[pos // blk]
 
         segsum = csum_at(segend) - csum_at(segstart) + sdelta[segstart]
-    return _compact_write(table, segsum, useg, mode, key, urows, col)
+    return _compact_write(table, segsum, useg, mode, key, urows)
 
 
-def _compact_write(table, segsum, useg, mode, key, urows, col):
+def _compact_write(table, segsum, useg, mode, key, urows):
     """The compact update's WRITE half: one unique+sorted cap-lane
     write of the fp32 per-segment totals — ``add`` for ``dedup``,
     stochastic-rounded ``set`` of ``urows + totals`` for ``dedup_sr``.
@@ -432,12 +416,7 @@ def _compact_write(table, segsum, useg, mode, key, urows, col):
     totals) and :func:`compact_apply_totals` (the fused Pallas
     backward's totals) so the write semantics can never drift."""
     if mode == "dedup":
-        upd = _to_table_width(segsum.astype(table.dtype), table, col)
-        if col:
-            return table.at[:, useg].add(
-                upd.T, mode="drop",
-                unique_indices=True, indices_are_sorted=True,
-            )
+        upd = _to_table_width(segsum.astype(table.dtype), table)
         return table.at[useg].add(
             upd, mode="drop",
             unique_indices=True, indices_are_sorted=True,
@@ -446,31 +425,24 @@ def _compact_write(table, segsum, useg, mode, key, urows, col):
         raise ValueError("dedup_sr needs key= and urows=")
     new_rows = urows.astype(jnp.float32) + segsum
     vals = _to_table_width(
-        stochastic_round(new_rows, table.dtype, key), table, col)
-    if col:
-        return table.at[:, useg].set(
-            vals.T, mode="drop",
-            unique_indices=True, indices_are_sorted=True,
-        )
+        stochastic_round(new_rows, table.dtype, key), table)
     return table.at[useg].set(
         vals, mode="drop",
         unique_indices=True, indices_are_sorted=True,
     )
 
 
-def compact_apply_totals(table, totals, caux, mode, key, urows,
-                         col: bool = False):
+def compact_apply_totals(table, totals, caux, mode, key, urows):
     """Apply PRECOMPUTED [cap, w] fp32 per-segment totals to ``table``
     — the write half of :func:`compact_apply` for callers that already
     hold the totals, i.e. the fused Pallas backward
     (ops/pallas_fused.fm_bwd_segment_totals), whose output is exactly
     the ``-lr·g_full`` segment sums the blocked prefix would produce.
-    ``caux``/``mode``/``key``/``urows``/``col`` as in
+    ``caux``/``mode``/``key``/``urows`` as in
     :func:`compact_apply`."""
     useg = caux[0]
-    _check_sentinel_range(table.shape[1] if col else table.shape[0],
-                          useg.shape[-1])
-    return _compact_write(table, totals, useg, mode, key, urows, col)
+    _check_sentinel_range(table.shape[0], useg.shape[-1])
+    return _compact_write(table, totals, useg, mode, key, urows)
 
 
 def _aux_apply(table, delta, aux, mode, key, old_rows):

@@ -93,11 +93,6 @@ def stack_field_params(spec, params, n_feat: int) -> dict:
     """Per-field table list → ``{"w0", "vw": [F_pad, bucket, width]}``."""
     if not spec.fused_linear:
         raise ValueError("field-sharded step requires fused_linear=True")
-    if getattr(spec, "table_layout", "row") != "row":
-        raise ValueError(
-            "the field-sharded layout requires table_layout='row' "
-            "(transposed tables are a single-chip compact-path option)"
-        )
     f_pad = padded_num_fields(spec.num_fields, n_feat)
     tables = list(params["vw"])
     pad = f_pad - len(tables)

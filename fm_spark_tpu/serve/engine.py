@@ -66,11 +66,12 @@ class Generation:
     the single-assignment atomicity the no-torn-swap invariant rides.
 
     ``params`` is the tree the bucket executables take: the model's
-    parameters on the device in SERVING form (:mod:`~fm_spark_tpu.serve.
-    tables`: narrow tables packed or lane-padded where the device would
-    otherwise copy them on every dispatch). ``shapes`` is the canonical
-    tree's ``jax.ShapeDtypeStruct``s — what a checkpoint of this model
-    restores into. No canonical copy is kept; ``tables.unpack(params,
+    parameters on the device in the form a holder that only reads takes
+    (:mod:`fm_spark_tpu.models.rows`: narrow tables packed or
+    lane-padded where the device would otherwise copy them on every
+    dispatch). ``shapes`` is the canonical tree's
+    ``jax.ShapeDtypeStruct``s — what a checkpoint of this model
+    restores into. No canonical copy is kept; ``rows.canonical(params,
     shapes)`` is the way back. ``held`` counts what was installed
     (tables packed, padded, as they were; resident bytes)."""
 
@@ -194,15 +195,17 @@ class PredictEngine:
 
     def _install(self, params, step: int, gen_id: int) -> Generation:
         """Put canonical ``params`` on the device in serving form
-        (:func:`tables.install`; the caller's arrays are not consumed),
+        (``rows.hold``; the caller's arrays are not consumed),
         off the request path, then make them THE generation by a single
         reference store — the one way a generation comes to be, at
         construction and at every swap. The gauges say what this engine
         serves now: the live /healthz endpoint (ISSUE 14) reads the
         step, and a fresh engine that never swaps must still report it."""
-        from fm_spark_tpu.serve import tables
+        from fm_spark_tpu.models import rows
 
-        gen = Generation(*tables.install(self.spec, params), step, gen_id)
+        gen = Generation(
+            *rows.hold(params, self.spec.row_tables, writes=False),
+            step, gen_id)
         self._gen = gen  # fmlint: disable=thread-lock-discipline -- THE swap: one atomic reference store; worker reads the reference once per batch (no-torn-swap contract, chaos-audited)
         obs.gauge("serve/generation_step").set(gen.step)
         for name, value in gen.held.items():

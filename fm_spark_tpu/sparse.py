@@ -27,8 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from fm_spark_tpu.models import rows as rows_lib
 from fm_spark_tpu.ops import losses as losses_lib
-from fm_spark_tpu.ops.vmem import LANES
 from fm_spark_tpu.train import TrainConfig
 
 
@@ -120,8 +120,7 @@ def _check_host_dedup(config: TrainConfig, loss: str):
                          "exclusive")
 
 
-def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False,
-                        width=None):
+def _compact_gather_all(tables, aux, cd, mask_overflow=False, width=None):
     """COMPACT forward table access (``config.compact_cap`` > 0): gather
     each field's ``cap`` unique rows once from the big table, expand
     per-lane rows from the small [cap, w] buffer via the inverse map
@@ -138,14 +137,14 @@ def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False,
     multiply.
 
     ``width``: the model's row width where a table may be held wider
-    (:func:`pad_field_tables`); the ``cap`` whole rows are gathered,
-    then cut, so ``urows`` and ``rows`` are ``width`` wide."""
+    (models/rows.py says why); the ``cap`` whole rows are gathered, then
+    cut, so ``urows`` and ``rows`` are ``width`` wide."""
     from fm_spark_tpu.ops import scatter as scatter_lib
 
     useg, inv = aux[0], aux[4]
     cap = useg.shape[-1]
     urows = [
-        scatter_lib.compact_gather(t, useg[f], col=col)[:, :width]
+        scatter_lib.compact_gather(t, useg[f])[:, :width]
         for f, t in enumerate(tables)
     ]
     rows = []
@@ -158,8 +157,7 @@ def _compact_gather_all(tables, aux, cd, col=False, mask_overflow=False,
 
 
 def _compact_apply_all(tables, g_fulls, urows, config: TrainConfig,
-                       sr_base_key, step_idx, lr, aux, field_offset=0,
-                       col=False):
+                       sr_base_key, step_idx, lr, aux, field_offset=0):
     """COMPACT update: one cumsum-derived segment total and one
     unique+sorted cap-lane write per field (ops/scatter.compact_apply);
     the counterpart of :func:`_apply_field_updates` for
@@ -180,7 +178,7 @@ def _compact_apply_all(tables, g_fulls, urows, config: TrainConfig,
         new.append(
             scatter_lib.compact_apply(
                 tables[f], -lr * g_full, tuple(a[f] for a in aux),
-                config.sparse_update, key, urows[f], col=col,
+                config.sparse_update, key, urows[f],
                 segtotal_pallas=config.segtotal_pallas,
             )
         )
@@ -232,7 +230,16 @@ def _fold_overflow(loss, ovf, config: TrainConfig):
     return jnp.where(ovf > 0, jnp.float32(-jnp.inf), loss)
 
 
-def _rows_for(compact, tables, aux, cd, gat, ids, width, col=False,
+# The parameter keys of the tables the fused bodies below read and write:
+# their rows are cut to the model's width on read (_rows_for) and padded
+# back on write (ops/scatter._to_table_width), so the one-chip loop may
+# hold exactly these wider than the model (models/rows.hold). A
+# ``fused_linear=False`` spec's ``v`` / ``w`` are neither cut nor padded
+# by its body and stay as they are.
+FUSED_TABLE_KEYS = ("vw",)
+
+
+def _rows_for(compact, tables, aux, cd, gat, ids, width,
               device_cap: int = 0):
     """The fused bodies' shared forward table access: the compact
     cap-lane path (host- or device-built aux) or the plain per-lane
@@ -241,35 +248,33 @@ def _rows_for(compact, tables, aux, cd, gat, ids, width, col=False,
     (device) so the update half consumes one object either way. One
     definition so the three fused factories (FM/FFM/DeepFM) can never
     drift. ``urows`` and ``rows`` are ``width`` wide, the model's,
-    whatever the tables are: a lane-padded table
-    (:func:`pad_field_tables`) gives up whole rows (asking the gather
-    for the leading columns only, ``table[idx, :width]``, compiles to a
-    serial loop of one dynamic-slice a row on the TPU, 10-50x slower:
-    PERF.md §6, PR 27) and they are cut here, so the bodies' arithmetic
-    never sees the padding; the writes (ops/scatter) pad it back."""
+    whatever the tables are: a lane-padded table (models/rows.py has
+    why the one-chip loop holds one) gives up whole rows (asking the
+    gather for the leading columns only, ``table[idx, :width]``,
+    compiles to a serial loop of one dynamic-slice a row on the TPU,
+    10-50x slower: PERF.md §6, PR 27) and they are cut here, so the
+    bodies' arithmetic never sees the padding; the writes (ops/scatter)
+    pad it back."""
     if device_cap > 0:
         aux, ovf = _device_compact_aux_all(ids, device_cap,
                                            len(tables))
-        urows, rows = _compact_gather_all(tables, aux, cd, col=col,
+        urows, rows = _compact_gather_all(tables, aux, cd,
                                           mask_overflow=True, width=width)
         return urows, rows, aux, ovf
     if compact:
-        urows, rows = _compact_gather_all(tables, aux, cd, col=col,
-                                          width=width)
+        urows, rows = _compact_gather_all(tables, aux, cd, width=width)
         return urows, rows, aux, None
     rows = [r[:, :width] for r in _gather_all(gat, tables, ids, cd)]
     return None, rows, aux, None
 
 
 def _updates_for(compact, tables, ids, g_fulls, rows, urows,
-                 config: TrainConfig, sr_base_key, step_idx, lr, aux,
-                 col=False):
+                 config: TrainConfig, sr_base_key, step_idx, lr, aux):
     """The fused bodies' shared update dispatch, counterpart of
     :func:`_rows_for` (same single-definition rationale)."""
     if compact:
         return _compact_apply_all(
-            tables, g_fulls, urows, config, sr_base_key, step_idx, lr,
-            aux, col=col,
+            tables, g_fulls, urows, config, sr_base_key, step_idx, lr, aux,
         )
     return _apply_field_updates(
         tables, ids, g_fulls, rows, config, sr_base_key, step_idx, lr,
@@ -447,10 +452,6 @@ def fused_embed_plan(spec, config: TrainConfig):
                           "update; it needs compact_cap > 0")
         if not spec.fused_linear:
             return None, "the fused FM backward needs fused_linear=True"
-        if getattr(spec, "table_layout", "row") == "col":
-            return None, ("table_layout='col' stores transposed tables; "
-                          "the kernel's resident urows block is "
-                          "row-major")
         reason = pallas_fused.fm_bwd_supported(
             config.compact_cap, spec.rank + 1,
             jnp.dtype(spec.pdtype).itemsize)
@@ -630,109 +631,6 @@ def _gather_all(gat, tables, ids, cd):
     return [gat(tables[f], ids[:, f]).astype(cd) for f in range(len(tables))]
 
 
-# --------------------------------------------------------------------------
-# Where the per-field tables sit on the chip.
-#
-# The TPU's default layout for a tall narrow ``f32[N, w]`` puts dimension
-# 0 minor unless w is a whole number of 128-lane tiles, and XLA's gather
-# and scatter read rows: a step handed such tables transposes each into
-# a temporary before its gather and transposes the updated table back
-# for its result, two whole-table copies a table a step (PERF.md §5: 12%
-# of config 3's step, 50% of avazu's). Stating a row-major layout on the
-# jit (``jax.experimental.layout.Format``) removes them, but an
-# executable READ BACK from the persistent compile cache returns its
-# results in the default layout again (jax 0.9.0 / libtpu 0.0.34; PERF.md
-# §6), and every entry point runs with that cache on. So the one-chip
-# loop holds such tables in the one shape whose DEFAULT layout is
-# row-major: the width padded with zero columns to whole lanes. That is
-# byte for byte what a row-major ``[N, w]`` occupies (its rows pad to
-# 128 lanes too); gathered rows are cut to the model's w columns
-# (_rows_for) and every write pads its rows back with zeros
-# (ops/scatter), so the arithmetic between runs at the model's width and
-# the padding stays zero. Where the device's default is row-major
-# already (the CPU; a width of whole lanes; a ``table_layout='col'``
-# ``[w, N]`` table) nothing is padded.
-# --------------------------------------------------------------------------
-
-def _is_row_table(path, leaf) -> bool:
-    """A per-field table indexed by row: a rank-2 leaf under ``vw``."""
-    return path[0].key == "vw" and len(leaf.shape) == 2
-
-
-@functools.lru_cache(maxsize=None)
-def _default_is_row_major(shape, dtype, device) -> bool:
-    """Does ``device`` lay a ``dtype[shape]`` array out row-major when
-    nobody says how? Asked of the compiler (a program that only makes
-    such an array), so it holds for a described device too."""
-    made = jax.jit(
-        lambda: jnp.zeros(shape, dtype),
-        out_shardings=jax.sharding.SingleDeviceSharding(device),
-    ).lower().compile()
-    layout = made.output_formats.layout
-    return layout is None or (
-        tuple(layout.major_to_minor) == tuple(range(len(shape))))
-
-
-def pad_field_tables(params, device=None):
-    """A one-chip parameter tree as the training loop holds it, and the
-    way back: ``(padded, unpad)``. Every row table that ``device`` would
-    not lay out row-major by default is padded with zero columns to a
-    whole number of lanes; every other leaf comes back as it is.
-    ``unpad(tree)`` gives the canonical tree (what ``spec.init`` gives,
-    checkpoints hold and the models score) of ``padded`` or of what a
-    step made of it: exactly the tables padded here, cut to the width
-    they came with (``release=True``: the caller is done with ``tree``,
-    and each padded table goes as soon as its cut is on the device).
-    The tables passed in are CONSUMED: each is deleted as soon as its
-    padded one is on the device, so no second copy of the tables stands
-    beside the first. ``device`` None is where a table is (the default
-    device for NumPy arrays and bare shapes). Works on shapes
-    (``jax.ShapeDtypeStruct``) as on arrays."""
-    widths = {}                 # path of each table padded -> its width
-
-    def pad(path, leaf):
-        if not _is_row_table(path, leaf) or leaf.shape[1] % LANES == 0:
-            return leaf
-        on = device
-        if on is None:
-            on = (next(iter(leaf.devices()))
-                  if isinstance(leaf, jax.Array) else
-                  jax.config.jax_default_device or jax.local_devices()[0])
-        if _default_is_row_major(tuple(leaf.shape), jnp.dtype(leaf.dtype),
-                                 on):
-            return leaf
-        widths[path] = leaf.shape[1]
-        extra = -leaf.shape[1] % LANES
-        if isinstance(leaf, jax.ShapeDtypeStruct):
-            return jax.ShapeDtypeStruct(
-                (leaf.shape[0], leaf.shape[1] + extra), leaf.dtype,
-                sharding=leaf.sharding)
-        if not isinstance(leaf, jax.Array):
-            return jnp.pad(leaf, ((0, 0), (0, extra)))
-        # Buffers are allocated as work is queued, ahead of the device:
-        # wait for whatever makes the table (an init still in flight
-        # holds its own temporaries) before asking for its padded one,
-        # and for that one before letting the table go.
-        leaf.block_until_ready()
-        padded = jnp.pad(leaf, ((0, 0), (0, extra))).block_until_ready()
-        leaf.delete()
-        return padded
-
-    def unpad(tree, release=False):
-        def cut(path, leaf):
-            if path not in widths:
-                return leaf
-            table = leaf[:, :widths[path]]
-            if release:
-                table.block_until_ready()
-                leaf.delete()
-            return table
-
-        return jax.tree_util.tree_map_with_path(cut, tree)
-
-    return jax.tree_util.tree_map_with_path(pad, params), unpad
-
-
 def make_field_sparse_sgd_body(spec, config: TrainConfig):
     """Unjitted fused-step body for :class:`FieldFMSpec` (see the jitted
     wrapper :func:`make_field_sparse_sgd_step`); exposed separately so
@@ -753,15 +651,6 @@ def make_field_sparse_sgd_body(spec, config: TrainConfig):
     compact = config.compact_cap > 0
     if compact and not spec.fused_linear:
         raise ValueError("compact_cap requires fused_linear=True")
-    col = getattr(spec, "table_layout", "row") == "col"
-    if col and not compact:
-        raise ValueError(
-            "table_layout='col' requires the compact path (compact_cap "
-            "> 0): the plain per-lane gather/scatter assumes row-major "
-            "tables"
-        )
-    if col and config.use_pallas:
-        raise ValueError("table_layout='col' and use_pallas are exclusive")
     if config.gfull_fused and not spec.fused_linear:
         raise ValueError("gfull_fused targets the fused-linear g_full "
                          "construction; it requires fused_linear=True")
@@ -797,7 +686,7 @@ def make_field_sparse_sgd_body(spec, config: TrainConfig):
             # [B]-lane work never touches table-sized operands).
             urows, rows, aux, ovf = _rows_for(
                 compact, params["vw"], aux, cd, gat, ids,
-                spec.table_width, col=col, device_cap=device_cap,
+                spec.table_width, device_cap=device_cap,
             )                                           # F × [B, k+1]
         else:
             urows = None
@@ -887,7 +776,7 @@ def make_field_sparse_sgd_body(spec, config: TrainConfig):
                         jnp.concatenate([factor_grad(f), g_lin], axis=1))
             new_vw = _updates_for(
                 compact, params["vw"], ids, g_fulls, rows, urows, config,
-                sr_base_key, step_idx, lr, aux, col=col,
+                sr_base_key, step_idx, lr, aux,
             )
             out = {"w0": w0, "vw": new_vw}
         else:
@@ -1515,8 +1404,8 @@ def lower_field_sparse_step(spec, config: TrainConfig, batch_size: int,
     Returns a ``jax.stages.Lowered``; ``.compile()`` produces the
     executable (and, with the persistent cache enabled, persists it).
     Dispatches FieldFM / FieldFFM / FieldDeepFM exactly like the
-    training loop's builders, tables padded as the loop holds them on
-    that device (:func:`pad_field_tables`), so the compiled program is
+    training loop's builders, tables in the form the loop holds them in
+    on that device (``models/rows.hold``), so the compiled program is
     the one the loop's first dispatch would otherwise build on the
     critical path.
     """
@@ -1534,8 +1423,9 @@ def lower_field_sparse_step(spec, config: TrainConfig, batch_size: int,
     def on_device(tree):
         return jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype), tree)
 
-    params_abs, _ = pad_field_tables(
-        on_device(jax.eval_shape(spec.init, jax.random.key(0))), device)
+    params_abs = rows_lib.hold(
+        on_device(jax.eval_shape(spec.init, jax.random.key(0))),
+        FUSED_TABLE_KEYS, writes=True)[0]
     batch_abs = on_device(abstract_field_batch(spec, batch_size))
     aux_abs = on_device(
         abstract_host_aux(config, batch_size, spec.num_fields))

@@ -38,7 +38,7 @@ def placement(params) -> dict:
     stacked ``[F_pad, bucket, w]`` array on a mesh — how many field
     slots each device holds, the distinct on-device layouts of those
     tables (``major_to_minor``; ``[0, 1]`` is row-major, what the
-    one-chip loop holds: sparse.pad_field_tables) and the bytes they
+    one-chip loop holds: models/rows.py) and the bytes they
     occupy on their devices, lane padding included."""
     import jax
 

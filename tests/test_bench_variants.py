@@ -24,12 +24,11 @@ def _grid(model, batch=1 << 17):
 
 
 def test_fm_label_config_consistency():
-    for label, (pd, cd, layout), cfg in _grid("fm"):
+    for label, (pd, cd), cfg in _grid("fm"):
         assert ("gfull" in label) == cfg.gfull_fused, label
         assert ("segtotal" in label) == cfg.segtotal_pallas, label
         assert ("fusedbwd" in label) == (cfg.fused_embed != "off"), label
         assert ("devaux" in label) == cfg.compact_device, label
-        assert ("colT" in label) == (layout == "col"), label
         assert (f"compact{cfg.compact_cap}" in label) == (
             cfg.compact_cap > 0), label
         assert label.startswith(pd), label
@@ -131,7 +130,7 @@ def test_fm_kaggle_grid():
     # second, bf16/dedup_sr as the tail sentinel; compact cap bounds
     # the measured 10,711 max per-field unique at B=131072.
     head, tail = bench.default_variants("fm_kaggle", 1 << 17)
-    label0, (pd0, cd0, _), cfg0 = head[0]
+    label0, (pd0, cd0), cfg0 = head[0]
     assert label0 == "float32/scatter_add/cd-bf16"
     assert (pd0, cd0) == ("float32", "bfloat16")
     label1, _, cfg1 = head[1]
@@ -146,7 +145,7 @@ def test_ffm_salvage_order_measured_winner_first():
     # bf16 compute + scatter_add. Label<->config consistency matters
     # here doubly — cd-bf16 with FP32 storage is exact-storage, so the
     # label's "/cd-bf16" is the only record that compute ran in bf16.
-    label, (pd, cd, layout), cfg = head[0]
+    label, (pd, cd), cfg = head[0]
     assert label == "float32/scatter_add/cd-bf16"
     assert (pd, cd) == ("float32", "bfloat16")
     assert cfg.sparse_update == "scatter_add"
@@ -183,7 +182,7 @@ def test_default_grids_build_and_step():
     for model in ("fm", "ffm", "deepfm", "fm_kaggle"):
         head, tail = bench.default_variants(model, B)
         assert head or tail, model
-        for label, (pd, cd, layout), cfg in head + tail:
+        for label, (pd, cd), cfg in head + tail:
             # Mirror bench.make_spec's dtype fallback: a None compute
             # dtype means "the --compute-dtype default" (float32), NOT
             # dtype(None) — numpy canonicalizes the latter to float64.
@@ -203,8 +202,7 @@ def test_default_grids_build_and_step():
                 spec = models.FieldDeepFMSpec(**common, mlp_dims=(8, 8))
                 step = make_field_deepfm_sparse_step(spec, cfg)
             else:
-                spec = models.FieldFMSpec(
-                    **common, table_layout=layout or "row")
+                spec = models.FieldFMSpec(**common)
                 step = make_field_sparse_sgd_step(spec, cfg)
             params = spec.init(jax.random.key(0))
             if model == "deepfm":

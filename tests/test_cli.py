@@ -505,6 +505,17 @@ def test_distributed_flag_plumbs_initialize(monkeypatch):
         _maybe_init_distributed(parse(["--num-processes", "2"]))
 
 
+def test_train_has_no_table_layout_flag(capsys):
+    """``--table-layout`` went with ``FieldFMSpec.table_layout`` (PR 31):
+    asking for it is a usage error, not a silently ignored option."""
+    with pytest.raises(SystemExit) as exc:
+        cli.build_parser().parse_args(
+            ["train", "--config", "criteo1tb_fm_r64", "--synthetic", "64",
+             "--table-layout", "col"])
+    assert exc.value.code == 2
+    assert "--table-layout" in capsys.readouterr().err
+
+
 def test_distributed_init_precedes_backend_touch():
     # On a pod slice, jax.distributed.initialize must run before the
     # backend initializes (a single-process backend init first would

@@ -266,87 +266,6 @@ def test_cli_measured_best_flags_smoke(tmp_path):
     assert spec2.param_dtype == "bfloat16"
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("mode", ["dedup", "dedup_sr"])
-@pytest.mark.parametrize("param_dtype", ["float32", "bfloat16"])
-def test_col_layout_matches_row_bitwise(rng, mode, param_dtype):
-    """table_layout='col' (transposed [w, bucket] storage) must be
-    BITWISE equal to the row layout under transpose: same init values,
-    same SR key stream, identical step math — only the physical
-    orientation differs (PERF.md 'transpose' probe rationale)."""
-    ids, vals, labels, weights = _batch(rng)
-    aux = tuple(jnp.asarray(a) for a in compact_aux(ids, CAP))
-    base = dict(num_features=F * BUCKET, rank=K, num_fields=F,
-                bucket=BUCKET, init_std=0.1, param_dtype=param_dtype)
-    cfg = TrainConfig(learning_rate=0.05, optimizer="sgd",
-                      reg_factors=1e-4, reg_linear=1e-4,
-                      sparse_update=mode, host_dedup=True,
-                      compact_cap=CAP)
-    sr_ = models.FieldFMSpec(**base)
-    sc = models.FieldFMSpec(**base, table_layout="col")
-    pr = sr_.init(jax.random.key(1))
-    pc = sc.init(jax.random.key(1))
-    args = (jnp.int32(2), jnp.asarray(ids), jnp.asarray(vals),
-            jnp.asarray(labels), jnp.asarray(weights), aux)
-    pr, lr_ = make_field_sparse_sgd_step(sr_, cfg)(pr, *args)
-    pc, lc_ = make_field_sparse_sgd_step(sc, cfg)(pc, *args)
-    assert float(lr_) == float(lc_)
-    for f in range(F):
-        np.testing.assert_array_equal(
-            np.asarray(pc["vw"][f]).T, np.asarray(pr["vw"][f])
-        )
-    s_r = sr_.scores(pr, jnp.asarray(ids), jnp.asarray(vals))
-    s_c = sc.scores(pc, jnp.asarray(ids), jnp.asarray(vals))
-    np.testing.assert_array_equal(np.asarray(s_r), np.asarray(s_c))
-
-
-def test_col_layout_validation():
-    spec = models.FieldFMSpec(
-        num_features=F * BUCKET, rank=K, num_fields=F, bucket=BUCKET,
-        init_std=0.1, table_layout="col",
-    )
-    # col without the compact path: the plain gather assumes row-major.
-    with pytest.raises(ValueError, match="compact"):
-        make_field_sparse_sgd_body(
-            spec, TrainConfig(optimizer="sgd")
-        )
-    # col + field-sharded stacking: rejected.
-    from fm_spark_tpu.parallel.field_step import stack_field_params
-
-    with pytest.raises(ValueError, match="row"):
-        stack_field_params(spec, spec.init(jax.random.key(0)), 2)
-    with pytest.raises(ValueError, match="table_layout"):
-        models.FieldFMSpec(
-            num_features=F * BUCKET, rank=K, num_fields=F, bucket=BUCKET,
-            init_std=0.1, table_layout="diagonal",
-        )
-
-
-def test_col_layout_model_io_roundtrip(rng, tmp_path):
-    """spec.json carries table_layout; save/load and libFM export see
-    identical values either way."""
-    from fm_spark_tpu import models as m
-    from fm_spark_tpu.models.io import load_model, save_model
-
-    base = dict(num_features=F * BUCKET, rank=K, num_fields=F,
-                bucket=BUCKET, init_std=0.1)
-    sc = m.FieldFMSpec(**base, table_layout="col")
-    pc = sc.init(jax.random.key(5))
-    save_model(str(tmp_path / "m"), sc, pc)
-    spec2, params2 = load_model(str(tmp_path / "m"))
-    assert spec2.table_layout == "col"
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(a, b), params2, pc
-    )
-    flat_c = sc.to_flat_params(pc)
-    flat_r = m.FieldFMSpec(**base).to_flat_params(
-        m.FieldFMSpec(**base).init(jax.random.key(5))
-    )
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(a, b), flat_c, flat_r
-    )
-
-
 @pytest.mark.parametrize("mode", ["dedup", "dedup_sr"])
 @pytest.mark.parametrize("n_feat,num_fields", [(4, 5), (2, 5), (4, 4)])
 def test_sharded_compact_matches_single(rng, mode, n_feat, num_fields):

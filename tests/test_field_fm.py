@@ -88,6 +88,34 @@ def test_field_fm_save_load(tmp_path, field_spec, batch):
     )
 
 
+@pytest.mark.parametrize("stored,loads", [("row", True), ("col", False)])
+def test_field_fm_loads_spec_json_of_before_table_layout_went(
+        tmp_path, field_spec, batch, stored, loads):
+    """Every FieldFM model saved until PR 31 carries ``"table_layout":
+    "row"`` in its ``spec.json`` and must load and score as it did; the
+    transposed storage went, and a ``"col"`` model is refused by the
+    key's name rather than by a dataclass's TypeError."""
+    import json
+
+    ids, vals, _ = batch
+    params = field_spec.init(jax.random.key(2))
+    path = tmp_path / "m"
+    models.save_model(str(path), field_spec, params)
+    meta = json.loads((path / "spec.json").read_text())
+    assert "table_layout" not in meta["spec"]
+    meta["spec"]["table_layout"] = stored
+    (path / "spec.json").write_text(json.dumps(meta))
+    if not loads:
+        with pytest.raises(ValueError, match="table_layout.*'col'"):
+            models.load_model(str(path))
+        return
+    spec2, params2 = models.load_model(str(path))
+    assert spec2 == field_spec
+    np.testing.assert_array_equal(
+        np.asarray(field_spec.scores(params, ids, vals)),
+        np.asarray(spec2.scores(params2, ids, vals)))
+
+
 def test_field_fm_validation():
     with pytest.raises(ValueError, match="num_fields"):
         models.FieldFMSpec(num_features=100, rank=2, num_fields=0, bucket=10)

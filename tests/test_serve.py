@@ -982,6 +982,19 @@ heavy = ("orbax.checkpoint", "fm_spark_tpu.checkpoint",
          "fm_spark_tpu.serve.reload")
 out = {"loaded_by_the_engine": [m for m in heavy if m in sys.modules],
        "listed": "ReloadFollower" in dir(serve)}
+# A scorer that holds a generation (models/rows.py chooses the form)
+# and answers a request has loaded nothing of the trainer.
+import jax, numpy as np
+from fm_spark_tpu import models
+spec = models.FieldFMSpec(num_features=4 * 64, num_fields=4, bucket=64,
+                          rank=8)
+eng = PredictEngine(spec, spec.init(jax.random.key(0)), buckets=(8,))
+eng.warmup()
+eng.score(np.zeros((3, 4), np.int32), np.ones((3, 4), np.float32))
+eng.close()
+trainer = ("fm_spark_tpu.sparse", "fm_spark_tpu.train", "optax")
+out["trainer_loaded_by_a_serving_engine"] = [
+    m for m in trainer if m in sys.modules]
 from fm_spark_tpu.serve import ReloadFollower
 from fm_spark_tpu.serve.reload import ReloadFollower as there
 out["the_class"] = (ReloadFollower is there and isinstance(there, type)
@@ -1015,6 +1028,9 @@ def import_probe():
     # checkpoint library (13-25 s of its start-up on the chip's host).
     ("loaded_by_the_engine", []),
     ("listed", True),
+    # ... nor, for choosing the form it holds its tables in, the trainer
+    # (until PR 31 serve/tables.py asked fm_spark_tpu.sparse).
+    ("trainer_loaded_by_a_serving_engine", []),
     ("the_class", True),
     ("loaded_by_the_follower", ["orbax.checkpoint", "fm_spark_tpu.checkpoint",
                                 "fm_spark_tpu.serve.reload"]),

@@ -1,4 +1,4 @@
-"""The form a scorer holds its tables in (serve/tables.py, models/rows.py).
+"""The form a scorer holds its tables in (models/rows.py).
 
 On the TPU a default-placed tall narrow table is dimension-0-minor and a
 predict program copies all of it per dispatch; ``PredictEngine`` installs
@@ -23,7 +23,7 @@ from fm_spark_tpu import models, obs, sparse
 from fm_spark_tpu.checkpoint import Checkpointer
 from fm_spark_tpu.models import rows as rows_lib
 from fm_spark_tpu.models.rows import PackedTable
-from fm_spark_tpu.serve import PredictEngine, ReloadFollower, tables
+from fm_spark_tpu.serve import PredictEngine, ReloadFollower
 from fm_spark_tpu.train import TrainConfig
 
 BUCKET = 512
@@ -60,7 +60,12 @@ def chip_row_major(shape, dtype, device):
 
 @pytest.fixture
 def chip_defaults(monkeypatch):
-    monkeypatch.setattr(sparse, "_default_is_row_major", chip_row_major)
+    monkeypatch.setattr(rows_lib, "default_is_row_major", chip_row_major)
+
+
+def install(spec, params):
+    """The scorer's call of the walk (PredictEngine._install)."""
+    return rows_lib.hold(params, spec.row_tables, writes=False)
 
 
 def _params(spec, seed=0):
@@ -122,10 +127,10 @@ def test_served_tree_predicts_bitwise(chip_defaults, case):
     make, forms = CASES[case]
     spec = make()
     params = _params(spec)
-    served, shapes, held = tables.install(spec, params)
+    served, shapes, held = install(spec, params)
     assert _forms(spec, served, params) == forms
-    assert [held[f"tables_{f}"] for f in tables.FORMS] == [
-        forms.count(f) for f in tables.FORMS]
+    assert [held[f"tables_{f}"] for f in rows_lib.FORMS] == [
+        forms.count(f) for f in rows_lib.FORMS]
     ids, vals = _batch(spec)
     # The reader hands the model the same rows ...
     width = spec.table_width
@@ -143,23 +148,46 @@ def test_served_tree_predicts_bitwise(chip_defaults, case):
                         _predict(spec, params, ids, vals))
 
 
+@pytest.mark.parametrize("holder", ["scorer", "training_loop"])
 @pytest.mark.parametrize("case", CASES)
-def test_unpack_is_the_way_back(chip_defaults, case):
+def test_unpack_is_the_way_back(chip_defaults, case, holder):
+    """A tree held by the reader's walk (packed, padded) and one held by
+    the writer's (padded only, and only the fused bodies' ``vw``, its
+    sources consumed) come back bit-identical through the one way
+    back."""
     spec = CASES[case][0]()
     params = _params(spec)
-    served, shapes, held = tables.install(spec, params)
-    back = tables.unpack(served, shapes)
-    assert jax.tree.structure(back) == jax.tree.structure(params)
+    host = [np.asarray(leaf).copy() for leaf in jax.tree.leaves(params)]
+    if holder == "scorer":
+        held, shapes, report = install(spec, params)
+        # The caller's arrays are not consumed.
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(params))
+    else:
+        held, shapes, report = rows_lib.hold(
+            params, sparse.FUSED_TABLE_KEYS, writes=True, consume=True)
+        assert report["tables_packed"] == 0
+        assert report["tables_padded"] == len(params.get("vw", ()))
+        # Each table that changed form is gone; nothing else is.
+        assert {key for key, leaves in params.items()
+                if any(leaf.is_deleted() for leaf in jax.tree.leaves(leaves))
+                } == ({"vw"} if "vw" in params else set())
+    assert report["resident_table_bytes"] == sum(
+        leaf.nbytes for leaf in jax.tree.leaves(held))
     assert jax.tree.structure(shapes) == jax.tree.structure(params)
-    for got, shape, want in zip(jax.tree.leaves(back),
-                                jax.tree.leaves(shapes),
-                                jax.tree.leaves(params)):
-        assert (shape.shape, shape.dtype) == (want.shape, want.dtype)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-    # The caller's arrays are not consumed (pad_field_tables deletes its).
-    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
-    assert held["resident_table_bytes"] == sum(
-        leaf.nbytes for leaf in jax.tree.leaves(served))
+    for release in (False, True):
+        back = rows_lib.canonical(held, shapes, release=release)
+        assert jax.tree.structure(back) == jax.tree.structure(params)
+        for got, shape, want in zip(jax.tree.leaves(back),
+                                    jax.tree.leaves(shapes), host):
+            assert (shape.shape, shape.dtype) == (want.shape, want.dtype)
+            assert np.array_equal(np.asarray(got), want)
+    # Released: every table that had changed form went as it came back.
+    changed = report["tables_packed"] + report["tables_padded"]
+    assert sum(any(part.is_deleted() for part in jax.tree.leaves(leaf))
+               for leaf in jax.tree.leaves(
+                   held, is_leaf=lambda x: isinstance(x, PackedTable))
+               ) == changed
 
 
 @pytest.mark.parametrize("width,p", [
@@ -169,32 +197,41 @@ def test_packed_columns(width, p):
     assert rows_lib.packed_columns(width) == p
 
 
-@pytest.mark.parametrize("shape,form", [
-    ((4096, 65), "packed"),        # config 3
-    ((4096, 64), "packed"),        # its factors alone
-    ((4096, 17), "packed"),        # config 5
-    ((4096, 369), "padded"),       # avazu: 384 / 369 - 1 = 4%
-    ((4096, 120), "padded"),       # 128 / 120 - 1 = 6.7%
-    ((4096, 113), "as_is"),        # 13.3% over, and 49 columns left over
-    ((4096, 66), "as_is"),         # two columns left over
-    ((4100, 17), "as_is"),         # 4100 * 16 is no whole number of lines
-    ((4096, 128), "as_is"),        # whole lanes
-    ((65, 4096), "as_is"),         # table_layout='col'
-    ((4096,), "as_is"),            # a linear weight vector
+@pytest.mark.parametrize("shape,form,writes", [
+    ((4096, 65), "packed", False),     # config 3
+    ((4096, 64), "packed", False),     # its factors alone
+    ((4096, 17), "packed", False),     # config 5
+    ((4096, 369), "padded", False),    # avazu: 384 / 369 - 1 = 4%
+    ((4096, 120), "padded", False),    # 128 / 120 - 1 = 6.7%
+    ((4096, 113), "as_is", False),     # 13.3% over, 49 columns left over
+    ((4096, 66), "as_is", False),      # two columns left over
+    ((4100, 17), "as_is", False),      # 4100 * 16: no whole number of lines
+    ((4096, 128), "as_is", False),     # whole lanes
+    ((65, 4096), "as_is", False),      # wider than tall
+    ((4096,), "as_is", False),         # a linear weight vector
+    # The same question from a holder that WRITES its tables (the
+    # training loop): whole lanes whatever they cost, never packed.
+    ((4096, 65), "padded", True),      # fm_r64.train
+    ((4096, 369), "padded", True),     # ffm_r16.train
+    ((4096, 17), "padded", True),      # deepfm_r16.train: 7.5x the bytes
+    ((4096, 113), "padded", True),
+    ((4096, 128), "as_is", True),
+    ((65, 4096), "as_is", True),
+    ((4096,), "as_is", True),
 ])
-def test_serving_form_by_shape(monkeypatch, shape, form):
+def test_serving_form_by_shape(monkeypatch, shape, form, writes):
     # Where the device lays everything out row-major, nothing changes.
-    assert tables.serving_form(shape, jnp.float32,
-                               jax.devices()[0]) == "as_is"
-    monkeypatch.setattr(sparse, "_default_is_row_major", chip_row_major)
-    assert tables.serving_form(shape, jnp.float32, None) == form
+    assert rows_lib.held_form(shape, jnp.float32, jax.devices()[0],
+                              writes) == "as_is"
+    monkeypatch.setattr(rows_lib, "default_is_row_major", chip_row_major)
+    assert rows_lib.held_form(shape, jnp.float32, None, writes) == form
 
 
 def test_unpackable_width_keeps_its_array(chip_defaults):
     spec = models.FieldFMSpec(num_features=4 * BUCKET, num_fields=4,
                               bucket=BUCKET, rank=65, init_std=0.1)
     params = _params(spec)
-    served, shapes, held = tables.install(spec, params)
+    served, shapes, held = install(spec, params)
     assert held["tables_as_is"] == 4 and held["tables_packed"] == 0
     for got, want in zip(served["vw"], params["vw"]):
         assert isinstance(got, jax.Array) and got.shape == (BUCKET, 66)
@@ -203,24 +240,19 @@ def test_unpackable_width_keeps_its_array(chip_defaults):
         PackedTable.pack(params["vw"][0])
 
 
-def test_col_and_flat_tables_keep_todays_path(chip_defaults):
-    """A ``table_layout='col'`` table is read by column and a flat
-    ``[N, k]`` table through ops/fm: neither is a row table of this
-    sense, whatever its shape."""
-    col = models.FieldFMSpec(num_features=4 * BUCKET, num_fields=4,
-                             bucket=BUCKET, rank=64, table_layout="col")
-    flat = models.FMSpec(num_features=4 * BUCKET, rank=64)
-    for spec in (col, flat):
-        assert spec.row_tables == ()
-        params = spec.init(jax.random.key(0))
-        served, shapes, held = tables.install(spec, params)
-        assert [held[f"tables_{f}"] for f in tables.FORMS] == [0, 0, 0]
-        assert not any(isinstance(leaf, PackedTable) for leaf in
-                       jax.tree.leaves(served, is_leaf=lambda x:
-                                       isinstance(x, PackedTable)))
-        for got, want in zip(jax.tree.leaves(served),
-                             jax.tree.leaves(params)):
-            assert got.shape == want.shape
+def test_flat_tables_keep_todays_path(chip_defaults):
+    """A flat ``[N, k]`` table is read through ops/fm: not a row table
+    of this sense, whatever its shape."""
+    spec = models.FMSpec(num_features=4 * BUCKET, rank=64)
+    assert spec.row_tables == ()
+    params = spec.init(jax.random.key(0))
+    served, shapes, held = install(spec, params)
+    assert [held[f"tables_{f}"] for f in rows_lib.FORMS] == [0, 0, 0]
+    assert not any(isinstance(leaf, PackedTable) for leaf in
+                   jax.tree.leaves(served, is_leaf=lambda x:
+                                   isinstance(x, PackedTable)))
+    for got, want in zip(jax.tree.leaves(served), jax.tree.leaves(params)):
+        assert got.shape == want.shape
 
 
 def test_out_of_range_ids_clamp_to_the_table_edge():
@@ -238,7 +270,7 @@ def test_packed_read_is_traced_once_for_all_fields(chip_defaults, case):
     of ONE traced body, which XLA inlines (tests/test_table_layout.py
     reads the compiled program)."""
     spec = CASES[case][0]()
-    served, _, _ = tables.install(spec, _params(spec))
+    served, _, _ = install(spec, _params(spec))
     ids, vals = _batch(spec, 16)
     eqns = jax.make_jaxpr(spec.predict)(served, ids, vals).jaxpr.eqns
     reads = [e for e in eqns if e.params.get("name") == "gather"]
@@ -251,7 +283,7 @@ def test_packed_read_is_traced_once_for_all_fields(chip_defaults, case):
 
 def _gauges():
     return [int(obs.registry().gauge(f"serve/tables_{f}").value)
-            for f in tables.FORMS]
+            for f in rows_lib.FORMS]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -265,7 +297,7 @@ def test_engine_installs_the_serving_form(chip_defaults, case):
         eng.warmup()
         gen = eng.generation()
         assert _forms(spec, gen.params, params) == forms
-        assert _gauges() == [forms.count(f) for f in tables.FORMS]
+        assert _gauges() == [forms.count(f) for f in rows_lib.FORMS]
         assert (obs.registry().gauge("serve/resident_table_bytes").value
                 == gen.held["resident_table_bytes"] > 0)
         ids, vals = _batch(spec, 64)

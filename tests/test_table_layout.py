@@ -1,5 +1,5 @@
-"""Where the per-field tables sit on the chip (sparse.pad_field_tables
-for the training loop, serve/tables.install for the scorer).
+"""Where the per-field tables sit on the chip (models/rows.py: ``hold``
+for the training loop, which writes them, and for the scorer).
 
 A default-placed tall narrow table is dimension-0-minor on the TPU and
 costs a one-chip step two whole-table copies; the loop holds such tables
@@ -23,8 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from fm_spark_tpu import cli, models, sparse
+from fm_spark_tpu.models import rows
 from fm_spark_tpu.models.rows import PackedTable
-from fm_spark_tpu.serve import tables
 from fm_spark_tpu.ops.scatter import dedup_aux
 from fm_spark_tpu.train import TrainConfig
 from fm_spark_tpu.utils import device as device_lib
@@ -88,10 +88,10 @@ def one_chip():
 @pytest.fixture
 def chip_defaults(monkeypatch):
     """The CPU answering the layout question as the chip does (a tall
-    table is not row-major by default), so that pad_field_tables pads
-    here what it pads there and its way back is exercised for real."""
+    table is not row-major by default), so that the walk pads here
+    what it pads there and its way back is exercised for real."""
     monkeypatch.setattr(
-        sparse, "_default_is_row_major",
+        rows, "default_is_row_major",
         lambda shape, dtype, device: shape[0] <= shape[1])
 
 
@@ -145,7 +145,7 @@ def test_compiled_step_copies_no_table(one_chip, family, steps_per_call):
             f"{FIELDS}: re-read PERF.md §5 before trusting the padding")
 
 
-# The scorer's side (serve/tables.py): the tables as PredictEngine holds a
+# The scorer's side (a holder that only reads): the tables as PredictEngine holds a
 # generation of each registry family at the sizes the benchmark serves or
 # trains, the engine's own program (``spec.predict`` under jit) compiled
 # at a small batch bucket. Beside the other described compiles because
@@ -175,7 +175,8 @@ def test_served_predict_program_moves_no_table(one_chip, case):
                                                     sharding=chip)
     canonical = jax.tree.map(lambda a: sds(a.shape, a.dtype),
                              jax.eval_shape(spec.init, jax.random.key(0)))
-    served, shapes, held = tables.install(spec, canonical)
+    served, shapes, held = rows.hold(canonical, spec.row_tables,
+                                     writes=False)
     assert held[f"tables_{form}"] == spec.num_fields
     assert shapes == canonical
     key = spec.row_tables[0]
@@ -218,49 +219,63 @@ def test_served_predict_program_moves_no_table(one_chip, case):
             "re-read PERF.md §5 before trusting the serving form")
 
 
+def _held_for_training(tree, consume=True):
+    """The training loop's call of the walk (cli._place_field_state)."""
+    return rows.hold(tree, sparse.FUSED_TABLE_KEYS, writes=True,
+                     consume=consume)
+
+
 def test_padding_follows_the_devices_default(one_chip):
-    sds = jax.ShapeDtypeStruct
-    tree = {"w0": sds((), jnp.float32),
-            "vw": [sds((BUCKET, 65), jnp.float32),      # config 3
-                   sds((BUCKET, 369), jnp.float32),     # avazu
-                   sds((BUCKET, 17), jnp.bfloat16),     # config 5
-                   sds((BUCKET, 128), jnp.float32),     # whole lanes
-                   sds((65, BUCKET), jnp.float32)],     # table_layout='col'
-            "mlp": [sds((64, 32), jnp.float32)]}
-    padded, unpad = sparse.pad_field_tables(tree, one_chip)
+    def tree(sharding=None):
+        sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+        return {"w0": sds((), jnp.float32),
+                "vw": [sds((BUCKET, 65), jnp.float32),      # config 3
+                       sds((BUCKET, 369), jnp.float32),     # avazu
+                       sds((BUCKET, 17), jnp.bfloat16),     # config 5
+                       sds((BUCKET, 128), jnp.float32),     # whole lanes
+                       sds((65, BUCKET), jnp.float32)],     # wider than tall
+                "v": [sds((BUCKET, 64), jnp.float32)],      # not the loop's
+                "mlp": [sds((64, 32), jnp.float32)]}
+
+    on_chip = tree(SingleDeviceSharding(one_chip))
+    padded, shapes, report = _held_for_training(on_chip)
     assert [t.shape for t in padded["vw"]] == [
         (BUCKET, 128), (BUCKET, 384), (BUCKET, 128), (BUCKET, 128),
         (65, BUCKET)]
+    assert padded["v"][0].shape == (BUCKET, 64)
     assert padded["mlp"][0].shape == (64, 32)
+    assert shapes == on_chip
+    assert (report["tables_padded"], report["tables_as_is"],
+            report["tables_packed"]) == (3, 2, 0)
     # The CPU lays every table out row-major: nothing to pad, and the
     # way back is the identity.
-    same, unpad = sparse.pad_field_tables(tree)
-    assert same == tree and unpad(tree) == tree
+    same, shapes, _ = _held_for_training(tree())
+    assert same == tree() and rows.canonical(same, shapes) == tree()
 
 
-def test_unpad_cuts_what_pad_padded_and_nothing_else(chip_defaults):
-    """The way back is the inverse of what was done, not a guess from
-    shapes: a ``[w, N]`` col table (never padded, far wider than the
+def test_way_back_cuts_what_the_walk_padded_and_nothing_else(chip_defaults):
+    """The way back is the inverse of what was done, by the shapes the
+    walk recorded: a ``[w, N]`` table (never padded, far wider than the
     model's w) and a whole-lane table come back whole; the tables passed
     in are consumed one by one."""
     rng = np.random.default_rng(0)
-    shapes = [(BUCKET, 65), (BUCKET, 369), (BUCKET, 128), (65, BUCKET)]
-    host = [rng.random(s, np.float32) for s in shapes]
+    table_shapes = [(BUCKET, 65), (BUCKET, 369), (BUCKET, 128), (65, BUCKET)]
+    host = [rng.random(s, np.float32) for s in table_shapes]
     tree = {"w0": jnp.float32(0.5), "vw": [jnp.asarray(t) for t in host]}
-    padded, unpad = sparse.pad_field_tables(tree)
+    padded, shapes, _ = _held_for_training(tree)
     assert [t.shape for t in padded["vw"]] == [
         (BUCKET, 128), (BUCKET, 384), (BUCKET, 128), (65, BUCKET)]
     assert [t.is_deleted() for t in tree["vw"]] == [True, True, False,
                                                     False]
     assert not np.asarray(padded["vw"][0][:, 65:]).any()
-    back = unpad(padded)
+    back = rows.canonical(padded, shapes)
     assert back["w0"] is padded["w0"]
     for got, want in zip(back["vw"], host):
         np.testing.assert_array_equal(np.asarray(got), want)
     assert not any(t.is_deleted() for t in padded["vw"])    # kept
     # The loop's last act: the padded tables go as they are cut, the
     # others are handed back as they are.
-    back = unpad(padded, release=True)
+    back = rows.canonical(padded, shapes, release=True)
     assert [t.is_deleted() for t in padded["vw"]] == [True, True, False,
                                                       False]
     for got, want in zip(back["vw"], host):
@@ -317,7 +332,7 @@ def test_padded_tables_train_like_canonical(chip_defaults, family,
             "deepfm": sparse.make_field_deepfm_sparse_step}[family](
                 spec, config)
     want = spec.init(jax.random.key(3))
-    got, unpad = sparse.pad_field_tables(spec.init(jax.random.key(3)))
+    got, shapes, _ = _held_for_training(spec.init(jax.random.key(3)))
     state = ((step.init_opt_state(want), step.init_opt_state(got))
              if family == "deepfm" else None)
     for i in range(2):
@@ -333,9 +348,9 @@ def test_padded_tables_train_like_canonical(chip_defaults, family,
             got, loss = step(got, jnp.int32(i), *b)
         assert float(loss) == pytest.approx(float(want_loss), rel=rel)
     for t in got["vw"]:
-        assert t.shape[1] % sparse.LANES == 0
+        assert t.shape[1] % rows.LANES == 0
         assert not np.asarray(t[:, spec.table_width:]).any()
-    for a, b in zip(jax.tree_util.tree_leaves(unpad(got)),
+    for a, b in zip(jax.tree_util.tree_leaves(rows.canonical(got, shapes)),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
@@ -346,34 +361,37 @@ COMPACT = {"sparse_update": "dedup", "compact_device": True,
            "compact_cap": 1024}
 
 
-@pytest.mark.parametrize("table_layout,overrides,padded", [
-    ("row", {}, False),
-    ("col", COMPACT, False),
-    ("row", {}, True),
-    ("row", COMPACT, True),
-    ("col", COMPACT, True),
+@pytest.mark.parametrize("spec_over,overrides,padded", [
+    ({}, {}, False),
+    ({}, COMPACT, False),
+    ({}, {}, True),
+    ({}, COMPACT, True),
+    ({"fused_linear": False}, {}, True),
 ])
-def test_placed_steps_match_unplaced(request, table_layout, overrides,
-                                     padded):
+def test_placed_steps_match_unplaced(request, spec_over, overrides, padded):
     """Two steps through the single-chip branch of _place_field_state,
     then ``to_canonical`` (a mid-run eval or save, then the return,
-    which releases the loop's tables), give the parameters of the unplaced step: bitwise where
-    nothing is padded (the CPU's own defaults; a col spec anywhere),
-    to a rounding in the model's columns where the tables are."""
+    which releases the loop's tables), give the parameters of the
+    unplaced step: bitwise where nothing is padded (the CPU's own
+    defaults; an unfused spec's ``v`` / ``w`` anywhere, which its body
+    neither cuts on read nor pads on write), to a rounding in the
+    model's columns where the tables are."""
     if padded:
         request.getfixturevalue("chip_defaults")
-    pads = padded and table_layout == "row"
-    spec, batch = _spec("fm")
-    spec = dataclasses.replace(spec, table_layout=table_layout)
+    spec, batch = _spec("fm", **spec_over)
+    pads = padded and spec.fused_linear
     config = dataclasses.replace(CONFIG, **overrides)
     step, params, opt, prep, to_canonical, mesh = cli._place_field_state(
         spec, config, cli._FIELD_CAPS["FieldFMSpec"],
         spec.init(jax.random.key(3)), {}, 1, 1, False, 1, False)
     assert mesh is None
-    placed = device_lib.placement(params)
-    assert placed["table_layouts"] == [[0, 1]]
-    width = 128 if pads else spec.table_width
-    assert placed["table_device_bytes"] == FIELDS * BUCKET * width * 4
+    if spec.fused_linear:
+        placed = device_lib.placement(params)
+        assert placed["table_layouts"] == [[0, 1]]
+        width = 128 if pads else spec.table_width
+        assert placed["table_device_bytes"] == FIELDS * BUCKET * width * 4
+    else:
+        assert [t.shape for t in params["v"]] == [(BUCKET, 64)] * FIELDS
 
     bare = jax.jit(sparse.make_field_sparse_sgd_body(spec, config),
                    donate_argnums=(0,))
