@@ -242,7 +242,7 @@ def main():
         ("+device_put", source, put_block),
         ("+prefetcher", lambda: Prefetcher(source(),
                                            depth=args.prefetch_depth,
-                                           device_put=True),
+                                           place=jax.device_put),
          lambda b: jax.block_until_ready(b)),
     ]
     rates = {}
@@ -285,7 +285,7 @@ def main():
             r = _rate(
                 lambda: Prefetcher(stream_native(),
                                    depth=args.prefetch_depth,
-                                   device_put=True),
+                                   place=jax.device_put),
                 args.seconds, args.batch,
                 lambda b: jax.block_until_ready(b))
             streaming["stream_native+prefetch"] = r

@@ -600,7 +600,7 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
 
     if sharded:
         from fm_spark_tpu.parallel import (
-            make_field_mesh, pad_field_batch, shard_field_batch,
+            FieldBatchFeed, make_field_mesh, pad_field_batch,
             shard_field_deepfm_params, shard_field_params,
             stack_field_deepfm_params, stack_field_params,
             unstack_field_deepfm_params, unstack_field_params,
@@ -625,9 +625,9 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
                 p, tiled=True
             )
         else:
-            prep = lambda b: shard_field_batch(
-                pad_field_batch(b, spec.num_fields, n_feat), mesh
-            )
+            # Whole host batches through prep(b); from a loader that can
+            # hand rows over, each chip's shard made and sent apart.
+            prep = FieldBatchFeed(mesh, spec.num_fields)
             fetch = jax.device_get
         if is_deepfm:
             step = cap.sharded_step(spec, tconfig, mesh)
@@ -684,7 +684,8 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
 def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                       eval_source=None, prefetch: int = 0,
                       row_shards: int = 1, steps_per_call: int = 1,
-                      ckpt_sharded: bool = False, devices=None):
+                      ckpt_sharded: bool = False, devices=None,
+                      place_in_loop: bool = False):
     """Training loop on the fused sparse steps (the CTR fast path).
 
     On one device this is the single-chip fused step; with multiple
@@ -711,6 +712,13 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
     canonical checkpoint re-places onto them at resume — how the
     elastic retry wrapper continues a run on the surviving half of a
     shrunk fleet.
+
+    Batches are placed by the FEED (``wrap_prefetch(..., place=prep)``:
+    in the prefetcher's thread, each chip's shard made and sent apart on
+    a mesh), so the loop takes them from the queue already on the
+    chips. ``place_in_loop`` keeps ``prep`` on the loop's own thread
+    instead: the elastic wrapper's, whose mesh shrinks under it — a
+    queued batch placed on a lost chip is worse than a slow one.
     """
     import jax
     import jax.numpy as jnp
@@ -919,13 +927,20 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                                  total=tconfig.num_steps - start)
     from fm_spark_tpu.resilience import faults
 
-    batches, close_prefetch = wrap_prefetch(batches, prefetch)
+    if place_in_loop:
+        batches, close_prefetch = wrap_prefetch(batches, prefetch)
+    else:
+        batches, close_prefetch = wrap_prefetch(batches, prefetch,
+                                                place=prep)
+        prep = lambda placed: placed
     # The loop's hot intervals (obs.interval: always-live ring, profiler
     # annotation, trace.jsonl). Per iteration a parent ``train/step``
     # and inside it next_batch (the wait on the prefetch queue), prep
-    # (pad, shard, host-to-device placement), dispatch (the jitted call
-    # returning) and, at log cadence, loss_fetch (the fence: the host
-    # waiting for the device). The rest of train/step is its self time.
+    # (what placement is left on this thread: taking the tuple, unless
+    # place_in_loop — the feed's own share is feed/place), dispatch
+    # (the jitted call returning) and, at log cadence, loss_fetch (the
+    # fence: the host waiting for the device). The rest of train/step
+    # is its self time.
     try:
         if multi:
             i = start
@@ -1066,7 +1081,7 @@ def _fit_field_sparse_elastic(spec, tconfig, batches, checkpointer,
                 spec, tconfig, batches, logger, checkpointer,
                 eval_source=eval_source, prefetch=prefetch,
                 row_shards=row_shards, steps_per_call=steps_per_call,
-                devices=devices,
+                devices=devices, place_in_loop=True,
             )
             supervisor.note_success("train")
             if elastic.degraded and journal is not None:

@@ -13,6 +13,7 @@ from fm_spark_tpu.data.pipeline import (  # noqa: F401
     BernoulliBatches,
     DedupAuxBatches,
     MappedBatches,
+    PlacedBatches,
     Prefetcher,
     StackedBatches,
     iterate_once,

@@ -38,7 +38,7 @@ Overlap with compute comes from the existing
 :class:`~fm_spark_tpu.data.pipeline.Prefetcher`: wrap this source and
 chunk N+1 parses on the producer thread (the ctypes call releases the
 GIL) while batch N trains, with the device transfer double-buffered by
-``device_put=True`` — producer-thread failures surface as the same
+``place=jax.device_put`` — producer-thread failures surface as the same
 ``BadRecord`` / ``IngestAborted`` on the consumer side.
 
 Fault points: ``ingest_truncate`` fires per chunk read (same as

@@ -24,6 +24,13 @@ def train_test_split(ids, vals, labels, test_fraction=0.2, seed=0):
     return (ids[tr], vals[tr], labels[tr]), (ids[te], vals[te], labels[te])
 
 
+def _widened(a, width: int):
+    """``a`` with zero columns appended up to ``width``."""
+    out = np.zeros((a.shape[0], width), a.dtype)
+    out[:, :a.shape[1]] = a
+    return out
+
+
 class Batches:
     """Epoch-shuffling minibatch iterator over fixed-nnz arrays.
 
@@ -72,8 +79,12 @@ class Batches:
         self.index = int(state["index"])
         self._perm = None
 
-    def next_batch(self):
-        """Return ``(ids, vals, labels, weights)``, advancing the cursor."""
+    def next_rows(self):
+        """``(sel, weights)``: the next batch as row numbers into the
+        arrays, with its weights, advancing the cursor. ``next_batch``
+        gathers them whole; a feed that places a batch shard by shard
+        (:class:`PlacedBatches`) cuts ``sel`` into runs and has each
+        gathered by a worker of its own (:meth:`take`)."""
         n, b = self.num_examples, self.batch_size
         perm = self._epoch_perm()
         start = self.index
@@ -87,7 +98,7 @@ class Batches:
             self.epoch += 1
             self.index = 0
             self._perm = None
-            return self.next_batch()
+            return self.next_rows()
         else:
             sel = perm[start:n]
             pad = b - sel.shape[0]
@@ -98,7 +109,25 @@ class Batches:
             self.epoch += 1
             self.index = 0
             self._perm = None
-        return self.ids[sel], self.vals[sel], self.labels[sel], weights
+        return sel, weights
+
+    def take(self, sel, width: int | None = None):
+        """``(ids, vals, labels)`` of the rows ``sel``; ``width`` pads
+        ``ids`` and ``vals`` with zero columns up to it (a mesh's padded
+        field count). ``np.take`` and not ``a[sel]``: the same rows, a
+        third faster, and it lets go of the interpreter lock while it
+        copies, so a pool of callers runs side by side (four threads
+        indexing ``a[sel]`` take longer than one)."""
+        ids = np.take(self.ids, sel, axis=0)
+        vals = np.take(self.vals, sel, axis=0)
+        if width is not None and width != ids.shape[1]:
+            ids, vals = _widened(ids, width), _widened(vals, width)
+        return ids, vals, np.take(self.labels, sel, axis=0)
+
+    def next_batch(self):
+        """Return ``(ids, vals, labels, weights)``, advancing the cursor."""
+        sel, weights = self.next_rows()
+        return (*self.take(sel), weights)
 
     def __iter__(self):
         return self
@@ -376,6 +405,61 @@ class StackedBatches:
         return getattr(self._source, "guard", None)
 
 
+class PlacedBatches(MappedBatches):
+    """Batch-source wrapper whose batches are ON THE DEVICE(S) when it
+    hands them on: placement is the feed's job, not the training loop's,
+    and the LAST stage of a producer chain (after :class:`DedupAuxBatches`
+    / :class:`MappedBatches` / :class:`StackedBatches`; wrap before or,
+    through ``place=``, by :class:`Prefetcher`).
+
+    ``place(batch)`` takes a host batch and returns it placed (the
+    loops' ``prep``: ``jnp.asarray`` on one chip, a mesh's sharded
+    ``device_put``). A ``place`` that also has ``from_rows(take, sel,
+    weights)`` is handed, from a source that has ``next_rows`` /
+    ``take`` (:class:`Batches`), the batch as row numbers instead, and
+    makes each device's shard itself: gathered by a worker of its own
+    and sent straight to its device, no whole host batch in between
+    (``parallel.FieldBatchFeed``). Either way the batch is waited for
+    (``block_until_ready``) before it is handed on: what the consumer
+    takes has arrived.
+
+    One ``feed/place`` interval per batch (inside the producer's
+    ``feed/produce``): the call into ``place``, the per-shard gathers
+    included where they are its workers', with ``shards`` (addressable
+    devices the first array lies on) and ``bytes`` (of all its arrays).
+    ``state()`` is the source's: the cursor after the batch last made.
+    ``close()`` closes the ``place`` if it has something to close.
+    """
+
+    def __init__(self, source, place):
+        import jax
+
+        super().__init__(source, place)
+        self._jax = jax
+        self._by_rows = (hasattr(place, "from_rows")
+                         and hasattr(source, "next_rows"))
+
+    def next_batch(self):
+        if self._by_rows:
+            place = self._fn.from_rows
+            args = (self._source.take, *self._source.next_rows())
+        else:
+            place, args = self._fn, (self._source.next_batch(),)
+        with obs.interval("feed/place") as placed:
+            batch = self._jax.block_until_ready(place(*args))
+            leaves = self._jax.tree_util.tree_leaves(batch)
+            placed.set(
+                shards=len(leaves[0].sharding.addressable_devices),
+                bytes=sum(x.nbytes for x in leaves),
+            )
+        return batch
+
+    def close(self) -> None:
+        close = getattr(self._fn, "close", None)
+        if close is not None:
+            close()
+
+
 def _batch_rows(batch) -> int:
     """Examples in a batch tuple (``labels`` is element 2; a stacked
     batch counts every step's), 0 for a shape this cannot read."""
@@ -388,11 +472,11 @@ def _batch_rows(batch) -> int:
 class Prefetcher:
     """Background-thread batch prefetch with a bounded queue.
 
-    Overlaps host-side batch assembly (memmap reads, fancy indexing,
-    field-local id conversion) and optionally the host→device transfer
-    with device compute — the producer/consumer idiom grain/tf.data use,
-    kept dependency-free. Wraps any batch source with ``next_batch()``
-    (Batches, PackedBatches, cli.StreamingBatches).
+    Overlaps host-side batch assembly (memmap reads, row gathers,
+    field-local id conversion) and, with ``place``, the host→device
+    transfer with device compute — the producer/consumer idiom
+    grain/tf.data use, kept dependency-free. Wraps any batch source with
+    ``next_batch()`` (Batches, PackedBatches, cli.StreamingBatches).
 
     Checkpoint semantics: ``state()`` returns the wrapped source's cursor
     as of the LAST CONSUMED batch, not the producer's read-ahead cursor —
@@ -400,24 +484,27 @@ class Prefetcher:
     saw. (The producer snapshots ``source.state()`` after producing each
     batch and the snapshot travels with the batch through the queue.)
 
-    ``device_put=True`` moves each batch onto the default device inside
-    the producer thread (``jax.device_put`` is thread-safe), so transfer
-    cost is paid off the critical path.
+    ``place`` (a callable on a host batch; :class:`PlacedBatches` has
+    the contract) puts each batch on its device(s) inside the producer
+    thread (``jax.device_put`` is thread-safe), so the consumer takes
+    batches that have already arrived and ``feed/produce`` covers making
+    AND placing one. The prefetcher owns it from then on: ``close()``
+    closes it.
     """
 
     _STOP = object()
 
-    def __init__(self, source, depth: int = 2, device_put: bool = False):
+    def __init__(self, source, depth: int = 2, place=None):
         import queue
         import threading
 
-        self._source = source
+        self._placed = None if place is None else PlacedBatches(source, place)
+        self._source = source if place is None else self._placed
         self._has_state = hasattr(source, "state")
         self._last_state = source.state() if self._has_state else None
         self._q = queue.Queue(maxsize=max(1, int(depth)))
         self._stop = threading.Event()
         self._terminal = None
-        self._device_put = bool(device_put)
         self._thread = threading.Thread(target=self._produce, daemon=True)
         self._thread.start()
 
@@ -429,10 +516,6 @@ class Prefetcher:
                 # producer stood at a full queue — the feed's slack.
                 with obs.interval("feed/produce") as made:
                     batch = self._source.next_batch()
-                    if self._device_put:
-                        import jax
-
-                        batch = jax.device_put(batch)
                     made.set(rows=_batch_rows(batch))
                 state = self._source.state() if self._has_state else None
                 with obs.interval("feed/put_wait", rows=made.attrs["rows"]):
@@ -506,6 +589,8 @@ class Prefetcher:
         except Exception:
             pass
         self._thread.join(timeout=5)
+        if self._placed is not None:
+            self._placed.close()
 
     def __enter__(self):
         return self
@@ -514,21 +599,30 @@ class Prefetcher:
         self.close()
 
 
-def wrap_prefetch(batches, depth: int):
+def wrap_prefetch(batches, depth: int, place=None):
     """Wrap a batch source with a :class:`Prefetcher`; returns
     ``(source, close)``. No-op (identity source, noop close) when
     ``depth <= 0`` or the source has no ``next_batch`` (plain
     iterables can't be safely read ahead AND checkpointed).
+
+    ``place`` makes the source hand out batches that are on the
+    device(s) already (:class:`PlacedBatches`): in the producer thread
+    where there is one, in the caller's where there is none — the
+    caller takes placed batches either way, and ``close`` closes the
+    ``place`` too.
 
     Call AFTER any checkpoint restore — the producer thread starts
     reading ahead immediately, so a later restore would race it.
     Single definition shared by cli training loops and FMTrainer.fit
     so prefetch lifecycle semantics can never diverge between them.
     """
-    if depth <= 0 or not hasattr(batches, "next_batch"):
+    if depth > 0 and hasattr(batches, "next_batch"):
+        pf = Prefetcher(batches, depth=depth, place=place)
+        return pf, pf.close
+    if place is None:
         return batches, lambda: None
-    pf = Prefetcher(batches, depth=depth)
-    return pf, pf.close
+    placed = PlacedBatches(batches, place)
+    return placed, placed.close
 
 
 def iterate_once(ids, vals, labels, batch_size: int):

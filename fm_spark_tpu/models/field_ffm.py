@@ -61,6 +61,15 @@ class FieldFFMSpec(base.ModelSpec):
         return self.num_fields * self.rank + 1
 
     def init(self, rng: jax.Array) -> dict:
+        """One table at a time, each waited for (as ``rows.hold`` forms
+        them): buffers are allocated as work is queued, ahead of the
+        device, so an init left to run ahead holds a few tables' worth
+        of transients and where the tables come to lie on the chip
+        follows how far ahead the host happened to get: a different
+        layout every run, and at this cell's sizes (23 x 192 MiB) some
+        of them scatter and gather 1-3% slower for the whole run
+        (PERF.md §6, PR 33). Waited for, the layout is one and the
+        transient one table's."""
         f, k = self.num_fields, self.rank
         keys = jax.random.split(rng, f)
         tables = []
@@ -69,11 +78,11 @@ class FieldFFMSpec(base.ModelSpec):
                 jax.random.normal(keys[i], (self.bucket, f * k), jnp.float32)
                 * self.init_std
             ).astype(self.pdtype)
-            tables.append(
-                jnp.concatenate(
-                    [v, jnp.zeros((self.bucket, 1), self.pdtype)], axis=1
-                )
+            table = jnp.concatenate(
+                [v, jnp.zeros((self.bucket, 1), self.pdtype)], axis=1
             )
+            del v
+            tables.append(jax.block_until_ready(table))
         return {"w0": jnp.zeros((), jnp.float32), "vw": tables}
 
     def gather_rows(self, params: dict, ids: jax.Array):

@@ -31,6 +31,7 @@ from fm_spark_tpu.parallel.step import (  # noqa: F401
     precompile_parallel_train_step,
 )
 from fm_spark_tpu.parallel.field_step import (  # noqa: F401
+    FieldBatchFeed,
     field_batch_specs,
     field_param_specs,
     make_field_deepfm_sharded_step,

@@ -161,10 +161,87 @@ def shard_field_params(stacked: dict, mesh) -> dict:
 
 
 def shard_field_batch(batch, mesh):
+    """Place a host batch (already ``F_pad`` wide) example-sharded over
+    the mesh. Each array goes to ``device_put`` AS IT IS, with the
+    sharding: a NumPy array is cut into the devices' row runs on the
+    host and each run sent to its own device. Never ``jnp.asarray(x)``
+    first: that stages the WHOLE batch on chip 0 and the ``device_put``
+    then re-shards it from there with a device program
+    (``jit__multi_slice``) that queues behind chip 0's running step:
+    228 ms of ``train/prep`` in a 349 ms step of ``fm_r64.train_4chip``
+    (ledger, PR 31). The training loop's feed makes the shards apart and
+    in parallel (:class:`FieldBatchFeed`); this is the one-call form for
+    a batch that exists whole (evals, the elastic loop, tests)."""
     return tuple(
-        jax.device_put(jnp.asarray(x), NamedSharding(mesh, s))
+        jax.device_put(x, NamedSharding(mesh, s))
         for x, s in zip(batch, field_batch_specs(mesh))
     )
+
+
+class FieldBatchFeed:
+    """The mesh's ``place`` for the training loop's feed
+    (``data.wrap_prefetch(batches, depth, place=...)``): batches come to
+    the loop example-sharded over ``mesh``, each addressable device's
+    shard made and sent by a worker of its own.
+
+    ``feed(batch)`` takes a whole host batch ``(ids, vals, labels,
+    weights)``: :func:`pad_field_batch` + :func:`shard_field_batch`.
+    ``feed.from_rows(take, sel, weights)`` is what
+    ``data.PlacedBatches`` calls when the source can hand the batch over
+    as row numbers (``data.Batches``): ``sel`` is cut into one contiguous
+    run per device, in the order the sharding lays rows out, and one
+    worker per device gathers its run straight into ``[b, F_pad]``
+    arrays (``take(run, F_pad)``), sends them to ITS device, and the
+    global arrays are assembled from the pieces — the same bits on the
+    same devices as the whole-batch form, with neither a whole host
+    batch nor a second pass to pad it. The workers are as many as the
+    sharding's addressable devices (a pool, started at the first batch;
+    one device: the caller's own thread) and ``close()`` joins them.
+    Single-process meshes only: across processes each feeds its own
+    rows (:func:`shard_field_batch_local`).
+    """
+
+    def __init__(self, mesh, num_fields: int):
+        self._mesh = mesh
+        self._num_fields = num_fields
+        self._n_feat = mesh.shape["feat"]
+        self._f_pad = padded_num_fields(num_fields, self._n_feat)
+        self._shardings = tuple(
+            NamedSharding(mesh, s) for s in field_batch_specs(mesh))
+        self._pool = None
+
+    def __call__(self, batch):
+        return shard_field_batch(
+            pad_field_batch(batch, self._num_fields, self._n_feat),
+            self._mesh)
+
+    def from_rows(self, take, sel, weights):
+        rows = self._shardings[2].addressable_devices_indices_map(
+            (len(sel),))
+
+        def shard(device):
+            run = rows[device][0]
+            return jax.device_put(
+                (*take(sel[run], self._f_pad), weights[run]), device)
+
+        if len(rows) > 1 and self._pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            self._pool = ThreadPoolExecutor(
+                len(rows), thread_name_prefix="fm-spark-feed")
+        each = self._pool.map if self._pool is not None else map
+        parts = list(each(shard, rows))
+        return tuple(
+            jax.make_array_from_single_device_arrays(
+                (len(sel), *parts[0][k].shape[1:]), sharding,
+                [part[k] for part in parts])
+            for k, sharding in enumerate(self._shardings)
+        )
+
+    def close(self) -> None:
+        pool, self._pool = self._pool, None
+        if pool is not None:
+            pool.shutdown(wait=True, cancel_futures=True)
 
 
 def shard_field_batch_local(batch, mesh):
@@ -663,9 +740,10 @@ def stacked_field_batch_specs(mesh) -> tuple:
 def shard_field_batch_stacked(stacked, mesh):
     """Device-place an ``[m, ...]``-stacked batch tuple
     (data/pipeline.StackedBatches over F_pad-padded batches) for
-    :func:`make_field_sharded_multistep`."""
+    :func:`make_field_sharded_multistep`. Host arrays go to their
+    devices run by run, as in :func:`shard_field_batch`."""
     return tuple(
-        jax.device_put(jnp.asarray(x), NamedSharding(mesh, sp))
+        jax.device_put(x, NamedSharding(mesh, sp))
         for x, sp in zip(stacked, stacked_field_batch_specs(mesh))
     )
 

@@ -28,6 +28,32 @@ def _batch(rng, b, F, bucket):
     )
 
 
+def test_init_waits_for_each_table_and_keeps_its_values(monkeypatch):
+    """init makes the tables one at a time, each waited for before the
+    next is queued (where they lie on the chip must not follow how far
+    ahead the host got), with the values the plain expression gives,
+    and still traces (eval_shape: what restores and lowerings build
+    their shapes from)."""
+    spec = _spec(F=5, bucket=8, k=2)
+    waited = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: (waited.append(x.shape), real(x))[1])
+    params = spec.init(jax.random.key(7))
+    monkeypatch.undo()
+    assert waited == [(8, 11)] * 5
+    assert all(t.is_ready() for t in params["vw"])
+    keys = jax.random.split(jax.random.key(7), 5)
+    for key, table in zip(keys, params["vw"]):
+        v = jax.random.normal(key, (8, 10), jnp.float32) * spec.init_std
+        np.testing.assert_array_equal(np.asarray(table[:, :10]),
+                                      np.asarray(v))
+        assert not np.asarray(table[:, 10]).any()
+    shapes = jax.eval_shape(spec.init, jax.random.key(7))
+    assert [s.shape for s in shapes["vw"]] == [(8, 11)] * 5
+
+
 def test_scores_match_flat_ffm():
     rng = np.random.default_rng(0)
     spec = _spec()

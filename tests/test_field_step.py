@@ -419,3 +419,98 @@ def test_deepfm_sharded_eval_matches_canonical(rng):
     for key in ("auc", "logloss", "rmse", "count"):
         np.testing.assert_allclose(got[key], want[key], rtol=1e-5,
                                    atol=1e-6, err_msg=key)
+
+
+# ------------------------------------------- PR 33: the feed places batches
+#
+# Placement moved from the loop's thread into the feed; no device program
+# moved with it. Two pins: the steps lower to the text they had, and a
+# batch the feed placed lowers a step exactly as one the loop placed.
+
+_PIN_FIELDS, _PIN_BUCKET, _PIN_BATCH = 6, 4096, 256
+_PIN_CONFIG = TrainConfig(learning_rate=0.05, lr_schedule="constant",
+                          optimizer="sgd", reg_factors=1e-6)
+# sha256 of ``Lowered.as_text()`` at the parent of PR 33 (commit 80133fb;
+# jax 0.9.0), CPU devices. A PR that MEANS to change a step's program
+# re-pins its line and says so; any other PR must leave these alone.
+_LOWERED_AT_PARENT = {
+    ("fm", 1): "16ddeda5d213e482d9b8b6a64759fe0922d9344ab5b89990fb4fa1a8fe969916",
+    ("fm", 4): "decc58193a7aacd359914542dd49c9866df3a719f7bdb306aeb4fb6c8a9be57d",
+    ("ffm", 1): "cb8a46468237aa7f82ef13d929222f166b66a3d1625374c2a06b7cd31150e1a9",
+    ("ffm", 4): "6f788a6918c6fb831577e61b343110f6c029f0287432e1826a3f1cb595088077",
+    ("deepfm", 1): "443ef79d5a6f6d907744ece4636c7362bdcbc3f6e7c207e00d27e202f8d89f76",
+    ("deepfm", 4): "f29e8a63f69cffdfb82aa9f62b231eee58d436080fdc93e0982a369e018e59ac",
+}
+
+
+def _pin_spec(family):
+    common = dict(num_features=_PIN_FIELDS * _PIN_BUCKET,
+                  num_fields=_PIN_FIELDS, bucket=_PIN_BUCKET)
+    if family == "fm":
+        return models.FieldFMSpec(rank=64, **common)
+    if family == "ffm":
+        return models.FieldFFMSpec(rank=16, **common)
+    return models.FieldDeepFMSpec(rank=16, mlp_dims=(32, 16), **common)
+
+
+@pytest.mark.parametrize("family,chips", sorted(_LOWERED_AT_PARENT))
+def test_step_lowers_to_the_parents_text(family, chips):
+    import hashlib
+
+    from fm_spark_tpu import sparse
+    from fm_spark_tpu.parallel import lower_field_sharded_step
+
+    spec = _pin_spec(family)
+    lowered = (
+        sparse.lower_field_sparse_step(spec, _PIN_CONFIG, _PIN_BATCH)
+        if chips == 1 else
+        lower_field_sharded_step(spec, _PIN_CONFIG, make_field_mesh(chips),
+                                 _PIN_BATCH))
+    text = lowered.as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == _LOWERED_AT_PARENT[family, chips])
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_feed_placed_batch_lowers_the_step_as_a_loop_placed_one(chips):
+    """The loop's own state (cli._place_field_state) and the batch both
+    ways: placed as the parent's loop placed it (``jnp.asarray`` of the
+    whole batch, then the re-sharding ``device_put`` on a mesh), and
+    taken from the feed. Same avals, same shardings, same program."""
+    from jax.sharding import NamedSharding
+
+    from fm_spark_tpu import cli, sparse
+    from fm_spark_tpu.data import Batches, wrap_prefetch
+    from fm_spark_tpu.parallel import field_batch_specs
+
+    spec = _pin_spec("fm")
+    _, params, _, prep, _, mesh = cli._place_field_state(
+        spec, _PIN_CONFIG, cli._FIELD_CAPS["FieldFMSpec"],
+        spec.init(jax.random.key(3)), {}, chips, 1, chips > 1, 1, False,
+        devices=jax.devices()[:chips])
+    rng = np.random.default_rng(0)
+    ids, vals, labels, _ = _make_batch(rng, 4 * _PIN_BATCH, _PIN_FIELDS,
+                                       _PIN_BUCKET)
+    by_loop = Batches(ids, vals, labels, _PIN_BATCH, seed=1).next_batch()
+    if mesh is None:
+        step = make_field_sparse_sgd_step(spec, _PIN_CONFIG)
+        by_loop = tuple(map(jnp.asarray, by_loop))
+    else:
+        step = make_field_sharded_sgd_step(spec, _PIN_CONFIG, mesh)
+        by_loop = tuple(
+            jax.device_put(jnp.asarray(x), NamedSharding(mesh, s))
+            for x, s in zip(pad_field_batch(by_loop, _PIN_FIELDS, chips),
+                            field_batch_specs(mesh)))
+    source, close = wrap_prefetch(
+        Batches(ids, vals, labels, _PIN_BATCH, seed=1), 2, place=prep)
+    try:
+        by_feed = source.next_batch()
+    finally:
+        close()
+    for a, b in zip(by_feed, by_loop):
+        assert (a.shape, a.dtype, a.sharding) == (b.shape, b.dtype,
+                                                  b.sharding)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    i = jnp.int32(0)
+    assert (step.lower(params, i, *by_feed).as_text()
+            == step.lower(params, i, *by_loop).as_text())

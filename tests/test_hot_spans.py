@@ -234,6 +234,32 @@ def test_train_loop_rolled(tiny_fm, monkeypatch):
                for iv in since(t, "feed/produce")[:3])
 
 
+@pytest.mark.parametrize("devices,extra,shards", [
+    (1, (), 1), (4, (), 4),
+    (1, ("--steps-per-call", "2"), 1), (4, ("--steps-per-call", "2"), 4),
+], ids=["one_device", "mesh4", "one_device_rolled", "mesh4_rolled"])
+def test_feed_places_every_batch(tiny_fm, monkeypatch, devices, extra,
+                                 shards):
+    """Placement is the feed's: one ``feed/place`` inside every
+    ``feed/produce``, on the producer's thread, ``shards`` the devices a
+    batch lies on; the loop's ``train/prep`` is still there
+    (``check_steps``) around what is left on its thread."""
+    monkeypatch.setattr(jax, "device_count", lambda *a: devices)
+    t = train(tiny_fm, *extra)
+    produced = since(t, "feed/produce")
+    placed = since(t, "feed/place")
+    assert len(placed) >= STEPS // (2 if extra else 1)
+    made = {iv.span_id: iv for iv in produced}
+    assert len({iv.parent_id for iv in placed}) == len(placed)
+    for iv in placed:
+        parent = made[iv.parent_id]
+        assert parent.t0 <= iv.t0 <= iv.t1 <= parent.t1
+        assert iv.thread == parent.thread != threading.get_ident()
+        assert iv.attrs["shards"] == shards and iv.attrs["bytes"] > 0
+    assert not [th for th in threading.enumerate()
+                if th.name.startswith("fm-spark-feed")]
+
+
 def test_run_directory_gets_the_same_names(tiny_fm, monkeypatch, tmp_path):
     monkeypatch.setattr(jax, "device_count", lambda *a: 1)
     t = train(tiny_fm, "--obs-dir", str(tmp_path))

@@ -1,6 +1,9 @@
 """Batch pipeline: determinism, resume, epoch coverage, padding."""
 
+import threading
+
 import numpy as np
+import pytest
 
 from fm_spark_tpu.data import Batches, iterate_once, synthetic_ctr, train_test_split
 
@@ -233,6 +236,196 @@ def test_prefetcher_restore_after_start_raises_documented_error():
     with Prefetcher(src, depth=2) as pf:
         with pytest.raises(RuntimeError, match="BEFORE constructing"):
             pf.restore({"epoch": 0, "index": 0, "seed": 3})
+
+
+# ------------------------------------------- the feed places its batches
+#
+# wrap_prefetch(batches, depth, place=...): what the loop takes is on the
+# devices already. The reference is the parent's way, written out: the
+# loader's ``a[sel]`` gather, pad_field_batch, and shard_field_batch as it
+# was (``jnp.asarray`` of the whole batch, then a re-sharding device_put).
+
+FIELDS = 39
+_LOCK = threading.Lock()
+
+
+def _feed_workers():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("fm-spark-feed")]
+
+
+def _parent_next_batch(b):
+    """Batches.next_batch as it read before next_rows / take: fancy
+    indexing on the one thread (the loop version kept as the reference)."""
+    n, size = b.num_examples, b.batch_size
+    perm = b._epoch_perm()
+    start, end = b.index, b.index + size
+    if end <= n:
+        sel, weights = perm[start:end], np.ones((size,), np.float32)
+        b.index = end
+    elif b.drop_remainder or start >= n:
+        b.epoch, b.index, b._perm = b.epoch + 1, 0, None
+        return _parent_next_batch(b)
+    else:
+        sel = perm[start:n]
+        pad = size - sel.shape[0]
+        weights = np.concatenate([np.ones(sel.shape[0], np.float32),
+                                  np.zeros(pad, np.float32)])
+        sel = np.concatenate([sel, np.zeros(pad, np.int64)])
+        b.epoch, b.index, b._perm = b.epoch + 1, 0, None
+    return b.ids[sel], b.vals[sel], b.labels[sel], weights
+
+
+def _parent_shard_field_batch(batch, mesh):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from fm_spark_tpu.parallel import field_batch_specs
+
+    return tuple(jax.device_put(jnp.asarray(x), NamedSharding(mesh, s))
+                 for x, s in zip(batch, field_batch_specs(mesh)))
+
+
+@pytest.mark.parametrize("drop_remainder", [False, True])
+def test_next_rows_and_take_make_the_parents_batches(drop_remainder):
+    ids, vals, labels = _data(n=70, nnz=FIELDS, f=FIELDS * 64)
+    ref = Batches(ids, vals, labels, 16, seed=5,
+                  drop_remainder=drop_remainder)
+    b = Batches(ids, vals, labels, 16, seed=5, drop_remainder=drop_remainder)
+    for _ in range(10):
+        for want, got in zip(_parent_next_batch(ref), b.next_batch()):
+            assert want.dtype == got.dtype
+            np.testing.assert_array_equal(want, got)
+        assert b.state() == ref.state()
+    sel = np.array([3, 0, 3])
+    wide_ids, wide_vals, _ = b.take(sel, width=40)
+    assert wide_ids.shape == wide_vals.shape == (3, 40)
+    np.testing.assert_array_equal(wide_ids[:, :FIELDS], ids[sel])
+    assert not wide_ids[:, FIELDS:].any() and not wide_vals[:, FIELDS:].any()
+
+
+@pytest.mark.parametrize("by_rows", [True, False],
+                         ids=["by_rows", "host_batch"])
+@pytest.mark.parametrize("depth", [2, 0], ids=["prefetched", "inline"])
+@pytest.mark.parametrize("n_feat,n_row", [(4, 1), (2, 2)],
+                         ids=["feat4", "feat2xrow2"])
+def test_placed_feed_is_the_parents_stream_bit_for_bit(n_feat, n_row, depth,
+                                                       by_rows):
+    """Ten batches across two epoch boundaries (70 rows in batches of 16:
+    the fifth of an epoch is a padded tail), from a four-shard place:
+    shard by shard and as global arrays what the parent's loop placed, on
+    the mesh's own NamedSharding with every shard on a device of its own,
+    and ``state()`` after each consumed batch the parent's cursor."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    from fm_spark_tpu import obs
+    from fm_spark_tpu.data import wrap_prefetch
+    from fm_spark_tpu.parallel import (
+        FieldBatchFeed, field_batch_specs, make_field_mesh, pad_field_batch,
+    )
+
+    mesh = make_field_mesh(n_feat * n_row, n_row=n_row)
+    ids, vals, labels = _data(n=70, nnz=FIELDS, f=FIELDS * 64)
+    ref = Batches(ids, vals, labels, 16, seed=11)
+    feed = FieldBatchFeed(mesh, FIELDS)
+    place = feed if by_rows else (lambda b: feed(b))
+    seen = len(obs.intervals())
+    source, close = wrap_prefetch(Batches(ids, vals, labels, 16, seed=11),
+                                  depth, place=place)
+    try:
+        for _ in range(10):
+            got = source.next_batch()
+            want = _parent_shard_field_batch(
+                pad_field_batch(_parent_next_batch(ref), FIELDS, n_feat),
+                mesh)
+            assert source.state() == ref.state()
+            for g, w, spec in zip(got, want, field_batch_specs(mesh)):
+                assert g.sharding == w.sharding == NamedSharding(mesh, spec)
+                assert g.dtype == w.dtype and g.shape == w.shape
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+                assert ({s.device for s in g.addressable_shards}
+                        == set(mesh.devices.flat))
+                for gs, ws in zip(g.addressable_shards,
+                                  w.addressable_shards):
+                    assert (gs.device, gs.index) == (ws.device, ws.index)
+                    np.testing.assert_array_equal(np.asarray(gs.data),
+                                                  np.asarray(ws.data))
+        assert ref.epoch == 2
+    finally:
+        close()
+    placed = [r for r in obs.intervals()[seen:] if r.name == "feed/place"]
+    assert len(placed) >= 10
+    nbytes = 16 * (2 * 40 * 4 + 2 * 4)
+    assert {(r.attrs["shards"], r.attrs["bytes"]) for r in placed} == {
+        (4, nbytes)}
+    if depth:
+        made = {r.span_id for r in obs.intervals()[seen:]
+                if r.name == "feed/produce"}
+        assert all(r.parent_id in made for r in placed)
+
+
+def test_one_device_place_is_one_shard_on_the_callers_thread():
+    """The loop's one-chip ``prep`` as the place: ``shards=1``, no pool,
+    the batch a jax array when taken."""
+    import jax
+    import jax.numpy as jnp
+
+    from fm_spark_tpu import obs
+    from fm_spark_tpu.data import wrap_prefetch
+
+    ids, vals, labels = _data(n=64)
+    ref = Batches(ids, vals, labels, 16, seed=2)
+    host = lambda b: jax.tree_util.tree_map(jnp.asarray, tuple(b))
+    seen = len(obs.intervals())
+    source, close = wrap_prefetch(Batches(ids, vals, labels, 16, seed=2), 2,
+                                  place=host)
+    try:
+        for _ in range(5):
+            got, want = source.next_batch(), ref.next_batch()
+            assert all(isinstance(g, jax.Array) for g in got)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), w)
+            assert source.state() == ref.state()
+    finally:
+        close()
+    placed = [r for r in obs.intervals()[seen:] if r.name == "feed/place"]
+    assert placed and {r.attrs["shards"] for r in placed} == {1}
+    assert not _feed_workers()
+
+
+def test_a_shard_workers_error_reaches_the_consumer_and_close_joins():
+    """One of the four gathers fails: the consumer gets that error once
+    and on every later call, and ``close()`` leaves no worker behind."""
+    from fm_spark_tpu.data import Prefetcher
+    from fm_spark_tpu.parallel import FieldBatchFeed, make_field_mesh
+
+    class Flaky(Batches):
+        calls = 0
+
+        def take(self, sel, width=None):
+            with _LOCK:
+                Flaky.calls += 1
+                n = Flaky.calls
+            if n == 7:              # the third run of the second batch
+                raise OSError("a shard's rows could not be read")
+            return super().take(sel, width)
+
+    ids, vals, labels = _data(n=200, nnz=FIELDS, f=FIELDS * 64)
+    pf = Prefetcher(Flaky(ids, vals, labels, 32, seed=0), depth=1,
+                    place=FieldBatchFeed(make_field_mesh(4), FIELDS))
+    try:
+        assert pf.next_batch()[0].shape == (32, 40)
+        assert _feed_workers()
+        for _ in range(3):
+            with pytest.raises(OSError, match="could not be read"):
+                pf.next_batch()
+    finally:
+        pf.close()
+    assert not pf._thread.is_alive()
+    assert not _feed_workers()
+    pf.close()                      # idempotent with a pool too
 
 
 # ------------------------------------------------------- BernoulliBatches

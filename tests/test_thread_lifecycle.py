@@ -51,7 +51,8 @@ def test_static_rule_every_package_thread_daemon_or_joined():
 def test_no_live_nondaemon_threads_after_clean_shutdown(tmp_path):
     """The runtime half (the satellite's pin): spin up every
     package-owned thread population this suite can construct cheaply —
-    prefetcher producer, metrics endpoint, watchdog exit-mode monitor —
+    prefetcher producer, its placing pool, metrics endpoint, watchdog
+    exit-mode monitor —
     drive them, shut them all down cleanly, and enumerate: the
     non-daemon thread set is exactly what it was before, and no
     fm-spark-named thread survives."""
@@ -60,6 +61,22 @@ def test_no_live_nondaemon_threads_after_clean_shutdown(tmp_path):
     # Prefetcher producer thread.
     pf = Prefetcher(_CountSource(), depth=2)
     assert pf.next_batch() >= 1
+
+    # The placing prefetcher's pool: one worker per device of the mesh
+    # (non-daemon executor threads; close() is what joins them).
+    import numpy as np
+
+    from fm_spark_tpu.data import Batches
+    from fm_spark_tpu.parallel import FieldBatchFeed, make_field_mesh
+
+    rows = np.arange(64 * 3, dtype=np.int32).reshape(64, 3)
+    placing = Prefetcher(
+        Batches(rows, rows.astype(np.float32), np.ones(64, np.float32), 16),
+        depth=2, place=FieldBatchFeed(make_field_mesh(4), 3))
+    assert placing.next_batch()[0].shape == (16, 4)
+    # (The executor starts a worker only when none is idle: 1 to 4.)
+    assert 1 <= len([t for t in _fm_threads()
+                     if t.name.startswith("fm-spark-feed")]) <= 4
 
     # Live-metrics endpoint (ThreadingHTTPServer + serve_forever).
     server = obs_export.start_metrics_server(port=0)
@@ -76,6 +93,7 @@ def test_no_live_nondaemon_threads_after_clean_shutdown(tmp_path):
     obs.configure(str(tmp_path / "obs"), run_id="r-threads")
 
     pf.close()
+    placing.close()
     table.close()
     obs.shutdown()
 
@@ -87,6 +105,7 @@ def test_no_live_nondaemon_threads_after_clean_shutdown(tmp_path):
                            f"{[t.name for t in leftover]}"
     assert _nondaemon_threads() == before
     assert not pf._thread.is_alive()
+    assert not placing._thread.is_alive()
     assert exits == []  # the monitor never fired on a healthy phase
 
 
