@@ -309,9 +309,11 @@ def _single_fm_step(spec, tconfig):
 
 
 def _single_ffm_step(spec, tconfig):
-    from fm_spark_tpu.sparse import make_field_ffm_sparse_sgd_step
+    from fm_spark_tpu import sparse
 
-    return make_field_ffm_sparse_sgd_step(spec, tconfig)
+    if tconfig.optimizer == "adagrad":
+        return sparse.make_field_ffm_adagrad_step(spec, tconfig)
+    return sparse.make_field_ffm_sparse_sgd_step(spec, tconfig)
 
 
 def _single_deepfm_step(spec, tconfig):
@@ -351,6 +353,8 @@ class _FieldCap:
     single_step: callable            # (spec, tconfig) -> step
     sharded_step: callable | None    # (spec, tconfig, mesh) -> step
     carries_opt: bool                # optax state rides the step (DeepFM)
+    table_rules: tuple               # table optimizers besides 'sgd' whose
+                                     # slot tables ride the one-chip step
     sharded_2d: bool                 # 2-D (feat, row) mesh (--row-shards)
     sharded_host_compact: bool       # host-built compact aux when sharded
     sharded_device_compact: bool     # in-step compact aux when sharded
@@ -364,14 +368,16 @@ class _FieldCap:
 _FIELD_CAPS = {
     "FieldFMSpec": _FieldCap(
         single_step=_single_fm_step, sharded_step=_sharded_fm_step,
-        carries_opt=False, sharded_2d=True, sharded_host_compact=True,
+        carries_opt=False, table_rules=(),
+        sharded_2d=True, sharded_host_compact=True,
         sharded_device_compact=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
         sharded_score=True, sharded_deep=False,
     ),
     "FieldFFMSpec": _FieldCap(
         single_step=_single_ffm_step, sharded_step=_sharded_ffm_step,
-        carries_opt=False, sharded_2d=True, sharded_host_compact=True,
+        carries_opt=False, table_rules=("adagrad",),
+        sharded_2d=True, sharded_host_compact=True,
         sharded_device_compact=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
         sharded_score=False, sharded_deep=False,
@@ -379,7 +385,8 @@ _FIELD_CAPS = {
     "FieldDeepFMSpec": _FieldCap(
         single_step=_single_deepfm_step,
         sharded_step=_sharded_deepfm_step,
-        carries_opt=True, sharded_2d=True, sharded_host_compact=False,
+        carries_opt=True, table_rules=(),
+        sharded_2d=True, sharded_host_compact=False,
         sharded_device_compact=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
         sharded_score=False, sharded_deep=True,
@@ -533,6 +540,16 @@ def _validate_field_caps(spec, tconfig, cap, n, pc, sharded,
             f"--steps-per-call must be >= 1, got {steps_per_call}"
         )
     multi = steps_per_call > 1
+    if tconfig.optimizer in cap.table_rules and (sharded or multi):
+        # The slot tables ride the one-chip step alone: the mesh steps
+        # and the fori roll write their tables by plain SGD.
+        raise SystemExit(
+            f"--optimizer {tconfig.optimizer} on the tables of "
+            f"{type(spec).__name__} runs on one chip, one step a call "
+            f"(found {n} device(s), --steps-per-call {steps_per_call}); "
+            "the field-sharded steps and the multistep roll implement "
+            "plain SGD only"
+        )
     if multi:
         if sharded:
             # The SHARDED roll (round 4): the fori rides inside the
@@ -580,12 +597,15 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
     field-sharded (1-D/2-D mesh, single- or multi-process), with the
     uniform ``(params, opt, i, *b) → (params, opt, loss)`` step shape.
     Returns ``(step, params, opt, prep, to_canonical, mesh)`` —
-    ``mesh`` is None single-chip. Split out of _fit_field_sparse
+    ``mesh`` is None single-chip. A step that carries table slots
+    (``cap.table_rules``; :func:`_hold_slots` places them) returns a
+    fourth value, its ``stats``. Split out of _fit_field_sparse
     (VERDICT r3)."""
     import jax
     import jax.numpy as jnp
 
     is_deepfm = cap.carries_opt
+    slots = tconfig.optimizer in cap.table_rules
     mesh = None
 
     def adapt(step_pl):
@@ -664,7 +684,7 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
         from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
         built = cap.single_step(spec, tconfig)
-        step = built if is_deepfm else adapt(built)
+        step = built if is_deepfm or slots else adapt(built)
         # The loop holds its tables in the form models/rows.py chooses
         # for a holder that writes (lane-padded where the chip would
         # otherwise transpose each in and out of a step), formed once
@@ -679,6 +699,30 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
         opt, prep = opt0, host
 
     return step, params, opt, prep, to_canonical, mesh
+
+
+def _hold_slots(slots0):
+    """The one-chip loop's table slots (``optim.init_field_slots``'s
+    tree, or a checkpoint's) placed as its tables are: each slot table
+    in the form ``models/rows.py`` gives its table, by the same walk,
+    the canonical one let go as its held one arrives. Returns ``(slots,
+    to_host)``; ``to_host(slots)`` is the canonical tree as NumPy, one
+    table at a time, so that a checkpoint never stands a second copy of
+    the slots beside the first on the device."""
+    import jax
+    import numpy as np
+
+    from fm_spark_tpu import obs
+    from fm_spark_tpu.models import rows
+    from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
+
+    slots, shapes, held = rows.hold(slots0, FUSED_TABLE_KEYS,
+                                    writes=True, consume=True)
+    obs.gauge("train/slot_table_bytes").set(held["resident_table_bytes"])
+    to_host = lambda o: jax.tree.map(
+        lambda shape, leaf: np.asarray(rows.canonical(leaf, shape)),
+        shapes, o)
+    return slots, to_host
 
 
 def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
@@ -733,6 +777,7 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         )
     sharded = n > 1
     is_deepfm = cap.carries_opt
+    slots = tconfig.optimizer in cap.table_rules
 
     # ---- validation + placement (helpers above) -----------------------
     compact_sharded, multi = _validate_field_caps(
@@ -766,6 +811,20 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         opt0 = make_optimizer(tconfig).init(
             {"w0": canonical["w0"], "mlp": canonical["mlp"]}
         )
+    elif slots:
+        # Table-sized state: only its shapes until a checkpoint has had
+        # its say (a restore reads into them), so that a resume never
+        # holds fresh slots beside the restored ones.
+        import functools
+
+        from fm_spark_tpu import optim
+        from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
+
+        init_slots = functools.partial(
+            optim.init_field_slots, tconfig.optimizer,
+            keys=FUSED_TABLE_KEYS,
+            init_accumulator=tconfig.adagrad_init_accumulator)
+        opt0 = jax.eval_shape(init_slots, canonical)
     start = 0
     if not ckpt_sharded:
         # Default: checkpoints use the canonical per-field-list layout so
@@ -773,6 +832,8 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         # happens AFTER params are placed on the mesh, below.)
         canonical, opt0, start = _resume(checkpointer, canonical, opt0,
                                          batches)
+    if slots and isinstance(jax.tree.leaves(opt0)[0], jax.ShapeDtypeStruct):
+        opt0 = init_slots(canonical)
 
     step, params, opt, prep, to_canonical, mesh = _place_field_state(
         spec, tconfig, cap, canonical, opt0, n, pc, sharded, row_shards,
@@ -824,9 +885,9 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
     since = 0
     from fm_spark_tpu.data import wrap_prefetch
 
-    opt_canonical = (
-        (lambda o: jax.device_get(o)) if is_deepfm else (lambda o: {})
-    )
+    opt_canonical = jax.device_get if is_deepfm else (lambda o: {})
+    if slots:
+        opt, opt_canonical = _hold_slots(opt)
 
     def pipe_state():
         """Pipeline cursor for checkpoints. Multi-host: strip the
@@ -995,15 +1056,21 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                     with obs.interval("train/prep", step=i):
                         placed = prep(batch)
                     with obs.interval("train/dispatch", step=i):
-                        params, opt, loss = step(params, opt, jnp.int32(i),
-                                                 *placed)
+                        params, opt, loss, *stats = step(
+                            params, opt, jnp.int32(i), *placed)
                     note_loss(loss)
                     since += len(batch[2])
                     if ((i + 1) % log_every == 0
                             or i == tconfig.num_steps - 1):
                         with obs.interval("train/loss_fetch", step=i):
                             loss_now = fetch_loss(loss)
-                        logger.log(i + 1, samples=since, loss=loss_now)
+                            # What a slot-carrying step counted (its
+                            # batch's unique rows): device scalars of
+                            # the step just fenced, no second wait.
+                            counted = {k: int(v) for st in stats
+                                       for k, v in st.items()}
+                        logger.log(i + 1, samples=since, loss=loss_now,
+                                   **counted)
                         since = 0
                     maybe_eval(i + 1, lambda: to_canonical(params))
                     if checkpointer is not None and checkpointer.due(i + 1):

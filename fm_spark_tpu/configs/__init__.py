@@ -1,4 +1,4 @@
-"""The five benchmark run configs (BASELINE.json:7-11) as dataclasses.
+"""The benchmark run configs (BASELINE.json:7-11) as dataclasses.
 
 The reference has no config framework — hyperparameters are ``train()``
 arguments and cluster settings live in SparkConf (SURVEY.md §5 "Config /
@@ -16,6 +16,8 @@ Registry names map to the BASELINE table (SURVEY.md §6):
   features, field-partitioned tables (the bench.py headline layout) with
   the row-sharded strategy as the scale-out path.
 - ``avazu_ffm_r16``     — config 4: FFM rank-16, Avazu CTR.
+- ``avazu_ffm_r16_adagrad`` — config 4 under its paper's update rule:
+  per-coordinate AdaGrad on every table.
 - ``criteo1tb_deepfm``  — config 5 (stretch): DeepFM, FM + 3-layer MLP.
 """
 
@@ -56,6 +58,7 @@ class RunConfig:
     learning_rate: float = 0.1
     lr_schedule: str = "inv_sqrt"
     optimizer: str = "sgd"
+    adagrad_init_accumulator: float = 0.0   # TrainConfig's, same name
     reg_bias: float = 0.0
     reg_linear: float = 0.0
     reg_factors: float = 1e-6
@@ -179,6 +182,23 @@ CONFIGS = {
             model="field_ffm", dataset="avazu", rank=16, num_fields=23,
             bucket=1 << 14, strategy="field_sparse", num_steps=100_000,
             batch_size=8192, learning_rate=0.05, lr_schedule="constant",
+        ),
+        RunConfig(
+            name="avazu_ffm_r16_adagrad",
+            description="Config 4 as its paper trains it (Juan et al.,"
+            " RecSys 2016, Algorithm 1; libffm): avazu_ffm_r16's model"
+            " under per-coordinate AdaGrad on every table, eta 0.2,"
+            " lambda 2e-5, duplicates of a minibatch coalesced, G0 = 1"
+            " written against batch-mean gradients (1/8192^2). One chip:"
+            " the fused FieldFFM AdaGrad body, float32 accumulator tables"
+            " held beside the parameter tables. Measured at these defaults"
+            " (bucket raised to 2^17) by the benchmark's cell"
+            " ffm_r16_adagrad.train (PERF.md).",
+            model="field_ffm", dataset="avazu", rank=16, num_fields=23,
+            bucket=1 << 14, strategy="field_sparse", num_steps=100_000,
+            batch_size=8192, learning_rate=0.2, lr_schedule="constant",
+            optimizer="adagrad", adagrad_init_accumulator=2.0 ** -26,
+            reg_factors=2e-5,
         ),
         RunConfig(
             name="criteo1tb_deepfm",

@@ -99,6 +99,38 @@ def _dedup(ids: jax.Array, delta: jax.Array):
     return sid, summed[seg], run_start, order
 
 
+def coalesce(ids: jax.Array, delta: jax.Array):
+    """One id column's rows coalesced for a READ-MODIFY-WRITE rule (the
+    field bodies' per-coordinate optimizers, sparse.py): ``(useg [B],
+    totals [B, w] float32, n)``. ``useg[:n]`` are the column's unique
+    ids, ascending; ``totals[s]`` is the SUM of ``delta`` over the lanes
+    whose id is ``useg[s]``; past ``n`` the totals are zero and ``useg``
+    holds distinct ascending ids past any table's edge, so the whole
+    vector is sorted and unique (what ``indices_are_sorted`` /
+    ``unique_indices`` promise XLA) and a ``mode="drop"`` write leaves
+    those lanes out. :func:`_dedup` keeps every lane's total in its
+    sorted place, for the add and stochastic-round writes that mask
+    lanes; a set-semantics write of a rule's result wants each row once,
+    at the front."""
+    b = ids.shape[0]
+    order = jnp.argsort(ids)
+    sid = ids[order]
+    run_start = jnp.concatenate(
+        [jnp.ones((1,), bool), sid[1:] != sid[:-1]]
+    )
+    seg = (jnp.cumsum(run_start) - 1).astype(jnp.int32)
+    n = seg[-1] + 1
+    totals = jax.ops.segment_sum(
+        delta[order].astype(jnp.float32), seg, num_segments=b,
+        indices_are_sorted=True,
+    )
+    # Each run's first lane keeps its id, every other lane takes a
+    # sentinel of its own: sorted, that is the unique ids, then sentinels.
+    pos = jnp.arange(b, dtype=jnp.int32)
+    useg = jnp.sort(jnp.where(run_start, sid, (2**31 - 1 - b) + pos))
+    return useg, totals, n
+
+
 def dedup_aux(ids):
     """HOST-side dedup precompute for a ``[B, F]`` id batch.
 
@@ -350,6 +382,35 @@ def compact_gather(table, useg):
     which gathers at the small-operand fast rate (PERF.md fact 2)."""
     _check_sentinel_range(table.shape[0], useg.shape[-1])
     return table.at[useg].get(mode="clip", indices_are_sorted=True)
+
+
+# Lanes a read-modify-write rule takes at a time (sparse.py's AdaGrad
+# body walks :func:`coalesce`'s unique rows in chunks of this many, as
+# many chunks as hold them). Measured on the v5e (PERF.md §6, PR 34): a
+# gather or a set of n rows of a [131072, 384] table costs some 90 ns a
+# LANE, written or dropped, so a batch's ~570 unique rows a field cost an
+# eighth of its 8,192 lanes in one chunk of 1,024; a smaller chunk would
+# need two for the widest fields.
+RULE_CHUNK = 1024
+
+
+def rows_at(table, useg):
+    """The rows of ``table`` at :func:`coalesce`'s ids (sentinel lanes
+    clip to the last row; nobody reads them)."""
+    _check_sentinel_range(table.shape[0], useg.shape[-1])
+    return table.at[useg].get(mode="clip")
+
+
+def set_rows_at(table, useg, rows):
+    """``rows`` ([n, w], the model's width) SET at :func:`coalesce`'s
+    ids: each row written once, padded to the table's width, sentinel
+    lanes dropped. No ``unique_indices`` / ``indices_are_sorted``
+    promise, true as both are: on the v5e the promised scatter costs
+    0.6-0.7 ms a table whatever its lanes, the plain one 90 ns a lane
+    (PERF.md §6, PR 34)."""
+    _check_sentinel_range(table.shape[0], useg.shape[-1])
+    return table.at[useg].set(
+        _to_table_width(rows.astype(table.dtype), table), mode="drop")
 
 
 # Block size of the two-level prefix in compact_apply. Measured

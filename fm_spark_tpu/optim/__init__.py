@@ -18,6 +18,13 @@ update rules:
   any optax state. AdaGrad's dense form stays ``optax.adagrad`` (it
   predates this module).
 
+- **Field-table form** (``sparse.make_field_ffm_adagrad_body``): the
+  fused FieldFFM body under :func:`adagrad_rows`, as FFM's own paper
+  trains it (Juan et al., RecSys 2016, Algorithm 1), on per-field
+  tables of deployment size with slots from :func:`init_field_slots`.
+  The FieldFM and FieldDeepFM bodies and the mesh steps still write
+  their tables by plain SGD and refuse another rule by name.
+
 - **Sparse row form** (:func:`make_sparse_adaptive_step`): the fused
   flat-FM analog of ``sparse.make_sparse_sgd_step``, riding the SAME
   dedup/scatter machinery (:func:`fm_spark_tpu.ops.scatter._dedup`'s
@@ -59,6 +66,7 @@ __all__ = [
     "ftrl_init_z",
     "ftrl_rows",
     "init_adaptive_slots",
+    "init_field_slots",
     "make_sparse_adaptive_step",
 ]
 
@@ -223,6 +231,32 @@ def init_adaptive_slots(optimizer: str, spec, params) -> dict:
     return slots
 
 
+def init_field_slots(optimizer: str, params, keys,
+                     init_accumulator: float = 0.0) -> dict:
+    """Slot pytree for the fused FIELD bodies (``sparse
+    .make_field_ffm_adagrad_body``): ``{key: {"n": [tables]}}`` for
+    every parameter key in ``keys`` (per-field table lists), one float32
+    accumulator table per parameter table and of its shape, filled with
+    ``init_accumulator`` (AdaGrad's ``G0``; the flat path's
+    :func:`init_adaptive_slots` keeps 0). The bias has no slot, as
+    there. The same tree is the step's ``opt_state`` and what a
+    checkpoint stores beside the tables.
+
+    One table at a time, each waited for before the next is queued, as
+    ``FieldFFMSpec.init`` makes its tables: buffers are allocated as
+    work is queued, so a queue that runs ahead of the device leaves the
+    tables in another place every run, and where they lie sets a run's
+    speed (PERF.md §6, PR 33)."""
+    if optimizer != "adagrad":
+        raise ValueError(
+            f"field-table slots exist for 'adagrad', not {optimizer!r}")
+
+    return {key: {"n": [
+        jax.block_until_ready(
+            jnp.full(t.shape, init_accumulator, jnp.float32))
+        for t in params[key]]} for key in keys}
+
+
 def seed_ftrl_slots(slots: dict, params, alpha: float,
                     beta: float) -> dict:
     """Re-seed FTRL ``z`` slots from the CURRENT param tables (fresh
@@ -264,7 +298,9 @@ def make_sparse_adaptive_step(spec, config, *, beta: float = 1.0,
     if type(spec) is not FMSpec:
         raise ValueError(
             "the sparse adaptive step supports the flat FM family only "
-            "(the fused field families keep their SGD scatter bodies)")
+            "(of the fused field families FieldFFM takes 'adagrad', "
+            "sparse.make_field_ffm_adagrad_body; the others write by "
+            "plain SGD)")
     if config.optimizer not in ADAPTIVE_OPTIMIZERS:
         raise ValueError(
             f"make_sparse_adaptive_step handles {ADAPTIVE_OPTIMIZERS}; "

@@ -201,7 +201,10 @@ def _make_ffm_local_step(spec, config: TrainConfig, mesh):
     if type(spec) is not FieldFFMSpec:
         raise ValueError("expected a FieldFFMSpec")
     if config.optimizer != "sgd":
-        raise ValueError("sparse step implements plain SGD only")
+        from fm_spark_tpu.sparse import _SGD_ONLY
+
+        raise ValueError(_SGD_ONLY.format(what="the field-sharded FieldFFM step",
+                                          got=config.optimizer))
     from fm_spark_tpu.sparse import _reject_gfull
 
     _reject_gfull(config, "the field-sharded FFM step")

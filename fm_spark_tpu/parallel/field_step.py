@@ -506,7 +506,10 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
     if not spec.fused_linear:
         raise ValueError("field-sharded step requires fused_linear=True")
     if config.optimizer != "sgd":
-        raise ValueError("sparse step implements plain SGD only")
+        from fm_spark_tpu.sparse import _SGD_ONLY
+
+        raise ValueError(_SGD_ONLY.format(what="the field-sharded FieldFM step",
+                                          got=config.optimizer))
     from fm_spark_tpu.sparse import (
         _apply_field_updates,
         _check_host_dedup,

@@ -1,10 +1,13 @@
-"""Fused sparse-SGD train step: scatter-add updates, no dense gradient.
+"""Fused sparse train steps: row updates in place, no dense gradient.
 
 Why this exists (SURVEY.md §6 feasibility math): at Criteo scale the FM
 table is 10M × 64 (2.6 GB fp32). The generic ``jax.grad`` + optax path
 materializes a *dense* gradient table every step — ~8 GB of HBM traffic for
 a parameter update that only touches ``batch × nnz ≤ 5M`` rows. For plain
-SGD (the reference's optimizer) the update is a pure scatter-add, so this
+SGD (the reference's optimizer, and every body's here but one) the update
+is a pure scatter-add; the FieldFFM body also takes per-coordinate AdaGrad
+(:func:`make_field_ffm_adagrad_body`: coalesce, read, rule, set — each
+unique row and its accumulator row once). Either way the
 step computes the analytic per-row gradients — exactly the reference's
 ``computeGradient`` rule, ``x_i(s_f − v_{i,f}x_i)`` per BASELINE.json:5 —
 and applies them in place with ``.at[ids].add``:
@@ -42,6 +45,15 @@ def _lr_at(config: TrainConfig):
     if config.lr_schedule == "constant":
         return lambda i: jnp.float32(config.learning_rate)
     raise ValueError(f"unknown lr_schedule {config.lr_schedule!r}")
+
+
+# What a fused body that writes its tables by plain SGD says of any other
+# table rule (one wording for the FieldFM body and the mesh steps).
+_SGD_ONLY = (
+    "{what} writes its tables by plain SGD only (optimizer='sgd', not "
+    "{got!r}); per-coordinate 'adagrad' on the tables is implemented by "
+    "the one-chip FieldFFM body (sparse.make_field_ffm_adagrad_body) and, "
+    "with 'ftrl', by the flat-table optim.make_sparse_adaptive_step")
 
 
 def _sr_base_key(config: TrainConfig):
@@ -641,7 +653,8 @@ def make_field_sparse_sgd_body(spec, config: TrainConfig):
     if type(spec) is not FieldFMSpec:
         raise ValueError("expected a FieldFMSpec")
     if config.optimizer != "sgd":
-        raise ValueError("sparse step implements plain SGD only")
+        raise ValueError(_SGD_ONLY.format(what="the FieldFM body",
+                                          got=config.optimizer))
     if config.sparse_update != "scatter_add" and not spec.fused_linear:
         raise ValueError("dedup/dedup_sr modes require fused_linear=True")
     if config.use_pallas and not spec.fused_linear:
@@ -835,6 +848,12 @@ def make_field_sparse_multistep(spec, config: TrainConfig, n: int):
 
     if n < 1:
         raise ValueError(f"steps per call must be >= 1, got {n}")
+    if config.optimizer != "sgd":
+        raise ValueError(
+            f"the multistep roll carries no optimizer state and takes "
+            f"optimizer='sgd', not {config.optimizer!r} ('adagrad' on the "
+            "FieldFFM tables runs one step a call: "
+            "make_field_ffm_adagrad_step)")
     body = (
         make_field_ffm_sparse_sgd_body(spec, config)
         if isinstance(spec, FieldFFMSpec)
@@ -862,8 +881,13 @@ def make_field_sparse_multistep(spec, config: TrainConfig, n: int):
     return mstep
 
 
-def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
-    """Unjitted fused sparse-SGD body for :class:`FieldFFMSpec`.
+def _field_ffm_grads(spec, config: TrainConfig):
+    """The FieldFFM bodies' shared forward and analytic backward, up to
+    the per-lane row gradients: ``grads(params, step_idx, ids, vals,
+    labels, weights, aux) -> (loss, dscores, lr, g_fulls, rows, urows,
+    aux, ovf)``. What is done with ``g_fulls`` (F x [B, F·k+1], the L2
+    term of every occurrence inside) is the caller's: the SGD body
+    scatters ``-lr·g``, the AdaGrad body coalesces it per unique row.
 
     Analytic backward of the field-aware interaction (the reference's
     field-aware `computeGradient` analog, BASELINE.json:10): with
@@ -873,15 +897,13 @@ def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
         ∂L/∂sel[b,i,j] = dscore_b · sel[b,j,i]   (i ≠ j; diagonal 0)
         ∂L/∂v[id_i, field j] = ∂L/∂sel[b,i,j] · x_i
 
-    — one [B, F, F, k] transpose, then one scatter per field, same
+    — one [B, F, F, k] transpose, then one row update per field, same
     index-op count as the FieldFM step.
     """
     from fm_spark_tpu.models.field_ffm import FieldFFMSpec
 
     if type(spec) is not FieldFFMSpec:
         raise ValueError("expected a FieldFFMSpec")
-    if config.optimizer != "sgd":
-        raise ValueError("sparse step implements plain SGD only")
     _reject_gfull(config, "the FieldFFM body")
     _reject_embed_tier_require(config, "the single-chip FieldFFM body")
     _reject_collective_dtype(config, "the single-chip FieldFFM body")
@@ -895,11 +917,10 @@ def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
     per_example_loss = losses_lib.loss_fn(spec.loss)
     cd = spec.cdtype
     F, k = spec.num_fields, spec.rank
-    sr_base_key = _sr_base_key(config)
     lr_at = _lr_at(config)
     gat = _gather_fn(config)
 
-    def step(params, step_idx, ids, vals, labels, weights, aux=None):
+    def grads(params, step_idx, ids, vals, labels, weights, aux):
         if config.host_dedup and aux is None:
             raise ValueError(
                 "host_dedup step needs the batch's dedup_aux operand"
@@ -1017,13 +1038,39 @@ def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
             else:
                 g_l = jnp.zeros_like(dscores)
             g_fulls.append(jnp.concatenate([g_v, g_l[:, None]], axis=1))
+        return loss, dscores, lr, g_fulls, rows, urows, aux, ovf
+
+    return grads
+
+
+def _new_bias(spec, config: TrainConfig, w0, dscores, lr):
+    """The bias after a step: plain SGD under every table rule (one
+    scalar needs no per-coordinate rate; optim/ has the contract)."""
+    if not spec.use_bias:
+        return w0
+    return w0 - lr * (jnp.sum(dscores) + config.reg_bias * w0)
+
+
+def make_field_ffm_sparse_sgd_body(spec, config: TrainConfig):
+    """Unjitted fused sparse-SGD body for :class:`FieldFFMSpec`: the
+    shared gradients (:func:`_field_ffm_grads`), then one scatter of
+    ``-lr·g`` per field."""
+    if config.optimizer != "sgd":
+        raise ValueError(_SGD_ONLY.format(what="the FieldFFM SGD body",
+                                          got=config.optimizer))
+    grads = _field_ffm_grads(spec, config)
+    compact = config.compact_cap > 0
+    sr_base_key = _sr_base_key(config)
+
+    def step(params, step_idx, ids, vals, labels, weights, aux=None):
+        loss, dscores, lr, g_fulls, rows, urows, aux, ovf = grads(
+            params, step_idx, ids, vals, labels, weights, aux)
         new_vw = _updates_for(
             compact, params["vw"], ids, g_fulls, rows, urows, config,
             sr_base_key, step_idx, lr, aux,
         )
-        out = {"w0": w0, "vw": new_vw}
-        if spec.use_bias:
-            out["w0"] = w0 - lr * (jnp.sum(dscores) + config.reg_bias * w0)
+        out = {"w0": _new_bias(spec, config, params["w0"], dscores, lr),
+               "vw": new_vw}
         return out, _fold_overflow(loss, ovf, config)
 
     return step
@@ -1034,6 +1081,128 @@ def make_field_ffm_sparse_sgd_step(spec, config: TrainConfig):
     return jax.jit(
         make_field_ffm_sparse_sgd_body(spec, config), donate_argnums=(0,)
     )
+
+
+def make_field_ffm_adagrad_body(spec, config: TrainConfig):
+    """Unjitted fused body for :class:`FieldFFMSpec` under
+    per-coordinate AdaGrad on every table, as Juan et al. (RecSys 2016,
+    Algorithm 1) and libffm train the model. Returns ``(body,
+    init_slots)``; ``body(params, slots, step_idx, ids, vals, labels,
+    weights) -> (params, slots, loss, stats)``.
+
+    The gradients are the SGD body's (:func:`_field_ffm_grads`). The
+    write is not: an adaptive rule reads and writes a row's state, so
+    per field the batch's rows are COALESCED first (``ops/scatter
+    .coalesce``: each unique row's TOTAL gradient, L2 of every
+    occurrence inside), the row and its accumulator row are gathered at
+    the unique ids, ``optim.adagrad_rows`` is applied (``G += g²``, then
+    the step over the UPDATED ``sqrt(G)``), and both are set back once:
+    ``scatter.RULE_CHUNK`` lanes at a time, as many chunks as hold the
+    field's unique rows (gather and scatter cost by the lane on the
+    chip, and a skewed batch has far fewer unique rows than lanes).
+    A coordinate whose total gradient is exactly zero (an FFM row's own
+    diagonal block, a lane of zero weight) keeps its bits, row and
+    accumulator. No table-shaped temporary exists. The bias keeps plain
+    SGD (:func:`_new_bias`).
+
+    ``slots`` is ``{"vw": {"n": [F tables]}}``: one float32 accumulator
+    table per parameter table, whatever ``param_dtype`` is, in the form
+    its table is held in (``models/rows.hold``; a lane-padded table's
+    slot is lane-padded, its padding zero like the table's).
+    ``init_slots(params)`` builds them at ``config
+    .adagrad_init_accumulator`` (``optim.init_field_slots``).
+    ``stats["unique_rows"]`` is what coalescing made of the batch: its
+    unique rows summed over the fields.
+
+    The update runs under four named scopes, ``opt/coalesce``,
+    ``opt/gather``, ``opt/rule`` and ``opt/write``, which the
+    benchmark's ``opt_update_ms`` reads from a device trace."""
+    from fm_spark_tpu import optim
+    from fm_spark_tpu.ops import scatter as scatter_lib
+
+    if config.optimizer != "adagrad":
+        raise ValueError(
+            f"the FieldFFM AdaGrad body takes optimizer='adagrad', not "
+            f"{config.optimizer!r}")
+    # Levers of the SGD write: each names how rows are ADDED (a rounded
+    # sum, a Pallas accumulate, an aux or a cap built for an add); the
+    # rule coalesces in the step and sets each row once.
+    refused = {
+        "sparse_update='dedup_sr'": config.sparse_update == "dedup_sr",
+        "use_pallas": config.use_pallas,
+        "host_dedup": config.host_dedup,
+        "compact_device": config.compact_device,
+        "compact_cap": config.compact_cap > 0,
+        "segtotal_pallas": config.segtotal_pallas,
+        "fused_embed": config.fused_embed != "off",
+    }
+    if any(refused.values()):
+        raise ValueError(
+            f"{sorted(k for k, v in refused.items() if v)} shape the SGD "
+            "bodies' ADDED row updates and are not taken under "
+            "optimizer='adagrad': that body coalesces in the step and "
+            "sets each unique row and its accumulator row once")
+    grads = _field_ffm_grads(spec, config)
+    width = spec.table_width
+
+    def init_slots(params):
+        return optim.init_field_slots(
+            config.optimizer, params, FUSED_TABLE_KEYS,
+            config.adagrad_init_accumulator)
+
+    def step(params, slots, step_idx, ids, vals, labels, weights,
+             aux=None):
+        loss, dscores, lr, g_fulls, _, _, _, _ = grads(
+            params, step_idx, ids, vals, labels, weights, aux)
+        batch = ids.shape[0]
+        chunk = (scatter_lib.RULE_CHUNK
+                 if batch % scatter_lib.RULE_CHUNK == 0 else batch)
+        new_vw, new_n, unique = [], [], jnp.int32(0)
+        for f, g_full in enumerate(g_fulls):
+            with jax.named_scope("opt/coalesce"):
+                useg, g_bar, n = scatter_lib.coalesce(ids[:, f], g_full)
+
+            def one_chunk(c, held, useg=useg, g_bar=g_bar):
+                table, slot = held
+                with jax.named_scope("opt/gather"):
+                    at = jax.lax.dynamic_slice(useg, (c * chunk,), (chunk,))
+                    g = jax.lax.dynamic_slice(
+                        g_bar, (c * chunk, 0), (chunk, width))
+                    rows_u = scatter_lib.rows_at(table, at)[:, :width]
+                    n_u = scatter_lib.rows_at(slot, at)[:, :width]
+                with jax.named_scope("opt/rule"):
+                    rows_u, n_u = optim.adagrad_rows(rows_u, n_u, g, lr)
+                with jax.named_scope("opt/write"):
+                    return (scatter_lib.set_rows_at(table, at, rows_u),
+                            scatter_lib.set_rows_at(slot, at, n_u))
+
+            # The unique rows lie at the front: as many chunks as hold
+            # them (one, at this traffic's skew), the rest never touched.
+            table, slot = jax.lax.fori_loop(
+                0, (n + chunk - 1) // chunk, one_chunk,
+                (params["vw"][f], slots["vw"]["n"][f]))
+            new_vw.append(table)
+            new_n.append(slot)
+            unique = unique + n
+        out = {"w0": _new_bias(spec, config, params["w0"], dscores, lr),
+               "vw": new_vw}
+        return out, {"vw": {"n": new_n}}, loss, {"unique_rows": unique}
+
+    return step, init_slots
+
+
+def make_field_ffm_adagrad_step(spec, config: TrainConfig):
+    """Jitted :func:`make_field_ffm_adagrad_body`, tables and slots
+    donated (both are updated in place); ``step.init_opt_state`` builds
+    the slots, as the DeepFM step's builds its Adam state."""
+    body, init_slots = make_field_ffm_adagrad_body(spec, config)
+    _step = jax.jit(body, donate_argnums=(0, 1))
+
+    def step(params, slots, step_idx, ids, vals, labels, weights):
+        return _step(params, slots, step_idx, ids, vals, labels, weights)
+
+    step.init_opt_state = init_slots
+    return step
 
 
 def make_field_deepfm_sparse_body(spec, config: TrainConfig):
@@ -1263,7 +1432,10 @@ def make_sparse_sgd_step(spec, config: TrainConfig):
     if type(spec) is not FMSpec:
         raise ValueError("sparse step supports the plain FM family only")
     if config.optimizer != "sgd":
-        raise ValueError("sparse step implements plain SGD only")
+        raise ValueError(
+            f"the flat-table sparse step implements plain SGD only "
+            f"(optimizer='sgd', not {config.optimizer!r}); 'adagrad' and "
+            "'ftrl' on flat tables are optim.make_sparse_adaptive_step's")
     _reject_gfull(config, "the flat-table FM step (it has no fused "
                   "g_full concat to eliminate)")
     _reject_collective_dtype(config, "the single-chip flat-table FM step")
@@ -1447,6 +1619,19 @@ def lower_field_sparse_step(spec, config: TrainConfig, batch_size: int,
         opt_abs = on_device(jax.eval_shape(init_opt, params_abs))
         step = functools.partial(jax.jit, donate_argnums=(0, 1))(body)
         return step.lower(params_abs, opt_abs, i32, *batch_abs, aux_abs)
+
+    if isinstance(spec, FieldFFMSpec) and config.optimizer == "adagrad":
+        if multi:
+            raise ValueError(
+                "the FieldFFM AdaGrad body runs one step a call; the "
+                "multistep roll implements plain SGD only")
+        body, init_slots = make_field_ffm_adagrad_body(spec, config)
+        slots_abs = rows_lib.hold(
+            on_device(jax.eval_shape(
+                init_slots, jax.eval_shape(spec.init, jax.random.key(0)))),
+            FUSED_TABLE_KEYS, writes=True)[0]
+        step = jax.jit(body, donate_argnums=(0, 1))
+        return step.lower(params_abs, slots_abs, i32, *batch_abs)
 
     if multi:
         mstep = make_field_sparse_multistep(spec, config, steps_per_call)

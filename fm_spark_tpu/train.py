@@ -43,6 +43,13 @@ class TrainConfig:
     optimizer: str = "sgd"                 # 'sgd' | 'adam' | 'adagrad' |
                                            # 'ftrl' (per-coordinate
                                            # FTRL-Proximal, optim/)
+    # AdaGrad's G0 on the tables of a fused field body (sparse
+    # .make_field_ffm_adagrad_body): what every accumulator starts at.
+    # Juan et al.'s Algorithm 1 starts at 1 against per-example
+    # gradients; against this program's batch-MEAN gradients that is
+    # 1/B² (configs: avazu_ffm_r16_adagrad). The flat and dense AdaGrad
+    # forms keep their own starts.
+    adagrad_init_accumulator: float = 0.0
     reg_bias: float = 0.0                  # regParam triple (r0, r1, r2)
     reg_linear: float = 0.0
     reg_factors: float = 0.0

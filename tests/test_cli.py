@@ -23,7 +23,15 @@ def test_registry_has_all_five_baseline_configs():
         "criteo1tb_fm_r64",
         "avazu_ffm_r16",
         "criteo1tb_deepfm",
+        # Config 4 under its paper's update rule (PR 34): the same model,
+        # per-coordinate AdaGrad on every table.
+        "avazu_ffm_r16_adagrad",
     }
+    adagrad = configs_lib.CONFIGS["avazu_ffm_r16_adagrad"]
+    sgd = configs_lib.CONFIGS["avazu_ffm_r16"]
+    assert adagrad.spec() == sgd.spec()
+    assert (adagrad.optimizer, adagrad.adagrad_init_accumulator) == (
+        "adagrad", 2.0 ** -26)
 
 
 @pytest.mark.parametrize("name", sorted(configs_lib.CONFIGS))
