@@ -15,6 +15,8 @@ architecture rationale stays checkable on any machine:
              constraint).
   cast       fact 5: dense streaming bandwidth (why per-step shadow
              recasts are off the table).
+  ladder     the scatter_add write by lanes: plain against coalesced
+             (ops/scatter.COALESCE_MAX_LANES; PERF.md §6, PR 35).
   all        run everything.
 
 Prints one JSON line per measurement: {"bench": ..., "config": ...,
@@ -939,6 +941,90 @@ def bench_gfull(args):
           order, rows, vals, ds, s)
 
 
+# (table rows, table lanes, delta width): configs 4, 5 and 3 as the
+# one-chip loop holds their tables.
+_LADDER_SHAPES = [(1 << 17, 384, 369), (1 << 18, 128, 17), (1 << 18, 128, 65)]
+_LADDER_LANES = (2048, 4096, 8192, 16384, 32768, 65536, 131072)
+
+
+def bench_ladder(args):
+    """The ``scatter_add`` write's batch-size ladder (PERF.md §6, PR 35;
+    what ``ops/scatter.COALESCE_MAX_LANES`` is read from): for one
+    field's write into ``f32[131072,384]`` (delta 369 wide: config 4)
+    and ``f32[262144,128]`` (17: config 5; 65: config 3), at 2,048 to
+    131,072 lanes of Zipf(1.5) ids as ``synthetic_ctr`` draws them, the
+    plain add, the coalesced add, and the coalesce alone; and 8,192
+    lanes of uniform ids, the coalesced add's worst case. One jitted
+    program a point: ``reps`` rounds over four donated tables, each
+    round with ids of its own, ms per table per round at the median of
+    three fenced calls."""
+    import statistics
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fm_spark_tpu.ops import scatter
+
+    tables_n, reps = 4, 16
+
+    def plain(t, i, d):
+        return t.at[i].add(scatter._to_table_width(d, t), mode="drop")
+
+    def coalesce_only(t, i, d):
+        useg, totals, n = scatter.coalesce(i, d)
+        # Everything the coalesce makes is read (nothing DCE'd), one
+        # lane written.
+        keep = (totals.sum(0) + useg.sum() + n)[None, :]
+        return t.at[jnp.zeros((1,), jnp.int32)].add(
+            scatter._to_table_width(keep, t))
+
+    variants = {"plain": plain, "coalesced": scatter.coalesced_add,
+                "coalesce_only": coalesce_only}
+
+    def point(rows, lanes_w, width, b, draw, variant):
+        rng = np.random.default_rng(b)
+        ids = jnp.asarray(draw(rng, (reps, tables_n, b), rows), jnp.int32)
+        base = jnp.asarray(rng.normal(size=(b + reps, width)) * 1e-3,
+                           jnp.float32)
+        fn = variants[variant]
+
+        def rounds(ts, ids, base):
+            def one(r, ts):
+                d = jax.lax.dynamic_slice(base, (r, 0), (b, width))
+                return tuple(fn(t, ids[r, k], d)
+                             for k, t in enumerate(ts))
+            return jax.lax.fori_loop(0, reps, one, ts)
+
+        f = jax.jit(rounds, donate_argnums=0)
+        ts = tuple(jnp.zeros((rows, lanes_w), jnp.float32)
+                   for _ in range(tables_n))
+        ts = f(ts, ids, base)
+        _fence(ts[-1])
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            ts = f(ts, ids, base)
+            _fence(ts[-1])
+            times.append(time.perf_counter() - t0)
+        unique = float(np.mean(
+            [len(np.unique(np.asarray(ids[0, k]))) for k in range(tables_n)]))
+        return statistics.median(times) / (reps * tables_n) * 1e3, unique
+
+    zipf = lambda rng, size, rows: rng.zipf(1.5, size=size) % rows
+    uniform = lambda rng, size, rows: rng.integers(0, rows, size=size)
+    ladder = [(s, b, "zipf", zipf) for s in _LADDER_SHAPES
+              for b in _LADDER_LANES]
+    ladder += [(s, 8192, "uniform", uniform) for s in _LADDER_SHAPES[:2]]
+    for (rows, lanes_w, width), b, name, draw in ladder:
+        for variant in variants:
+            ms, unique = point(rows, lanes_w, width, b, draw, variant)
+            _out(f"ladder_{variant}",
+                 {"table": [rows, lanes_w], "delta_width": width,
+                  "lanes": b, "ids": name, "unique_rows": round(unique)},
+                 ms, "ms/field")
+
+
 BENCHES = {
     "dispatch": bench_dispatch,
     "gather": bench_gather,
@@ -954,6 +1040,7 @@ BENCHES = {
     "scanmodel": bench_scanmodel,
     "transpose": bench_transpose,
     "gfull": bench_gfull,
+    "ladder": bench_ladder,
 }
 
 

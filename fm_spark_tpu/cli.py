@@ -850,6 +850,17 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
 
     placed = device_lib.placement(params)
     print(json.dumps({"placement": placed}), flush=True)
+    if (not slots and tconfig.sparse_update == "scatter_add"
+            and not tconfig.use_pallas):
+        # The SGD bodies' default write: a field's owner takes the whole
+        # batch's lanes (one chip, or a mesh after its all-to-all) and
+        # ops/scatter decides from them and the table's shape.
+        from fm_spark_tpu.ops import scatter as scatter_lib
+
+        obs.gauge("train/update_lanes_per_field").set(
+            scatter_lib.update_lanes(
+                tconfig.batch_size,
+                jax.tree.leaves(params["vw"])[0].shape[-2:]))
     obs.event("table_layout", table_layouts=placed["table_layouts"],
               table_device_bytes=placed["table_device_bytes"])
 

@@ -4,15 +4,32 @@ The FieldFM hot path updates ``B`` gathered rows per field per step
 (sparse.py). Three write strategies, selected by ``TrainConfig
 .sparse_update``:
 
-- ``"scatter_add"`` — plain ``.at[ids].add``; duplicates accumulate in
-  XLA's scatter. The measured default (PERF.md).
+- ``"scatter_add"`` — the batch's deltas ADDED to their rows, every
+  occurrence of a row counted. How many lanes go into the table at a
+  time is chosen from the shapes (:func:`update_lanes`): a large batch
+  adds its ``B`` lanes as they come (``.at[ids].add``; duplicates
+  accumulate in XLA's scatter), a small one COALESCES first
+  (:func:`coalesced_add`: each unique row's float32 sum, then as many
+  chunks of ``RULE_CHUNK`` lanes as hold the unique rows). The measured
+  default (PERF.md §6, PR 35). Why by the lanes: on the v5e a plain add
+  into a ``[131072, 384]`` or ``[262144, 128]`` float32 table costs
+  75-115 ns a LANE, written or dropped, up to 16,384 / 32,768 lanes,
+  and 18-41 ns a lane from the next rung of the ladder up (XLA lowers
+  the larger scatter another way, with a sort of its own); coalescing
+  costs 20-46 ns a lane of the batch and a chunk of 1,024 lanes 0.06-
+  0.09 ms. One field, Zipf(1.5) ids, ms plain / coalesced at 2,048,
+  4,096, 8,192, 16,384, 32,768, 65,536, 131,072 lanes: ``[131072,
+  384]`` 0.235 / 0.182, 0.449 / 0.233, 0.865 / 0.342, 1.747 / 0.590,
+  1.341 / 1.200, 2.168 / 2.381, 3.800 / 4.768; ``[262144, 128]`` 0.176
+  / 0.141, 0.328 / 0.181, 0.631 / 0.261, 1.233 / 0.411, 2.440 / 0.801,
+  1.171 / 1.580, 2.385 / 3.409.
 - ``"dedup"`` — in-batch segment-sum first: sort ids, sum duplicate rows'
   deltas with a fixed-shape ``segment_sum``, then ONE add per unique id
   (duplicate lanes write out-of-bounds and are dropped — XLA scatter
-  drop-semantics, the jnp ``mode="drop"``). Bitwise-same result as
-  scatter_add up to float reassociation; under Zipf-skewed CTR ids most
-  lanes become no-ops, which matters iff XLA's scatter cost tracks
-  *colliding* writes (measure on chip before defaulting).
+  drop-semantics, the jnp ``mode="drop"``). Same result as scatter_add
+  up to float reassociation, and never faster on the chip: it keeps all
+  ``B`` lanes and only masks them, and a dropped lane costs what a
+  written one does.
 - ``"dedup_sr"`` — dedup, then write back ``old + Σdelta`` with
   STOCHASTIC ROUNDING via set-semantics. This is the bf16-storage
   quality fix: plain bf16 scatter-add loses updates smaller than half an
@@ -21,10 +38,18 @@ The FieldFM hot path updates ``B`` gathered rows per field per step
   expectation. Requires dedup because ``set`` with duplicate ids would
   drop all but one lane's contribution.
 
+The compact levers (:func:`compact_aux`, :func:`_compact_write`) cut the
+lanes too, to a static cap, but PROMISE the scatter sorted and unique
+indices, and ``indices_are_sorted=True`` alone costs 0.6-0.7 ms a table
+whatever its lanes (PERF.md §6, PR 34): at these sizes they lose to the
+plain add they were built to beat.
+
 All three are fixed-shape and jit/shard_map-safe.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -384,13 +409,16 @@ def compact_gather(table, useg):
     return table.at[useg].get(mode="clip", indices_are_sorted=True)
 
 
-# Lanes a read-modify-write rule takes at a time (sparse.py's AdaGrad
-# body walks :func:`coalesce`'s unique rows in chunks of this many, as
-# many chunks as hold them). Measured on the v5e (PERF.md §6, PR 34): a
-# gather or a set of n rows of a [131072, 384] table costs some 90 ns a
-# LANE, written or dropped, so a batch's ~570 unique rows a field cost an
-# eighth of its 8,192 lanes in one chunk of 1,024; a smaller chunk would
-# need two for the widest fields.
+# Lanes a coalesced write takes at a time. Two users walk
+# :func:`coalesce`'s unique rows in chunks of this many, as many chunks
+# as hold them: sparse.py's AdaGrad body (a read-modify-write rule:
+# gather, rule, set) and :func:`coalesced_add` (the SGD bodies'
+# ``scatter_add`` write, where :func:`update_lanes` says so). Measured
+# on the v5e (PERF.md §6, PR 34): a gather or a set of n rows of a
+# [131072, 384] table costs some 90 ns a LANE, written or dropped, so a
+# batch's ~570 unique rows a field cost an eighth of its 8,192 lanes in
+# one chunk of 1,024; a smaller chunk would need two for the widest
+# fields.
 RULE_CHUNK = 1024
 
 
@@ -411,6 +439,86 @@ def set_rows_at(table, useg, rows):
     _check_sentinel_range(table.shape[0], useg.shape[-1])
     return table.at[useg].set(
         _to_table_width(rows.astype(table.dtype), table), mode="drop")
+
+
+# Most lanes a field's ``scatter_add`` write coalesces before it adds
+# (:func:`update_lanes`): the highest rung of the ladder at which the
+# coalesced add measured faster than the plain one, on both tables the
+# one-chip cells hold (the module's docstring has every rung; PERF.md
+# §6, PR 35). At 32,768 lanes it wins by 1.12x into [131072, 384] and
+# 3.0x into [262144, 128]; at 65,536 it loses by 1.10x and 1.35x: XLA's
+# own scatter is another, cheaper algorithm up there (18-41 ns a lane
+# against 75-115), and sorting and summing the whole batch first costs
+# more than it saves. Uniform ids, every lane a row of its own, are the
+# coalesced add's worst case: 8,192 of them cost it 1.19-1.20x the plain
+# add (all eight chunks written, the coalesce pure cost).
+COALESCE_MAX_LANES = 32768
+
+
+def update_lanes(lanes: int, table_shape) -> int:
+    """Lanes a field's ``scatter_add`` write puts into a table of
+    ``table_shape`` at a time, given ``lanes`` ids (both static):
+    ``RULE_CHUNK`` where :func:`apply_row_updates` coalesces first
+    (:func:`coalesced_add`), ``lanes`` where it adds them as they come.
+    The one statement of that choice: ``apply_row_updates`` asks it, and
+    the training loop reports its answer
+    (``train/update_lanes_per_field``). A batch of one chunk or less has
+    nothing to save, and one that is not whole chunks is not walked in
+    chunks (the AdaGrad body's conditions too)."""
+    # The ladder's two tables, [131072, 384] and [262144, 128], cross
+    # between the same two rungs, so the shape decides nothing yet; it
+    # stays in the question, which a table that crosses elsewhere
+    # changes here and not in the callers.
+    del table_shape
+    coalesces = (RULE_CHUNK < lanes <= COALESCE_MAX_LANES
+                 and lanes % RULE_CHUNK == 0)
+    return RULE_CHUNK if coalesces else lanes
+
+
+def coalesced_add(table, ids, delta):
+    """``table.at[ids].add(delta, mode="drop")`` with each row added
+    ONCE: :func:`coalesce` sums the lanes of every unique id in float32
+    (the same terms as the lane-by-lane add, reassociated; a table in
+    fewer bits takes one rounded sum a row, not a rounded term an
+    occurrence), and the sums are added ``RULE_CHUNK`` lanes at a time,
+    as many chunks as hold the unique ids, the table updated in place.
+    ``ids.shape[0]`` is a multiple of ``RULE_CHUNK``.
+
+    An id is a value to this function, in range or not: lanes of one
+    value are summed and added AT that value, so an out-of-range id (the
+    2-D mesh's drop sentinel) is dropped by the same ``mode="drop"`` as
+    in the plain add, and a negative one wraps as it does there. They
+    need not avoid ``coalesce``'s own sentinels: those lie past the
+    table's edge too (``_check_sentinel_range``), nothing is promised
+    unique or sorted, and two dropped lanes at one index are two dropped
+    lanes."""
+    _check_sentinel_range(table.shape[0], ids.shape[0])
+    if ids.shape[0] % RULE_CHUNK:
+        raise ValueError(
+            f"coalesced_add walks whole chunks of {RULE_CHUNK} lanes, "
+            f"not {ids.shape[0]}")
+    return _coalesced_add(table, ids, delta, RULE_CHUNK)
+
+
+# A step writes F tables of one shape: under an inner jit the write is
+# traced once a shape, not once a field (0.5-0.8 s of every trace of the
+# FFM and DeepFM steps otherwise, and a run traces its step two or three
+# times).
+@functools.partial(jax.jit, static_argnames="chunk")
+def _coalesced_add(table, ids, delta, chunk):
+    with jax.named_scope("sgd/coalesce"):
+        useg, totals, n = coalesce(ids, delta)
+
+    def one_chunk(c, table):
+        at = jax.lax.dynamic_slice(useg, (c * chunk,), (chunk,))
+        rows = jax.lax.dynamic_slice(
+            totals, (c * chunk, 0), (chunk, totals.shape[1]))
+        return table.at[at].add(
+            _to_table_width(rows.astype(table.dtype), table), mode="drop")
+
+    with jax.named_scope("sgd/write"):
+        return jax.lax.fori_loop(0, (n + chunk - 1) // chunk, one_chunk,
+                                 table)
 
 
 # Block size of the two-level prefix in compact_apply. Measured
@@ -587,6 +695,17 @@ def apply_row_updates(
     """Apply per-row ``delta`` ([B, w] in compute dtype) to ``table``
     ([n, w] in storage dtype) at ``ids`` ([B]).
 
+    ``scatter_add`` (the default) adds every lane's delta to its row;
+    out-of-range ids are dropped. Whether the ``B`` lanes go in as they
+    come or coalesced, ``RULE_CHUNK`` lanes at a time, is
+    :func:`update_lanes`' answer for ``B`` and the table's shape, not
+    the caller's: the result is the same to float32 reassociation (each
+    unique row receives the float32 SUM of its occurrences in one add,
+    where the plain add gives them one by one), and the module's
+    docstring has what each costs on the chip. ``dedup`` masks its
+    ``B`` lanes and ``_compact_write`` promises its scatter sorted
+    indices: neither is ever the faster there.
+
     ``old_rows`` ([B, w], compute dtype) are the previously gathered rows
     — required for ``dedup_sr`` (the new value is formed in fp32 from
     them, so no second gather is paid). ``key`` seeds SR.
@@ -613,6 +732,8 @@ def apply_row_updates(
     if use_pallas and mode in ("scatter_add", "dedup"):
         return _pallas_dedup_add(table, ids, _to_table_width(delta, table))
     if mode == "scatter_add":
+        if update_lanes(ids.shape[0], table.shape) != ids.shape[0]:
+            return coalesced_add(table, ids, delta)
         # mode="drop" is XLA's default scatter OOB semantics, made
         # explicit: the 2-D field-sharded step routes non-owned lanes to
         # an out-of-bounds sentinel index that MUST be dropped.

@@ -175,10 +175,23 @@ def test_requires_feat_mesh(eight_devices):
     (2, 4, 5, "scatter_add"),   # uneven fields + deep row split
     (1, 8, 3, "scatter_add"),   # PURE row sharding (capacity only)
     (4, 2, 6, "dedup"),         # dedup's drop-lane path + sentinel rows
+    # scatter_add with the coalesced write forced on (ops/scatter
+    # .update_lanes at small constants): non-owned lanes' sentinel rows
+    # sort behind the owned ones and are dropped chunk by chunk.
+    (4, 2, 6, "coalesced"),
+    (2, 4, 5, "coalesced"),
 ])
-def test_field_sharded_2d_matches_single_chip(eight_devices, n_feat, n_row,
-                                              num_fields, mode):
+def test_field_sharded_2d_matches_single_chip(eight_devices, monkeypatch,
+                                              n_feat, n_row, num_fields,
+                                              mode):
     bucket, rank, b = 32, 4, 64
+    oracle_mode = "scatter_add"
+    if mode == "coalesced":
+        from fm_spark_tpu.ops import scatter
+
+        monkeypatch.setattr(scatter, "RULE_CHUNK", 16)
+        monkeypatch.setattr(scatter, "COALESCE_MAX_LANES", b)
+        mode, oracle_mode = "scatter_add", "dedup"   # an oracle that masks
     spec = models.FieldFMSpec(
         num_features=num_fields * bucket, rank=rank,
         num_fields=num_fields, bucket=bucket, init_std=0.1,
@@ -201,8 +214,13 @@ def test_field_sharded_2d_matches_single_chip(eight_devices, n_feat, n_row,
     # dedup ≡ scatter_add up to reassociation, so one single-chip oracle
     # serves both parametrizations.
     step_single = make_field_sparse_sgd_step(
-        spec, dataclasses.replace(config, sparse_update="scatter_add")
+        spec, dataclasses.replace(config, sparse_update=oracle_mode)
     )
+    if oracle_mode == "dedup":
+        from fm_spark_tpu.parallel import lower_field_sharded_step
+
+        assert "sgd/write" in lower_field_sharded_step(
+            spec, config, mesh, b).as_text(debug_info=True)
 
     rng = np.random.default_rng(0)
     for i in range(3):

@@ -1,11 +1,16 @@
 """ops/scatter.py: dedup ≡ scatter_add, SR unbiasedness, bf16+SR quality."""
 
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fm_spark_tpu import models
+from fm_spark_tpu import models, sparse
+from fm_spark_tpu.ops import scatter
 from fm_spark_tpu.ops.scatter import apply_row_updates, stochastic_round
 from fm_spark_tpu.sparse import make_field_sparse_sgd_step
 from fm_spark_tpu.train import TrainConfig
@@ -204,3 +209,189 @@ def test_compact_apply_totals_matches_compact_apply_write():
     a = compact_apply(table, delta, caux, "dedup_sr", key, urows)
     t = compact_apply_totals(table, totals, caux, "dedup_sr", key, urows)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(t))
+
+
+# --------------------------------------------- the coalesced scatter_add
+#
+# apply_row_updates' default write coalesces a field's rows before it
+# adds them, where ops/scatter.update_lanes says the lanes pay for it.
+
+
+def _coalesced_case(name):
+    """``(table, ids, delta)`` of 64 lanes; with RULE_CHUNK at 16 that is
+    four chunks at most."""
+    rng = np.random.default_rng(5)
+    n_rows, w, b = 200, 8, 64
+    table = rng.normal(size=(n_rows, w)).astype(np.float32)
+    delta = (rng.normal(size=(b, w)) * 0.1).astype(np.float32)
+    ids = rng.integers(0, 6, size=b)                    # heavy duplicates
+    if name == "all_unique":
+        ids = rng.permutation(n_rows)[:b]               # four whole chunks
+    elif name == "drop_sentinels":
+        # The 2-D mesh's: non-owned lanes at the table's edge (and one
+        # far past it), among real duplicates.
+        ids = np.where(rng.random(b) < 0.4, n_rows, ids)
+        ids[7] = 2**31 - 1 - b                          # coalesce's first
+    elif name == "lane_padded":
+        table = np.pad(table, ((0, 0), (0, 120)))       # 8 columns in 128
+    elif name == "bfloat16":
+        table = np.asarray(jnp.asarray(table, jnp.bfloat16))
+    return jnp.asarray(table), jnp.asarray(ids, jnp.int32), jnp.asarray(delta)
+
+
+@pytest.mark.parametrize("name", ["duplicates", "all_unique",
+                                  "drop_sentinels", "lane_padded",
+                                  "bfloat16"])
+def test_coalesced_add_is_the_plain_add(monkeypatch, name):
+    monkeypatch.setattr(scatter, "RULE_CHUNK", 16)
+    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES", 64)
+    table, ids, delta = _coalesced_case(name)
+    assert scatter.update_lanes(ids.shape[0], table.shape) == 16
+    got = np.asarray(
+        apply_row_updates(table, ids, delta, mode="scatter_add"),
+        np.float32)
+    padded = jnp.pad(delta, ((0, 0), (0, table.shape[1] - delta.shape[1])))
+    plain = np.asarray(
+        table.at[ids].add(padded.astype(table.dtype), mode="drop"),
+        np.float32)
+    if name == "bfloat16":
+        # Rounded once a row where the plain add rounds once an
+        # occurrence: at least as near the float32 answer.
+        exact = np.asarray(
+            table.astype(jnp.float32).at[ids].add(delta, mode="drop"))
+        assert np.abs(got - exact).sum() <= np.abs(plain - exact).sum()
+        np.testing.assert_allclose(got, exact, rtol=2**-7, atol=1e-3)
+    else:
+        np.testing.assert_allclose(got, plain, rtol=1e-5, atol=1e-6)
+    if name == "lane_padded":
+        assert not got[:, delta.shape[1]:].any()    # padding: exactly zero
+    untouched = np.setdiff1d(np.arange(table.shape[0]), np.asarray(ids))
+    np.testing.assert_array_equal(
+        got[untouched], np.asarray(table, np.float32)[untouched])
+
+
+@pytest.mark.parametrize("lanes,want", [
+    (1024, 1024),         # one chunk: nothing to save
+    (2048, 1024), (8192, 1024), (16384, 1024), (32768, 1024),
+    (8192 + 512, 8192 + 512),       # not whole chunks
+    (65536, 65536), (131072, 131072), (524288, 524288),
+])
+def test_update_lanes_by_the_real_constants(lanes, want):
+    assert scatter.update_lanes(lanes, (1 << 17, 384)) == want
+    assert scatter.update_lanes(lanes, (1 << 18, 128)) == want
+
+
+def _lowered(family, batch, **config):
+    common = dict(num_features=4 * 4096, num_fields=4, bucket=4096)
+    spec = (models.FieldFMSpec(rank=64, **common) if family == "fm"
+            else models.FieldFFMSpec(rank=16, **common))
+    tconfig = TrainConfig(learning_rate=0.05, lr_schedule="constant",
+                          reg_factors=1e-6, **config)
+    return sparse.lower_field_sparse_step(spec, tconfig, batch).as_text(
+        debug_info=True)
+
+
+def _table_scatter_lanes(text):
+    """Lane counts of every scatter into a ``[4096, w]`` table."""
+    return sorted({int(m) for m in re.findall(
+        r"\(tensor<4096x\d+xf32>, tensor<(\d+)x1xi32>, "
+        r"tensor<\d+x\d+xf32>\) -> tensor<4096x", text)})
+
+
+def test_the_write_coalesces_by_shape_alone():
+    """The real constants, lowered on the CPU: config 4's lanes coalesce,
+    config 3's do not, and the AdaGrad body keeps its own write."""
+    ffm = _lowered("ffm", 8192, optimizer="sgd")
+    assert "sgd/coalesce" in ffm and "sgd/write" in ffm
+    assert _table_scatter_lanes(ffm) == [1024]
+    fm = _lowered("fm", 131072, optimizer="sgd")
+    assert "sgd/coalesce" not in fm and "sgd/write" not in fm
+    assert _table_scatter_lanes(fm) == [131072]
+    adagrad = _lowered("ffm", 8192, optimizer="adagrad")
+    assert "sgd/coalesce" not in adagrad and "sgd/write" not in adagrad
+    for scope in ("opt/coalesce", "opt/gather", "opt/rule", "opt/write"):
+        assert scope in adagrad
+    assert _table_scatter_lanes(adagrad) == [1024]
+
+
+def test_coalescing_ffm_steps_match_the_plain_reference(monkeypatch):
+    """Three fused FFM SGD steps on one batch, the coalesced write forced
+    on at a tiny size, against ``benchmark/reference/sgd.py`` under the
+    limits ``ffm_r16.train`` holds its check run to."""
+    from benchmark.reference import ffm, sgd
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/traffic/train_fed.json")) as fh:
+        mix = json.load(fh)
+    monkeypatch.setattr(scatter, "RULE_CHUNK", 16)
+    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES", 128)
+    fields, rank, bucket, batch, steps = 5, 4, 64, 128, 3
+    spec = models.FieldFFMSpec(num_features=fields * bucket, rank=rank,
+                               num_fields=fields, bucket=bucket)
+    config = TrainConfig(learning_rate=0.05, lr_schedule="constant",
+                         optimizer="sgd", reg_factors=1e-4, reg_linear=1e-4)
+    assert "sgd/write" in sparse.lower_field_sparse_step(
+        spec, config, batch).as_text(debug_info=True)
+    rng = np.random.default_rng(9)
+    ids = np.where(rng.random((batch, fields)) < 0.7,
+                   rng.integers(0, 8, (batch, fields)),
+                   rng.integers(0, bucket, (batch, fields))).astype(np.int32)
+    vals = rng.uniform(0.5, 1.5, (batch, fields)).astype(np.float32)
+    labels = rng.integers(0, 2, batch).astype(np.float32)
+    params = spec.init(jax.random.key(3))
+    uniq, counts, inv, _ = sgd.touched(ids)
+    rows0 = np.stack([np.asarray(params["vw"][f])[uniq[f]]
+                      for f in range(fields)])
+    factor_cols = ffm.factor_columns(fields, rank)
+    want_losses, want_rows, want_w0 = sgd.train(
+        ffm.scores, rank, factor_cols, rows0, inv, vals, labels, steps=steps,
+        learning_rate=0.05, lr_schedule="constant", reg_factors=1e-4,
+        reg_linear=1e-4, reg_bias=config.reg_bias, chunk=batch)
+    step = sparse.make_field_ffm_sparse_sgd_step(spec, config)
+    losses = []
+    for i in range(steps):
+        params, loss = step(params, jnp.int32(i), ids, vals, labels,
+                            np.ones((batch,), np.float32))
+        losses.append(float(loss))
+    np.testing.assert_allclose(losses, want_losses, rtol=mix["loss_rtol"])
+    got_rows = np.stack([np.asarray(params["vw"][f])[uniq[f]]
+                         for f in range(fields)])
+    live = counts > 0
+    err, delta = np.abs(got_rows - want_rows), np.abs(want_rows - rows0)
+    ulp = np.spacing(np.maximum(np.abs(want_rows), np.abs(rows0)))
+    for cols in (slice(0, factor_cols), slice(factor_cols, None)):
+        allowed = (mix["rows_rtol"] * delta[..., cols][live].max()
+                   + steps * counts[..., None] * ulp[..., cols])
+        assert (err[..., cols] <= allowed)[live].all()
+    assert float(params["w0"]) == pytest.approx(want_w0,
+                                                rel=mix["loss_rtol"])
+
+
+@pytest.mark.parametrize("devices,batch,lanes", [
+    (1, 2048, 1024), (1, 256, 256), (8, 2048, 1024)])
+def test_the_loop_reports_the_lanes_its_write_takes(monkeypatch, capsys,
+                                                    devices, batch, lanes):
+    """``train/update_lanes_per_field`` after ``cli train``, on one chip
+    and on the tests' eight-device mesh (whose fields' owners each take
+    the whole batch's lanes and coalesce them by the same rule)."""
+    import dataclasses
+
+    from fm_spark_tpu import cli, obs
+    from fm_spark_tpu import configs as configs_lib
+
+    small = dataclasses.replace(
+        configs_lib.CONFIGS["avazu_ffm_r16"], name="ffm_lanes_tiny",
+        bucket=64, num_fields=5, rank=4)
+    monkeypatch.setitem(configs_lib.CONFIGS, small.name, small)
+    if devices == 1:
+        monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    obs.gauge("train/update_lanes_per_field").set(-1)
+    assert cli.main(["train", "--config", small.name, "--synthetic",
+                     str(2 * batch), "--steps", "2", "--batch-size",
+                     str(batch), "--test-fraction", "0",
+                     "--log-every", "1"]) == 0
+    losses = [json.loads(line)["loss"]
+              for line in capsys.readouterr().out.splitlines()
+              if line.startswith("{") and '"loss"' in line]
+    assert len(losses) == 2 and all(np.isfinite(losses))
+    assert obs.gauge("train/update_lanes_per_field").value == lanes

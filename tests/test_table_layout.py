@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from fm_spark_tpu import cli, models, sparse
 from fm_spark_tpu.models import rows
 from fm_spark_tpu.models.rows import PackedTable
-from fm_spark_tpu.ops.scatter import dedup_aux
+from fm_spark_tpu.ops.scatter import dedup_aux, update_lanes
 from fm_spark_tpu.train import TrainConfig
 from fm_spark_tpu.utils import device as device_lib
 
@@ -43,6 +43,11 @@ def _spec(family, **over):
         return models.FieldFMSpec(rank=64, **common), 1024
     if family == "ffm":
         return models.FieldFFMSpec(rank=16, **common), 256
+    if family == "ffm_coalescing":
+        # ffm_r16.train's lanes: the write coalesces (ops/scatter
+        # .update_lanes) and walks its chunks in a loop that carries
+        # the table.
+        return models.FieldFFMSpec(rank=16, **common), 8192
     return models.FieldDeepFMSpec(rank=16, mlp_dims=(32, 16), **common), 256
 
 
@@ -103,9 +108,11 @@ def _table_copies(compiled, spec, widths):
 
 
 @pytest.mark.parametrize("steps_per_call", [1, 4])
-@pytest.mark.parametrize("family", ["fm", "ffm"])
+@pytest.mark.parametrize("family", ["fm", "ffm", "ffm_coalescing"])
 def test_compiled_step_copies_no_table(one_chip, family, steps_per_call):
     spec, batch = _spec(family)
+    if family == "ffm_coalescing":
+        assert update_lanes(batch, (BUCKET, 128)) < batch
     lowered = sparse.lower_field_sparse_step(
         spec, CONFIG, batch, steps_per_call, device=one_chip)
     compiled = lowered.compile()
@@ -122,6 +129,8 @@ def test_compiled_step_copies_no_table(one_chip, family, steps_per_call):
     table_bytes = FIELDS * BUCKET * spec.table_width * 4
     assert compiled.memory_analysis().alias_size_in_bytes >= table_bytes
 
+    if family == "ffm_coalescing":
+        return      # the canary below is the two plain families'
     # The same step on tables as spec.init shapes them: if this stops
     # copying, the TPU's default layout changed and the padding (and
     # the assertions above) prove nothing.
@@ -133,7 +142,7 @@ def test_compiled_step_copies_no_table(one_chip, family, steps_per_call):
     step = (sparse.make_field_sparse_multistep(spec, CONFIG, steps_per_call)
             if steps_per_call > 1
             else sparse.make_field_ffm_sparse_sgd_step(spec, CONFIG)
-            if family == "ffm"
+            if family.startswith("ffm")
             else sparse.make_field_sparse_sgd_step(spec, CONFIG))
     bare = step.lower(*args).compile()
     assert bare.input_formats[0][0]["vw"][0].layout.major_to_minor == (1, 0)
