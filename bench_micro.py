@@ -15,8 +15,9 @@ architecture rationale stays checkable on any machine:
              constraint).
   cast       fact 5: dense streaming bandwidth (why per-step shadow
              recasts are off the table).
-  ladder     the scatter_add write by lanes: plain against coalesced
-             (ops/scatter.COALESCE_MAX_LANES; PERF.md §6, PR 35).
+  ladder     the scatter_add write by lanes and table: plain against
+             coalesced (ops/scatter.update_lanes' constants; PERF.md §6,
+             PR 35 and PR 37).
   all        run everything.
 
 Prints one JSON line per measurement: {"bench": ..., "config": ...,
@@ -945,19 +946,44 @@ def bench_gfull(args):
 # one-chip loop holds their tables.
 _LADDER_SHAPES = [(1 << 17, 384, 369), (1 << 18, 128, 17), (1 << 18, 128, 65)]
 _LADDER_LANES = (2048, 4096, 8192, 16384, 32768, 65536, 131072)
+# PR 37's points, (table, lanes), around where XLA changes its lowering
+# of the plain add: compiled for a v5e it sorts the update first from
+# one lane over an eighth of the table's ROWS.
+_DLRM = (1 << 19, 128, 128)         # rows that ARE lane tiles
+_MESH = (1 << 18, 65, 65)           # config 3's as a mesh holds them:
+#                                     nothing padded on, bucket-minor
+_BIG = (1 << 20, 128, 65)           # config 3's batch, four times the rows
+_LADDER_POINTS_37 = (
+    # DLRM's batch is 55,296 lanes; its table's eighth is 65,536.
+    [(_DLRM, b) for b in (32768, 49152, 55296, 61440, 65536, 66560, 131072)]
+    + [(s, b) for s in _LADDER_SHAPES for b in (49152, 55296, 61440)]
+    # What tells rows from the update's elements: 7.86M and 8.65M of
+    # them, both over this table's eighth (16,384 lanes).
+    + [(_LADDER_SHAPES[0], b) for b in (20480, 22528)]
+    + [(_MESH, b) for b in (32768, 65536, 131072)]
+    + [(_BIG, b) for b in (131072, 132096)])    # its eighth, a chunk over
+# ``plain_padded``: one chunk over an eighth of DLRM's rows, the fewest
+# whole chunks at which XLA sorts the plain add (65,536, the eighth
+# itself, read 4.856 ms: dear, as 55,296 unpadded).
+_LADDER_PADDED_LANES = 66560
 
 
 def bench_ladder(args):
-    """The ``scatter_add`` write's batch-size ladder (PERF.md §6, PR 35;
-    what ``ops/scatter.COALESCE_MAX_LANES`` is read from): for one
-    field's write into ``f32[131072,384]`` (delta 369 wide: config 4)
-    and ``f32[262144,128]`` (17: config 5; 65: config 3), at 2,048 to
-    131,072 lanes of Zipf(1.5) ids as ``synthetic_ctr`` draws them, the
-    plain add, the coalesced add, and the coalesce alone; and 8,192
-    lanes of uniform ids, the coalesced add's worst case. One jitted
-    program a point: ``reps`` rounds over four donated tables, each
-    round with ids of its own, ms per table per round at the median of
-    three fenced calls."""
+    """The ``scatter_add`` write's batch-size ladder (PERF.md §6, PR 35
+    and PR 37; what ``ops/scatter.update_lanes``' two constants are read
+    from): for one field's write into ``f32[131072,384]`` (delta 369
+    wide: config 4), ``f32[262144,128]`` (17: config 5; 65: config 3)
+    and ``f32[524288,128]`` (128: DLRM), at 2,048 to 131,072 lanes of
+    Zipf(1.5) ids as ``synthetic_ctr`` draws them, the plain add, the
+    coalesced add, and the coalesce alone; uniform ids, the coalesced
+    add's worst case, at 8,192 lanes and at DLRM's 55,296; the mesh's
+    ``f32[262144,65]`` and a table of 2^20 rows on either side of an
+    eighth of their rows; and, for the record only, DLRM's plain add
+    padded to 66,560 lanes with dropped ids (``plain_padded``: over
+    XLA's switch by construction, NOT shipped). One jitted program a
+    point: ``reps`` rounds over four donated tables, each round with ids
+    of its own, ms per table per round at the median of three fenced
+    calls."""
     import statistics
 
     import jax
@@ -971,6 +997,11 @@ def bench_ladder(args):
     def plain(t, i, d):
         return t.at[i].add(scatter._to_table_width(d, t), mode="drop")
 
+    def plain_padded(t, i, d):
+        extra = _LADDER_PADDED_LANES - i.shape[0]
+        i = jnp.concatenate([i, jnp.full((extra,), t.shape[0], i.dtype)])
+        return plain(t, i, jnp.pad(d, ((0, extra), (0, 0))))
+
     def coalesce_only(t, i, d):
         useg, totals, n = scatter.coalesce(i, d)
         # Everything the coalesce makes is read (nothing DCE'd), one
@@ -980,7 +1011,9 @@ def bench_ladder(args):
             scatter._to_table_width(keep, t))
 
     variants = {"plain": plain, "coalesced": scatter.coalesced_add,
-                "coalesce_only": coalesce_only}
+                "coalesce_only": coalesce_only,
+                "plain_padded": plain_padded}
+    three = ("plain", "coalesced", "coalesce_only")
 
     def point(rows, lanes_w, width, b, draw, variant):
         rng = np.random.default_rng(b)
@@ -1013,11 +1046,16 @@ def bench_ladder(args):
 
     zipf = lambda rng, size, rows: rng.zipf(1.5, size=size) % rows
     uniform = lambda rng, size, rows: rng.integers(0, rows, size=size)
-    ladder = [(s, b, "zipf", zipf) for s in _LADDER_SHAPES
-              for b in _LADDER_LANES]
-    ladder += [(s, 8192, "uniform", uniform) for s in _LADDER_SHAPES[:2]]
-    for (rows, lanes_w, width), b, name, draw in ladder:
-        for variant in variants:
+    # PR 37's points first, then PR 35's as they stood.
+    ladder = [(s, b, "zipf", zipf, three) for s, b in _LADDER_POINTS_37]
+    ladder += [(_DLRM, 55296, "uniform", uniform, three),
+               (_DLRM, 55296, "zipf", zipf, ("plain_padded",))]
+    ladder += [(s, b, "zipf", zipf, three) for s in _LADDER_SHAPES
+               for b in _LADDER_LANES]
+    ladder += [(s, 8192, "uniform", uniform, three)
+               for s in _LADDER_SHAPES[:2]]
+    for (rows, lanes_w, width), b, name, draw, vs in ladder:
+        for variant in vs:
             ms, unique = point(rows, lanes_w, width, b, draw, variant)
             _out(f"ladder_{variant}",
                  {"table": [rows, lanes_w], "delta_width": width,

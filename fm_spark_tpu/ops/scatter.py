@@ -6,23 +6,34 @@ The FieldFM hot path updates ``B`` gathered rows per field per step
 
 - ``"scatter_add"`` — the batch's deltas ADDED to their rows, every
   occurrence of a row counted. How many lanes go into the table at a
-  time is chosen from the shapes (:func:`update_lanes`): a large batch
-  adds its ``B`` lanes as they come (``.at[ids].add``; duplicates
-  accumulate in XLA's scatter), a small one COALESCES first
-  (:func:`coalesced_add`: each unique row's float32 sum, then as many
-  chunks of ``RULE_CHUNK`` lanes as hold the unique rows). The measured
-  default (PERF.md §6, PR 35). Why by the lanes: on the v5e a plain add
-  into a ``[131072, 384]`` or ``[262144, 128]`` float32 table costs
-  75-115 ns a LANE, written or dropped, up to 16,384 / 32,768 lanes,
-  and 18-41 ns a lane from the next rung of the ladder up (XLA lowers
-  the larger scatter another way, with a sort of its own); coalescing
-  costs 20-46 ns a lane of the batch and a chunk of 1,024 lanes 0.06-
-  0.09 ms. One field, Zipf(1.5) ids, ms plain / coalesced at 2,048,
-  4,096, 8,192, 16,384, 32,768, 65,536, 131,072 lanes: ``[131072,
-  384]`` 0.235 / 0.182, 0.449 / 0.233, 0.865 / 0.342, 1.747 / 0.590,
-  1.341 / 1.200, 2.168 / 2.381, 3.800 / 4.768; ``[262144, 128]`` 0.176
-  / 0.141, 0.328 / 0.181, 0.631 / 0.261, 1.233 / 0.411, 2.440 / 0.801,
-  1.171 / 1.580, 2.385 / 3.409.
+  time is chosen from the shapes (:func:`update_lanes`): the ``B`` lanes
+  as they come (``.at[ids].add``; duplicates accumulate in XLA's
+  scatter) where XLA gives that add its cheap lowering and the batch is
+  large, else COALESCED first (:func:`coalesced_add`: each unique row's
+  float32 sum, then as many chunks of ``RULE_CHUNK`` lanes as hold the
+  unique rows). The measured default (PERF.md §6, PR 35 and PR 37). Why
+  by the lanes and the table's rows: on the v5e a plain add costs
+  75-115 ns a LANE, written or dropped, while XLA scatters lane by
+  lane, and 14-54 ns once it sorts the update first, which it does from
+  one lane over AN EIGHTH OF THE TABLE'S ROWS, whatever the columns
+  (read from the compiled add, ``tests/test_table_layout.py``; not from
+  the update's elements: 65,536 lanes x 128 columns are cheap into
+  262,144 rows and dear into 524,288); coalescing costs 20-46 ns a lane
+  of the batch and a chunk of 1,024 lanes 0.06-0.09 ms. One field,
+  Zipf(1.5) ids, ms plain / coalesced (``bench_micro.py ladder``). At
+  2,048, 4,096, 8,192, 16,384, 32,768, 65,536, 131,072 lanes (PR 35):
+  ``[131072, 384]`` 0.235 / 0.182, 0.449 / 0.233, 0.865 / 0.342, 1.747
+  / 0.590, 1.341 / 1.200, 2.168 / 2.381, 3.800 / 4.768; ``[262144,
+  128]`` 0.176 / 0.141, 0.328 / 0.181, 0.631 / 0.261, 1.233 / 0.411,
+  2.440 / 0.801, 1.171 / 1.580, 2.385 / 3.409. At 32,768, 49,152,
+  55,296, 61,440, 65,536, 131,072 lanes (PR 37): ``[524288, 128]``
+  (DLRM's; its eighth is 65,536) 2.516 / 0.807, 3.721 / 1.195, 4.184 /
+  1.344, 4.715 / 1.506, 5.044 / 1.581, 2.326 / 3.418; ``[262144,
+  128]`` (eighth 32,768) 2.536 / 0.803, 0.993 / 1.195, 1.063 / 1.339,
+  1.132 / 1.505, 1.173 / 1.581, 2.387 / 3.412; ``[131072, 384]``
+  (eighth 16,384) at 20,480 and 22,528 lanes 1.106 / 0.813 and 1.148 /
+  0.876, at 49,152, 55,296, 61,440 1.817 / 1.794, 1.947 / 2.030, 2.077
+  / 2.254.
 - ``"dedup"`` — in-batch segment-sum first: sort ids, sum duplicate rows'
   deltas with a fixed-shape ``segment_sum``, then ONE add per unique id
   (duplicate lanes write out-of-bounds and are dropped — XLA scatter
@@ -442,17 +453,40 @@ def set_rows_at(table, useg, rows):
 
 
 # Most lanes a field's ``scatter_add`` write coalesces before it adds
-# (:func:`update_lanes`): the highest rung of the ladder at which the
-# coalesced add measured faster than the plain one, on both tables the
-# one-chip cells hold (the module's docstring has every rung; PERF.md
-# §6, PR 35). At 32,768 lanes it wins by 1.12x into [131072, 384] and
-# 3.0x into [262144, 128]; at 65,536 it loses by 1.10x and 1.35x: XLA's
-# own scatter is another, cheaper algorithm up there (18-41 ns a lane
-# against 75-115), and sorting and summing the whole batch first costs
-# more than it saves. Uniform ids, every lane a row of its own, are the
-# coalesced add's worst case: 8,192 of them cost it 1.19-1.20x the plain
-# add (all eight chunks written, the coalesce pure cost).
+# WHATEVER the plain add would cost (:func:`update_lanes`' first
+# clause): the highest rung of PR 35's ladder at which the coalesced add
+# measured faster than the plain one on every table, XLA's cheap
+# lowering included (the module's docstring has every rung; PERF.md §6,
+# PR 35 and PR 37). At 32,768 lanes it wins by 1.12x into [131072, 384]
+# (a quarter of its rows: the plain add is on the cheap lowering
+# already) and 3.0x into [262144, 128] (an eighth: dear); into [131072,
+# 384] it ties at 49,152 (1.817 / 1.794 ms) and loses from 55,296, and
+# at 65,536 it loses by 1.10x and 1.35x: the cheap lowering costs 14-41
+# ns a lane from there up, and sorting and summing the whole batch
+# first costs more than it saves. Uniform ids, every lane a row of its
+# own, are the coalesced add's worst case: 8,192 of them cost it
+# 1.19-1.20x the plain add (all eight chunks written, the coalesce pure
+# cost), 55,296 of them 1.16x (4.781 against 4.125 ms into [524288,
+# 128]).
 COALESCE_MAX_LANES = 32768
+
+# Rows of the table per lane of the update from which the plain add is
+# DEAR (:func:`update_lanes`' second clause). XLA lowers the plain add
+# two ways and chooses by the lanes against the table's ROWS: compiled
+# for a v5e, it sorts the update first from one lane over an eighth of
+# the rows ([65536 ... 2097152, 128 | 384]: no ``sort`` at rows / 8
+# lanes, one at rows / 8 + 1, whatever the columns;
+# ``tests/test_table_layout.py`` holds a libtpu to it), and the ladder
+# prices the two sides (PERF.md §6, PR 37): into [524288, 128] 75.7-77.0
+# ns a lane at 32,768 to 65,536 lanes, the eighth itself included, and
+# 17.7 at 131,072; into [262144, 128] 77.4 at 32,768 and 20.2-17.9 at
+# 49,152 to 65,536; into [131072, 384] 100.6 at 16,384 and 54.0 at
+# 20,480. With this many rows a lane or more the plain add pays the
+# dear price however many lanes there are, and the coalesced add, 24-25
+# ns a lane of 128 columns all told, wins 3.1-3.2x however many there
+# are. The update's ELEMENTS decide nothing: 65,536 lanes x 128 columns
+# are cheap into 262,144 rows and dear into 524,288.
+PLAIN_DEAR_ROWS_PER_LANE = 8
 
 
 def update_lanes(lanes: int, table_shape) -> int:
@@ -464,14 +498,13 @@ def update_lanes(lanes: int, table_shape) -> int:
     the training loop reports its answer
     (``train/update_lanes_per_field``). A batch of one chunk or less has
     nothing to save, and one that is not whole chunks is not walked in
-    chunks (the AdaGrad body's conditions too)."""
-    # The ladder's two tables, [131072, 384] and [262144, 128], cross
-    # between the same two rungs, so the shape decides nothing yet; it
-    # stays in the question, which a table that crosses elsewhere
-    # changes here and not in the callers.
-    del table_shape
-    coalesces = (RULE_CHUNK < lanes <= COALESCE_MAX_LANES
-                 and lanes % RULE_CHUNK == 0)
+    chunks (the AdaGrad body's conditions too). A larger one is left
+    plain only where the plain add gets XLA's cheap lowering AND has
+    more lanes than coalescing ever beat that lowering at."""
+    whole_chunks = lanes > RULE_CHUNK and lanes % RULE_CHUNK == 0
+    plain_is_dear = lanes * PLAIN_DEAR_ROWS_PER_LANE <= table_shape[0]
+    coalesces = whole_chunks and (lanes <= COALESCE_MAX_LANES
+                                  or plain_is_dear)
     return RULE_CHUNK if coalesces else lanes
 
 

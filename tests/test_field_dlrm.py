@@ -358,6 +358,37 @@ def test_cli_train_matches_the_reference_from_its_own_init(tiny):
     assert obs.gauge("train/update_lanes_per_field").value == BATCH
 
 
+def engage_the_coalesced_write(monkeypatch, clause="rows"):
+    """``ops/scatter``'s constants cut to the tiny batch and table, so
+    that 64 lanes into 48 rows coalesce in chunks of 16 through one of
+    ``update_lanes``' clauses: more lanes than the lane clause takes and
+    a table with rows enough a lane (the cell's case: 55,296 lanes,
+    under an eighth of its table's rows), or the lane clause alone."""
+    from fm_spark_tpu.ops import scatter
+
+    monkeypatch.setattr(scatter, "RULE_CHUNK", BATCH // 4)
+    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES",
+                        BATCH // 2 if clause == "rows" else BATCH)
+    monkeypatch.setattr(scatter, "PLAIN_DEAR_ROWS_PER_LANE",
+                        BUCKET / BATCH if clause == "rows" else BUCKET)
+    assert scatter.update_lanes(BATCH, (BUCKET, K)) == BATCH // 4
+
+
+@pytest.mark.parametrize("clause", ["rows", "lanes"])
+def test_the_coalesced_write_passes_the_cells_check(tiny, monkeypatch,
+                                                    clause):
+    """The driver's comparison and the gauge read the coalesced write."""
+    ctx, cfg = tiny
+    engage_the_coalesced_write(monkeypatch, clause)
+    obs.gauge("train/update_lanes_per_field").set(-1)
+    verdict = through_cli(ctx, cfg)
+    assert verdict["ok"], verdict
+    assert verdict["early"]["rows"]["over_allowed"] < 0.1
+    assert verdict["early"]["rows"]["row_distance_median"] < (
+        0.1 * MIX["row_distance_median"])
+    assert obs.gauge("train/update_lanes_per_field").value == BATCH // 4
+
+
 def test_a_reference_from_another_seed_fails(tiny):
     ctx, cfg = tiny
     uniq, counts, inv, x, labels = train_dlrm.one_batch(ctx, 1)
@@ -375,6 +406,19 @@ def test_the_check_catches(tiny, fault):
     with faults.FAULTS[fault](cfg.name):
         verdict = through_cli(ctx, configs_lib.CONFIGS[cfg.name])
     assert not verdict["ok"], (fault, verdict)
+
+
+def test_the_check_catches_bfloat16_tables_under_the_coalesced_write(
+        tiny, monkeypatch):
+    """One rounded SUM a row is nearer float32 than a rounded term an
+    occurrence, and is still not float32: the fault must read so with the
+    write the cell's batch takes."""
+    ctx, cfg = tiny
+    engage_the_coalesced_write(monkeypatch)
+    with faults.bf16_tables(cfg.name):
+        verdict = through_cli(ctx, configs_lib.CONFIGS[cfg.name])
+    assert obs.gauge("train/update_lanes_per_field").value == BATCH // 4
+    assert not verdict["ok"], verdict
 
 
 def test_the_reference_one_precision_lower_fails(tiny):

@@ -236,15 +236,25 @@ def _coalesced_case(name):
         table = np.pad(table, ((0, 0), (0, 120)))       # 8 columns in 128
     elif name == "bfloat16":
         table = np.asarray(jnp.asarray(table, jnp.bfloat16))
+    elif name == "as_is":
+        # DLRM's: a row IS a lane tile and the delta is as wide, so
+        # _to_table_width pads nothing.
+        table = rng.normal(size=(n_rows, 128)).astype(np.float32)
+        delta = (rng.normal(size=(b, 128)) * 0.1).astype(np.float32)
     return jnp.asarray(table), jnp.asarray(ids, jnp.int32), jnp.asarray(delta)
 
 
 @pytest.mark.parametrize("name", ["duplicates", "all_unique",
                                   "drop_sentinels", "lane_padded",
-                                  "bfloat16"])
+                                  "bfloat16", "as_is"])
 def test_coalesced_add_is_the_plain_add(monkeypatch, name):
     monkeypatch.setattr(scatter, "RULE_CHUNK", 16)
-    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES", 64)
+    # as_is engages through the ROWS clause: more lanes than the lane
+    # clause takes, and no more than a third of the table's rows.
+    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES",
+                        32 if name == "as_is" else 64)
+    monkeypatch.setattr(scatter, "PLAIN_DEAR_ROWS_PER_LANE",
+                        3 if name == "as_is" else 4)
     table, ids, delta = _coalesced_case(name)
     assert scatter.update_lanes(ids.shape[0], table.shape) == 16
     got = np.asarray(
@@ -270,15 +280,43 @@ def test_coalesced_add_is_the_plain_add(monkeypatch, name):
         got[untouched], np.asarray(table, np.float32)[untouched])
 
 
-@pytest.mark.parametrize("lanes,want", [
-    (1024, 1024),         # one chunk: nothing to save
-    (2048, 1024), (8192, 1024), (16384, 1024), (32768, 1024),
-    (8192 + 512, 8192 + 512),       # not whole chunks
-    (65536, 65536), (131072, 131072), (524288, 524288),
+FFM_TABLE, DEEPFM_TABLE = (1 << 17, 384), (1 << 18, 128)
+DLRM_TABLE = (1 << 19, 128)
+# The mesh's form of config 3's tables: 65 columns, nothing padded on.
+MESH_TABLE = (1 << 18, 65)
+BIG_TABLE = (1 << 20, 128)
+
+
+@pytest.mark.parametrize("table,lanes,want", [
+    *[(table, lanes, want) for table in (FFM_TABLE, DEEPFM_TABLE)
+      for lanes, want in [
+          (1024, 1024),         # one chunk: nothing to save
+          (2048, 1024), (8192, 1024), (16384, 1024), (32768, 1024),
+          (8192 + 512, 8192 + 512),       # not whole chunks
+          (65536, 65536), (131072, 131072), (524288, 524288)]],
+    # DLRM's table: XLA sorts the plain add (its cheap lowering) from
+    # one lane over an eighth of the rows, 65,536 here.
+    (DLRM_TABLE, 32768, 1024), (DLRM_TABLE, 49152, 1024),
+    (DLRM_TABLE, 55296, 1024), (DLRM_TABLE, 61440, 1024),
+    (DLRM_TABLE, 65536, 1024), (DLRM_TABLE, 66560, 66560),
+    (DLRM_TABLE, 131072, 131072),
+    (DLRM_TABLE, 55296 + 512, 55296 + 512),     # ragged: plain
+    (DLRM_TABLE, 1024, 1024),
+    # The same lanes are over an eighth of the smaller tables' rows:
+    # cheap already, and more than coalescing ever beat that at.
+    (FFM_TABLE, 49152, 49152), (FFM_TABLE, 55296, 55296),
+    (FFM_TABLE, 20480, 1024), (FFM_TABLE, 22528, 1024),
+    (DEEPFM_TABLE, 49152, 49152), (DEEPFM_TABLE, 55296, 55296),
+    (DEEPFM_TABLE, 61440, 61440),
+    # The columns decide nothing: the mesh's 65 as they come.
+    (MESH_TABLE, 32768, 1024), (MESH_TABLE, 65536, 65536),
+    (MESH_TABLE, 131072, 131072), (MESH_TABLE, 524288, 524288),
+    # Config 3's batch into a table of 2^20 rows and more (Reach 2).
+    (BIG_TABLE, 131072, 1024), (BIG_TABLE, 132096, 132096),
+    (BIG_TABLE, 524288, 524288),
 ])
-def test_update_lanes_by_the_real_constants(lanes, want):
-    assert scatter.update_lanes(lanes, (1 << 17, 384)) == want
-    assert scatter.update_lanes(lanes, (1 << 18, 128)) == want
+def test_update_lanes_by_the_real_constants(table, lanes, want):
+    assert scatter.update_lanes(lanes, table) == want
 
 
 def _lowered(family, batch, **config):
@@ -291,11 +329,11 @@ def _lowered(family, batch, **config):
         debug_info=True)
 
 
-def _table_scatter_lanes(text):
-    """Lane counts of every scatter into a ``[4096, w]`` table."""
+def _table_scatter_lanes(text, rows=4096):
+    """Lane counts of every scatter into a ``[rows, w]`` table."""
     return sorted({int(m) for m in re.findall(
-        r"\(tensor<4096x\d+xf32>, tensor<(\d+)x1xi32>, "
-        r"tensor<\d+x\d+xf32>\) -> tensor<4096x", text)})
+        rf"\(tensor<{rows}x\d+xf32>, tensor<(\d+)x1xi32>, "
+        rf"tensor<\d+x\d+xf32>\) -> tensor<{rows}x", text)})
 
 
 def test_the_write_coalesces_by_shape_alone():
@@ -312,6 +350,89 @@ def test_the_write_coalesces_by_shape_alone():
     for scope in ("opt/coalesce", "opt/gather", "opt/rule", "opt/write"):
         assert scope in adagrad
     assert _table_scatter_lanes(adagrad) == [1024]
+
+
+def _dlrm(rank, bucket=4096):
+    """A small DLRM: 2 value slots and 3 tables of ``rank``-wide rows."""
+    import dataclasses
+
+    from fm_spark_tpu import configs
+
+    cfg = dataclasses.replace(
+        configs.CONFIGS["criteo1tb_dlrm_mlperf"], rank=rank, num_fields=5,
+        dense_fields=2, bottom_mlp_dims=(8, rank), mlp_dims=(8,),
+        bucket=bucket)
+    return cfg.spec(), cfg.train_config()
+
+
+@pytest.mark.parametrize("batch,lanes", [
+    # The cell's write: 55,296 lanes are more than the lane clause takes
+    # and under an eighth of the table's 524,288 rows, where the plain
+    # add misses XLA's cheap lowering.
+    (55296, 1024),
+    (65536, 1024),                  # the eighth itself: still dear
+    (66560, 66560),                 # one chunk over it: plain
+    (55296 + 512, 55296 + 512),     # ragged: plain
+    (32768, 1024),                  # the lane clause, as before
+])
+def test_a_dlrm_step_scatters_what_the_tables_rows_say(batch, lanes):
+    """The real constants, lowered on the CPU at the cell's table shape:
+    every scatter into a DLRM step's ``[524288, 128]`` tables takes
+    ``update_lanes``' lanes."""
+    spec, config = _dlrm(128, bucket=1 << 19)
+    assert scatter.update_lanes(batch, (spec.bucket, 128)) == lanes
+    text = sparse.lower_field_sparse_step(spec, config, batch).as_text(
+        debug_info=True)
+    assert _table_scatter_lanes(text, rows=spec.bucket) == [lanes]
+    assert ("sgd/coalesce" in text) == ("sgd/write" in text) == (
+        lanes != batch)
+
+
+def test_a_coalescing_dlrm_step_is_the_plain_step(monkeypatch):
+    """Three fused DLRM steps on one batch with duplicates, the write
+    engaged through the rows clause at a tiny size (64 lanes, an eighth
+    of 512 rows), against the same steps with the write left plain:
+    equal to float32 reassociation."""
+    batch, bucket, steps = 64, 512, 3
+    spec, config = _dlrm(8, bucket=bucket)
+    rng = np.random.default_rng(13)
+    ids = np.where(rng.random((batch, 5)) < 0.7,
+                   rng.integers(0, 6, (batch, 5)),
+                   rng.integers(0, bucket, (batch, 5))).astype(np.int32)
+    vals = np.ones((batch, 5), np.float32)
+    vals[:, :2] = np.log1p(rng.integers(0, 9, (batch, 2)))
+    labels = rng.integers(0, 2, batch).astype(np.float32)
+    weights = np.ones((batch,), np.float32)
+    monkeypatch.setattr(scatter, "RULE_CHUNK", 16)
+    monkeypatch.setattr(scatter, "COALESCE_MAX_LANES", 32)
+
+    def run(rows_per_lane):
+        monkeypatch.setattr(scatter, "PLAIN_DEAR_ROWS_PER_LANE",
+                            rows_per_lane)
+        text = sparse.lower_field_sparse_step(spec, config, batch).as_text(
+            debug_info=True)
+        step = sparse.make_field_dlrm_sparse_step(spec, config)
+        params = spec.init(jax.random.key(3))
+        opt, losses = step.init_opt_state(params), []
+        for i in range(steps):
+            params, opt, loss = step(params, opt, jnp.int32(i), ids, vals,
+                                     labels, weights)
+            losses.append(float(loss))
+        return text, jax.tree.map(np.asarray, params), losses
+
+    text, got, got_losses = run(scatter.PLAIN_DEAR_ROWS_PER_LANE)
+    assert "sgd/write" in text
+    assert _table_scatter_lanes(text, rows=bucket) == [16]
+    text, want, want_losses = run(scatter.PLAIN_DEAR_ROWS_PER_LANE + 1)
+    assert "sgd/write" not in text
+    assert _table_scatter_lanes(text, rows=bucket) == [batch]
+    np.testing.assert_allclose(got_losses, want_losses, rtol=1e-6)
+    start = jax.tree.map(np.asarray, spec.init(jax.random.key(3)))
+    for g, w, s0 in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        jax.tree.leaves(start)):
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * np.abs(w - s0).max())
+    assert any((w != s0).any() for w, s0 in zip(want["vw"], start["vw"]))
 
 
 def test_coalescing_ffm_steps_match_the_plain_reference(monkeypatch):

@@ -154,6 +154,41 @@ def test_compiled_step_copies_no_table(one_chip, family, steps_per_call):
             f"{FIELDS}: re-read PERF.md §5 before trusting the padding")
 
 
+# ops/scatter.update_lanes leaves a large write plain only where XLA
+# gives the plain add its cheap lowering, the one that sorts the update
+# first (PERF.md §6, PR 37: 14-41 ns a lane with the sort, 75-115
+# without). Where that starts is the compiler's choice, read here from
+# the compiled add itself: a libtpu that moves it fails these cases, not
+# a cell.
+@pytest.mark.parametrize("table,over", [
+    ((1 << 19, 128), 0), ((1 << 19, 128), 1),       # DLRM's
+    ((1 << 18, 128), 0),                            # config 3's and 5's
+    ((1 << 17, 384), 0), ((1 << 17, 384), 1),       # config 4's
+])
+def test_xla_sorts_the_plain_add_where_update_lanes_says_it_is_cheap(
+        one_chip, table, over):
+    """No ``sort`` at the share of the rows ``update_lanes`` calls dear,
+    one a lane over it."""
+    from fm_spark_tpu.ops import scatter
+
+    share = table[0] // scatter.PLAIN_DEAR_ROWS_PER_LANE
+    lanes = share + over
+    chip = SingleDeviceSharding(one_chip)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+    compiled = jax.jit(
+        lambda t, i, d: t.at[i].add(d, mode="drop"), donate_argnums=0).lower(
+            sds(table, jnp.float32), sds((lanes,), jnp.int32),
+            sds((lanes, table[1]), jnp.float32)).compile()
+    sorts = len(re.findall(r" sort\(", compiled.as_text()))
+    assert sorts == over
+    # The rule's side of it, in whole chunks: coalesced up to the share,
+    # plain from one chunk over it (past the lanes the lane clause takes).
+    assert update_lanes(share, table) == scatter.RULE_CHUNK
+    over_it = max(share, scatter.COALESCE_MAX_LANES) + scatter.RULE_CHUNK
+    assert update_lanes(over_it, table) == over_it
+
+
 # The scorer's side (a holder that only reads): the tables as PredictEngine holds a
 # generation of each registry family at the sizes the benchmark serves or
 # trains, the engine's own program (``spec.predict`` under jit) compiled
