@@ -29,6 +29,7 @@ import argparse
 import dataclasses
 import json
 import sys
+import time
 
 import numpy as np
 
@@ -256,8 +257,11 @@ def _resume(checkpointer, params, opt_state, batches,
         else "drop --ckpt-sharded to resume it (or point "
         "--checkpoint-dir at a fresh directory)"
     )
+    from fm_spark_tpu import obs
+
     try:
-        restored = checkpointer.restore(params, opt_state)
+        with obs.interval("setup/resume", layout=layout):
+            restored = checkpointer.restore(params, opt_state)
     except Exception as e:
         raise SystemExit(
             f"could not restore the checkpoint as {layout}-layout — the "
@@ -732,6 +736,14 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
     return step, params, opt, prep, to_canonical, mesh
 
 
+def _tree_bytes(tree) -> int:
+    """The bytes of a tree's arrays by their shapes (no device asked)."""
+    import jax
+
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree.leaves(tree))
+
+
 def _hold_slots(slots0):
     """The one-chip loop's table slots (``optim.init_field_slots``'s
     tree, or a checkpoint's) placed as its tables are: each slot table
@@ -760,7 +772,7 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                       eval_source=None, prefetch: int = 0,
                       row_shards: int = 1, steps_per_call: int = 1,
                       ckpt_sharded: bool = False, devices=None,
-                      place_in_loop: bool = False):
+                      place_in_loop: bool = False, setup_done=None):
     """Training loop on the fused sparse steps (the CTR fast path).
 
     On one device this is the single-chip fused step; with multiple
@@ -794,9 +806,22 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
     chips. ``place_in_loop`` keeps ``prep`` on the loop's own thread
     instead: the elastic wrapper's, whose mesh shrinks under it — a
     queued batch placed on a lost chip is worse than a slow one.
+
+    What comes before the loop leaves hot intervals, disjoint and in
+    this order: ``setup/init`` (``spec.init`` and the optimizer's state,
+    with the waits ``spec.init`` makes itself and no other),
+    ``setup/resume`` (only where a checkpoint is read; after
+    ``setup/place`` under ``ckpt_sharded``), ``setup/place``
+    (:func:`_place_field_state` and :func:`_hold_slots`) and
+    ``setup/step_build`` (everything from there to the loop: the
+    placement report, evaluators, rolled steps, the feed). ``setup_done``,
+    where given, is called the instant before the loop's first step:
+    ``cmd_train`` closes its ``setup/run`` there.
     """
     import jax
     import jax.numpy as jnp
+
+    from fm_spark_tpu import obs
 
     n = len(devices) if devices is not None else jax.device_count()
     pc = jax.process_count()
@@ -832,30 +857,33 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         )
 
     # ---- state init ---------------------------------------------------
-    canonical = spec.init(jax.random.key(tconfig.seed))
-    opt0 = {}
-    if is_deepfm:
-        from fm_spark_tpu.train import make_optimizer
+    with obs.interval("setup/init") as phase:
+        canonical = spec.init(jax.random.key(tconfig.seed))
+        opt0 = {}
+        if is_deepfm:
+            from fm_spark_tpu.train import make_optimizer
 
-        # Dense-head optimizer state only (structure is device-count
-        # independent, so checkpoints resume on any mesh).
-        opt0 = make_optimizer(tconfig).init(
-            {k: canonical[k] for k in spec.dense_keys}
-        )
-    elif slots:
-        # Table-sized state: only its shapes until a checkpoint has had
-        # its say (a restore reads into them), so that a resume never
-        # holds fresh slots beside the restored ones.
-        import functools
+            # Dense-head optimizer state only (structure is device-count
+            # independent, so checkpoints resume on any mesh).
+            opt0 = make_optimizer(tconfig).init(
+                {k: canonical[k] for k in spec.dense_keys}
+            )
+        elif slots:
+            # Table-sized state: only its shapes until a checkpoint has
+            # had its say (a restore reads into them), so that a resume
+            # never holds fresh slots beside the restored ones.
+            import functools
 
-        from fm_spark_tpu import optim
-        from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
+            from fm_spark_tpu import optim
+            from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
-        init_slots = functools.partial(
-            optim.init_field_slots, tconfig.optimizer,
-            keys=FUSED_TABLE_KEYS,
-            init_accumulator=tconfig.adagrad_init_accumulator)
-        opt0 = jax.eval_shape(init_slots, canonical)
+            init_slots = functools.partial(
+                optim.init_field_slots, tconfig.optimizer,
+                keys=FUSED_TABLE_KEYS,
+                init_accumulator=tconfig.adagrad_init_accumulator)
+            opt0 = jax.eval_shape(init_slots, canonical)
+        n_tables = len(jax.tree.leaves(canonical["vw"]))
+        phase.set(tables=n_tables, bytes=_tree_bytes(canonical))
     start = 0
     if not ckpt_sharded:
         # Default: checkpoints use the canonical per-field-list layout so
@@ -863,20 +891,30 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         # happens AFTER params are placed on the mesh, below.)
         canonical, opt0, start = _resume(checkpointer, canonical, opt0,
                                          batches)
-    if slots and isinstance(jax.tree.leaves(opt0)[0], jax.ShapeDtypeStruct):
-        opt0 = init_slots(canonical)
-
-    step, params, opt, prep, to_canonical, mesh = _place_field_state(
-        spec, tconfig, cap, canonical, opt0, n, pc, sharded, row_shards,
-        compact_sharded, devices=devices,
-    )
+    opt_canonical = jax.device_get if is_deepfm else (lambda o: {})
+    with obs.interval("setup/place", tables=n_tables) as phase:
+        if slots and isinstance(jax.tree.leaves(opt0)[0],
+                                jax.ShapeDtypeStruct):
+            opt0 = init_slots(canonical)
+        step, params, opt, prep, to_canonical, mesh = _place_field_state(
+            spec, tconfig, cap, canonical, opt0, n, pc, sharded, row_shards,
+            compact_sharded, devices=devices,
+        )
+        if slots:
+            opt, opt_canonical = _hold_slots(opt)
+        # A spec's tables share one shape, so one says how all are held.
+        held, made = (jax.tree.leaves(tree["vw"])[0].shape
+                      for tree in (params, canonical))
+        phase.set(bytes=_tree_bytes((params, opt)),
+                  form=("stacked" if sharded else
+                        "padded" if held != made else "as_is"))
 
     if ckpt_sharded:
         params, opt, start = _resume(checkpointer, params, opt, batches,
                                      layout="sharded")
+    t_build = time.perf_counter()
     # Where the tables landed, and what each device's memory looked like
     # once they had (chip_smoke.py checks both on the chip).
-    from fm_spark_tpu import obs
     from fm_spark_tpu.utils import device as device_lib
 
     placed = device_lib.placement(params)
@@ -932,10 +970,6 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
     log_every = max(tconfig.log_every, 1)
     since = 0
     from fm_spark_tpu.data import wrap_prefetch
-
-    opt_canonical = jax.device_get if is_deepfm else (lambda o: {})
-    if slots:
-        opt, opt_canonical = _hold_slots(opt)
 
     def pipe_state():
         """Pipeline cursor for checkpoints. Multi-host: strip the
@@ -1042,6 +1076,9 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         batches, close_prefetch = wrap_prefetch(batches, prefetch,
                                                 place=prep)
         prep = lambda placed: placed
+    obs.record_interval("setup/step_build", t_build, time.perf_counter())
+    if setup_done is not None:
+        setup_done()
     # The loop's hot intervals (obs.interval: always-live ring, profiler
     # annotation, trace.jsonl). Per iteration a parent ``train/step``
     # and inside it next_batch (the wait on the prefetch queue), prep
@@ -1495,8 +1532,14 @@ def _announce_device(cache_dir: str) -> None:
 
 
 def cmd_train(args) -> int:
+    # Set-up is one hot interval, ``setup/run``: from here to the instant
+    # before the loop's first step (``setup_done`` below), with the
+    # phases that take the time as intervals inside it.
+    t_entry = time.perf_counter()
+
     from fm_spark_tpu import configs as configs_lib
     from fm_spark_tpu import models
+    from fm_spark_tpu import obs
     from fm_spark_tpu.data import Batches, train_test_split
     from fm_spark_tpu.train import FMTrainer, evaluate_params
     from fm_spark_tpu.utils import compile_cache
@@ -1714,7 +1757,9 @@ def cmd_train(args) -> int:
                 lambda b: (_field_local(b[0], cfg.bucket), *b[1:]),
             )
     else:
-        ids, vals, labels, num_features = load_dataset(cfg, args)
+        with obs.interval("setup/data") as phase:
+            ids, vals, labels, num_features = load_dataset(cfg, args)
+            phase.set(rows=len(labels))
         spec = cfg.spec(num_features if cfg.bucket <= 0 else None)
         (tr, te) = (
             train_test_split(ids, vals, labels, args.test_fraction,
@@ -1911,6 +1956,15 @@ def cmd_train(args) -> int:
         )
     else:
         eval_source = None
+
+    def setup_done():
+        obs.record_interval("setup/run", t_entry, time.perf_counter(),
+                            entry="train", config=cfg.name,
+                            chips=_jax.device_count())
+
+    if strategy != "field_sparse" or args.elastic:
+        # These loops are not instrumented: their set-up ends here.
+        setup_done()
     with profile_ctx:
         if strategy == "single" and embed_mode == "tiered":
             from fm_spark_tpu.embed import TieredTrainer
@@ -1954,7 +2008,8 @@ def cmd_train(args) -> int:
                                            prefetch=args.prefetch,
                                            row_shards=args.row_shards,
                                            steps_per_call=args.steps_per_call,
-                                           ckpt_sharded=args.ckpt_sharded)
+                                           ckpt_sharded=args.ckpt_sharded,
+                                           setup_done=setup_done)
             elif strategy in ("dp", "row"):
                 params = _fit_parallel(spec, tconfig, batches, strategy,
                                        logger, checkpointer,

@@ -26,9 +26,11 @@ Usage::
 
 Hot intervals (ISSUE 24) are the always-live twin: a few named call
 sites inside the loops every run executes (``train/*``, ``feed/*``,
-``serve/*``) record through :class:`Interval` into one bounded
-in-memory ring whether or not a run directory is configured — to spans
-what the registry is to metrics. One call feeds three sinks: the ring
+``serve/*``), the phases before those loops (``setup/*``, ISSUE 38) and
+every compilation jax reports (``compile/*``, recorded by
+``utils/compile_cache``'s listeners) record through :class:`Interval`
+into one bounded in-memory ring whether or not a run directory is
+configured — to spans what the registry is to metrics. One call feeds three sinks: the ring
 (:func:`intervals`), the profiler's trace (a
 ``jax.profiler.TraceAnnotation`` while a profiler session is on) and,
 when configured, the :class:`Tracer` (``trace.jsonl``, flight ring).

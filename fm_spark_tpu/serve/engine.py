@@ -160,6 +160,9 @@ class PredictEngine:
                  ids_dtype="int32", vals_dtype="float32"):
         import jax
 
+        # Where this engine's set-up began: the first warmup() closes
+        # the ``setup/run`` interval that starts here.
+        self._t_built: float | None = time.perf_counter()
         self.spec = spec
         self.buckets = tuple(sorted({int(b) for b in buckets}))
         if not self.buckets or self.buckets[0] < 1:
@@ -203,9 +206,13 @@ class PredictEngine:
         step, and a fresh engine that never swaps must still report it."""
         from fm_spark_tpu.models import rows
 
-        gen = Generation(
-            *rows.hold(params, self.spec.row_tables, writes=False),
-            step, gen_id)
+        with obs.interval("setup/install", gen_id=gen_id) as phase:
+            gen = Generation(
+                *rows.hold(params, self.spec.row_tables, writes=False),
+                step, gen_id)
+            phase.set(tables=sum(gen.held[f"tables_{form}"]
+                                 for form in rows.FORMS),
+                      resident_table_bytes=gen.held["resident_table_bytes"])
         self._gen = gen  # fmlint: disable=thread-lock-discipline -- THE swap: one atomic reference store; worker reads the reference once per batch (no-torn-swap contract, chaos-audited)
         obs.gauge("serve/generation_step").set(gen.step)
         for name, value in gen.held.items():
@@ -249,15 +256,17 @@ class PredictEngine:
         compile cache this is pure deserialization (asserted via
         :func:`fm_spark_tpu.utils.compile_cache.cache_stats` in tests
         and bench_serve). Returns ``{"seconds", "buckets",
-        "cache_stats"}``."""
+        "cache_stats"}``. The bucket loop is the hot interval
+        ``setup/warmup``; the first call also closes ``setup/run``
+        (``entry="serve"``), which began when the engine was built."""
         from fm_spark_tpu.utils import compile_cache
 
         jax = self._jax
         t0 = time.perf_counter()
         stats0 = compile_cache.cache_stats()
         gen = self._gen
-        with obs.span("serve/warmup", buckets=list(self.buckets),
-                      nnz=self.nnz):
+        with obs.interval("setup/warmup", buckets=len(self.buckets),
+                          nnz=self.nnz):
             for b in self.buckets:
                 if b in self._compiled:
                     continue
@@ -269,6 +278,11 @@ class PredictEngine:
                                          self._vals_dtype),
                 )
                 self._compiled[b] = lowered.compile()  # fmlint: disable=thread-lock-discipline -- warmup() runs before serving starts; bucket entries are add-only and never mutated after
+        if self._t_built is not None:
+            obs.record_interval("setup/run", self._t_built,
+                                time.perf_counter(), entry="serve",
+                                model=type(self.spec).__name__)
+            self._t_built = None
         stats1 = compile_cache.cache_stats()
         out = {
             "seconds": round(time.perf_counter() - t0, 4),

@@ -28,6 +28,26 @@ counts (jax's monitoring events) plus the on-disk footprint so tests,
 trusting wall-clock. jax's own ``JAX_ENABLE_COMPILATION_CACHE=false``
 still turns the cache off; nothing here overrides it.
 
+:func:`enable` also registers, once, a listener for jax's compile
+DURATIONS, which turns each into a hot interval of the program's one
+ring (``obs.record_interval``): ``compile/trace``, ``compile/lower``,
+``compile/backend`` and ``compile/cache_read``, with ``fun_name`` where
+jax passes it. In jax 0.9.0 ``backend_compile_duration`` wraps
+``compiler.compile_or_get_cached``, so a ``compile/backend`` is the XLA
+compilation OR the read from this cache: its ``cache_hit`` says which
+(and ``saved_s`` what the hit saved), and the ``compile/cache_read``
+lies inside it. One clock: the callback reads ``time.perf_counter()``
+when jax reports the duration and counts back; jax's own ``time.time()``
+stamps are not used. The parent is the recording thread's innermost open
+interval, so a compilation inside a loop hangs under the
+``train/dispatch`` (``step``) or ``serve/batch`` that caused it. jax
+reports a trace for every ``jax.numpy`` function traced INSIDE another
+function's trace or lowering, hundreds a step: a trace or lowering
+becomes an interval only where none is open around it on its thread (the
+outer one's duration holds it), told by the start jax announces for
+each; every ``compile/backend`` is kept. The listeners run only when
+something compiles.
+
 The cache composes with the AOT entries (:func:`fm_spark_tpu.sparse.
 precompile_field_sparse_step` and friends): an AOT ``.compile()``
 populates the same cache the later jit dispatch reads.
@@ -37,6 +57,9 @@ from __future__ import annotations
 
 import os
 import threading
+import time
+
+from fm_spark_tpu import obs
 
 __all__ = [
     "DEFAULT_DIR",
@@ -63,17 +86,70 @@ DEFAULT_DIR = os.path.join(_REPO_ROOT, ".jax_compile_cache")
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 
+# jax's duration events (jax/_src/dispatch.py, compiler.py) and the hot
+# interval each becomes. A jax without one of them loses that interval.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile/cache_read",
+}
+_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+
 _lock = threading.Lock()
 _state = {"dir": None, "hits": 0, "requests": 0, "listener": False}
+# Per thread (jax reports a compile's events on the compiling thread):
+# between a cache request and its backend_compile_duration, did the
+# cache answer (``hit``) and what did that save (``saved_s``); and how
+# many of jax's compile durations have started and not ended (``open``).
+_pending = threading.local()
 
 
 def _on_event(event: str, **_kw) -> None:
     if event == _HIT_EVENT:
         with _lock:
             _state["hits"] += 1
+        _pending.hit = True
     elif event == _REQUEST_EVENT:
         with _lock:
             _state["requests"] += 1
+        _pending.hit, _pending.saved_s = False, None
+
+
+def _on_start(event: str, _value=None, **_kw) -> None:
+    """jax announces a duration's start as a scalar under the same name:
+    count it, so that its end knows whether it lay inside another."""
+    if event in _COMPILE_SPANS:
+        _pending.open = getattr(_pending, "open", 0) + 1
+
+
+def _on_duration(event: str, duration, **kw) -> None:
+    """One of jax's compile durations into the ring (module docstring).
+    Called from inside a compile: whatever a later jax hands it, it
+    records what it can and never raises."""
+    try:
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            if event == _SAVED_EVENT:
+                _pending.saved_s = float(duration)
+            return
+        t1 = time.perf_counter()
+        if name != "compile/cache_read":    # jax announces no start of it
+            _pending.open = around = max(getattr(_pending, "open", 1) - 1, 0)
+            if around and name != "compile/backend":
+                return
+        attrs = {}
+        if "fun_name" in kw:
+            attrs["fun_name"] = str(kw["fun_name"])
+        if name == "compile/backend":
+            attrs["cache_hit"] = getattr(_pending, "hit", False)
+            saved = getattr(_pending, "saved_s", None)
+            if attrs["cache_hit"] and saved is not None:
+                attrs["saved_s"] = saved
+            _pending.hit, _pending.saved_s = False, None
+        obs.record_interval(name, t1 - float(duration), t1, **attrs)
+    except Exception:  # noqa: BLE001 -- a tracing fault must not fail the compile it narrates
+        pass
 
 
 def enable() -> str:
@@ -127,6 +203,8 @@ def enable() -> str:
         _state["listener"] = True
     if listen:
         monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_scalar_listener(_on_start)
     return path
 
 

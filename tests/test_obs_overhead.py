@@ -6,7 +6,8 @@
    supervisor, the latched ``obs.enabled()`` pattern in the trainer),
    so an UN-observed process must pay (almost) nothing. A 200-step
    synthetic train loop instrumented exactly like the hot paths is
-   timed against its bare twin.
+   held against its bare twin by the calls it makes and the bytes it
+   allocates (not by the clock: a shared core's noise is over the 1%).
 
 2. **SIGKILL-surviving flight recorder** — the whole point of the
    spool is that an *uncatchable* ending still leaves a parseable,
@@ -21,6 +22,7 @@ import signal
 import subprocess
 import sys
 import time
+import tracemalloc
 
 import pytest
 
@@ -82,26 +84,65 @@ def _loop_instrumented(steps: int, step_s: float) -> float:
     return time.perf_counter() - t0
 
 
+#: What one Python-level call costs, generously (CPython 3.12 makes one
+#: in 0.05-0.1 us): the conversion from calls made to time spent.
+CALL_S = 2e-7
+
+
+def _calls_and_bytes(loop, steps: int) -> tuple[int, int]:
+    """Python and C calls ``loop(steps, 0.0)`` makes, and the bytes it
+    leaves allocated (two passes: a profile function allocates too)."""
+    calls = 0
+
+    def count(frame, event, arg):
+        nonlocal calls
+        if event in ("call", "c_call"):
+            calls += 1
+
+    sys.setprofile(count)
+    try:
+        loop(steps, 0.0)
+    finally:
+        sys.setprofile(None)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        loop(steps, 0.0)
+        kept = tracemalloc.get_traced_memory()[0] - before
+    finally:
+        tracemalloc.stop()
+    return calls, kept
+
+
 @pytest.mark.parametrize("steps,step_s", [(200, 0.0005)])
 def test_disabled_tracing_overhead_under_1pct(steps, step_s):
+    """The disabled path under 1% of a ``step_s`` step, held by what
+    does not depend on the machine's load: the CALLS the instrumented
+    loop makes beyond its bare twin, and the bytes it leaves allocated.
+    (Until PR 38 this timed both loops and compared the clocks: on a
+    shared core the two spins differ by more than 1% of themselves, and
+    tier-1 went red every other run.)"""
     from fm_spark_tpu.obs import introspect
 
     obs.shutdown(reason=None)  # the disabled path is the unconfigured one
     introspect.clear()         # ...and the unarmed capture engine
     assert not obs.enabled()
     assert not introspect.active()
-    # Warm both loops (bytecode/alloc effects), then take the best of 3
-    # — min is the right statistic for a noise-floor comparison.
-    _loop_bare(20, step_s)
-    _loop_instrumented(20, step_s)
-    bare = min(_loop_bare(steps, step_s) for _ in range(3))
-    inst = min(_loop_instrumented(steps, step_s) for _ in range(3))
-    overhead = inst / bare - 1.0
-    # The contract is ≤1%; the spin calibration itself wobbles ~0.1%
-    # on a loaded CI core, so the assert keeps a little of the budget.
-    assert overhead <= 0.01, (
-        f"disabled-path tracing overhead {overhead:.2%} over "
-        f"{steps} steps (bare {bare:.4f}s vs instrumented {inst:.4f}s)")
+    # Warm both loops (bytecode/attribute caches) before counting.
+    _loop_bare(20, 0.0)
+    _loop_instrumented(20, 0.0)
+    bare_calls, bare_kept = _calls_and_bytes(_loop_bare, steps)
+    inst_calls, inst_kept = _calls_and_bytes(_loop_instrumented, steps)
+    per_step = (inst_calls - bare_calls) / steps
+    # mint_trace, span, its __enter__ and __exit__, observe_step_time,
+    # fire: six calls, none into C, nothing allocated.
+    assert per_step <= 8, (
+        f"the disabled path makes {per_step:.2f} calls a step "
+        f"({inst_calls} against the bare loop's {bare_calls})")
+    assert per_step * CALL_S <= 0.01 * step_s
+    assert inst_kept - bare_kept <= 16 * steps, (
+        f"the disabled path left {inst_kept - bare_kept} bytes allocated "
+        f"over {steps} steps")
 
 
 def test_disabled_span_is_allocation_free_singleton():
