@@ -19,8 +19,9 @@ The FieldFM hot path updates ``B`` gathered rows per field per step
   (read from the compiled add, ``tests/test_table_layout.py``; not from
   the update's elements: 65,536 lanes x 128 columns are cheap into
   262,144 rows and dear into 524,288); coalescing costs 20-46 ns a lane
-  of the batch and a chunk of 1,024 lanes 0.06-0.09 ms. One field,
-  Zipf(1.5) ids, ms plain / coalesced (``bench_micro.py ladder``). At
+  of the batch (7.1 less since PR 39) and a chunk of 1,024 lanes
+  0.06-0.09 ms. One field, Zipf(1.5) ids, ms plain / coalesced
+  (``bench_micro.py ladder``). At
   2,048, 4,096, 8,192, 16,384, 32,768, 65,536, 131,072 lanes (PR 35):
   ``[131072, 384]`` 0.235 / 0.182, 0.449 / 0.233, 0.865 / 0.342, 1.747
   / 0.590, 1.341 / 1.200, 2.168 / 2.381, 3.800 / 4.768; ``[262144,
@@ -33,7 +34,13 @@ The FieldFM hot path updates ``B`` gathered rows per field per step
   1.132 / 1.505, 1.173 / 1.581, 2.387 / 3.412; ``[131072, 384]``
   (eighth 16,384) at 20,480 and 22,528 lanes 1.106 / 0.813 and 1.148 /
   0.876, at 49,152, 55,296, 61,440 1.817 / 1.794, 1.947 / 2.030, 2.077
-  / 2.254.
+  / 2.254. Those coalesced prices are PR 35's and PR 37's; since PR 39
+  (:func:`_sorted_ids`) coalescing costs 7.1 ns a lane less, 17-34 ns
+  all told (parent -> change in one run): 8,192 lanes into ``[131072,
+  384]`` 0.337 -> 0.282; 16,384, 65,536 and 131,072 into ``[262144,
+  128]`` 0.412 -> 0.292, 1.577 -> 1.106 (plain 1.165) and 3.410 ->
+  2.466 (plain 2.383); 55,296 into ``[524288, 128]`` 1.343 -> 0.959
+  (plain 4.190).
 - ``"dedup"`` — in-batch segment-sum first: sort ids, sum duplicate rows'
   deltas with a fixed-shape ``segment_sum``, then ONE add per unique id
   (duplicate lanes write out-of-bounds and are dropped — XLA scatter
@@ -118,12 +125,24 @@ def stochastic_round(x: jax.Array, dtype, key: jax.Array) -> jax.Array:
     return jnp.where(finite_in, out, x.astype(jnp.bfloat16))
 
 
+def _sorted_ids(ids: jax.Array):
+    """``(sid, order)``: one id column ascending and the int32 stable
+    permutation that sorts it (``sid = ids[order]``, ``order`` what
+    ``jnp.argsort(ids)`` returns), from ONE two-operand sort.
+    ``jnp.argsort`` is that same sort with the sorted keys thrown away,
+    and ``ids[order]`` after it a gather of ``B`` scalars: 7.1 ns a lane
+    on the v5e, where the sort costs 0.5 ns an element (PERF.md §6,
+    PR 39). Stable, so float32 sums taken in ``order`` keep their order
+    of terms."""
+    lanes = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((ids, lanes), num_keys=1, is_stable=True)
+
+
 def _dedup(ids: jax.Array, delta: jax.Array):
     """Segment duplicate ids: returns (sorted ids, per-lane summed delta,
     run-start mask, sort order). ``summed[p]`` holds the TOTAL delta of
     the id at lane ``p``'s segment; only run-start lanes should write."""
-    order = jnp.argsort(ids)
-    sid = ids[order]
+    sid, order = _sorted_ids(ids)
     sdelta = delta[order]
     run_start = jnp.concatenate(
         [jnp.ones((1,), bool), sid[1:] != sid[:-1]]
@@ -149,8 +168,7 @@ def coalesce(ids: jax.Array, delta: jax.Array):
     lanes; a set-semantics write of a rule's result wants each row once,
     at the front."""
     b = ids.shape[0]
-    order = jnp.argsort(ids)
-    sid = ids[order]
+    sid, order = _sorted_ids(ids)
     run_start = jnp.concatenate(
         [jnp.ones((1,), bool), sid[1:] != sid[:-1]]
     )
@@ -363,8 +381,7 @@ def device_compact_aux(ids_col, cap: int):
     """
     b = ids_col.shape[0]
     imax = 2**31 - 1
-    order = jnp.argsort(ids_col, stable=True).astype(jnp.int32)
-    sid = ids_col[order]
+    sid, order = _sorted_ids(ids_col)
     run_start = jnp.concatenate(
         [jnp.ones((1,), bool), sid[1:] != sid[:-1]]
     )
@@ -467,7 +484,12 @@ def set_rows_at(table, useg, rows):
 # own, are the coalesced add's worst case: 8,192 of them cost it
 # 1.19-1.20x the plain add (all eight chunks written, the coalesce pure
 # cost), 55,296 of them 1.16x (4.781 against 4.125 ms into [524288,
-# 128]).
+# 128]). Since PR 39 the coalesce gathers no ids and the same rungs
+# read: into [262144, 128] 1.106 coalesced against 1.165 plain at 65,536
+# lanes (ahead by 5%) and 2.466 against 2.383 at 131,072 (behind by
+# 3.5%, FM's rung); 55,296 uniform ids 1.07x (4.391 against 4.117). The
+# constant stays where every rung under it wins and none over it is a
+# cell's: moving it is a claim on the FM cells, with their numbers.
 COALESCE_MAX_LANES = 32768
 
 # Rows of the table per lane of the update from which the plain add is
@@ -483,9 +505,10 @@ COALESCE_MAX_LANES = 32768
 # 49,152 to 65,536; into [131072, 384] 100.6 at 16,384 and 54.0 at
 # 20,480. With this many rows a lane or more the plain add pays the
 # dear price however many lanes there are, and the coalesced add, 24-25
-# ns a lane of 128 columns all told, wins 3.1-3.2x however many there
-# are. The update's ELEMENTS decide nothing: 65,536 lanes x 128 columns
-# are cheap into 262,144 rows and dear into 524,288.
+# ns a lane of 128 columns all told (17.3 since PR 39), wins 3.1-3.2x
+# (4.4x) however many there are. The update's ELEMENTS decide nothing:
+# 65,536 lanes x 128 columns are cheap into 262,144 rows and dear into
+# 524,288.
 PLAIN_DEAR_ROWS_PER_LANE = 8
 
 

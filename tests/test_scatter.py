@@ -280,6 +280,58 @@ def test_coalesced_add_is_the_plain_add(monkeypatch, name):
         got[untouched], np.asarray(table, np.float32)[untouched])
 
 
+def _argsort_then_gather(ids):
+    """What ``scatter._sorted_ids`` replaced (until PR 39), as it stood
+    in ``coalesce``, ``_dedup`` and ``device_compact_aux``."""
+    order = jnp.argsort(ids)
+    sid = ids[order]
+    return sid, order
+
+
+_SORTED_IDS_LANES, _SORTED_IDS_ROWS = 4096, 1 << 15
+_SORTED_IDS_DRAWS = {
+    # synthetic_ctr's ids: a few rows take most of the lanes.
+    "zipf": lambda rng, b: rng.zipf(1.5, size=b) % _SORTED_IDS_ROWS,
+    "all_equal": lambda rng, b: np.full(b, 7),
+    "all_distinct": lambda rng, b: rng.permutation(_SORTED_IDS_ROWS)[:b],
+    # Past the table's edge (the 2-D mesh's drop sentinel among them),
+    # negative, and in range, each many times over.
+    "out_of_range": lambda rng, b: rng.choice(
+        [-5, -1, 0, 3, _SORTED_IDS_ROWS - 1, _SORTED_IDS_ROWS,
+         _SORTED_IDS_ROWS + 9, 2**31 - 1 - 2 * b], size=b),
+}
+
+
+@pytest.mark.parametrize("fn,draw,width", [
+    (fn, draw, width) for fn in ("coalesce", "_dedup")
+    for draw in _SORTED_IDS_DRAWS for width in (17, 128, 369)
+] + [("device_compact_aux", draw, None) for draw in _SORTED_IDS_DRAWS])
+def test_one_sort_gives_what_argsort_then_gather_gave(monkeypatch, fn, draw,
+                                                      width):
+    """BIT-equal: the stable two-operand sort returns ``jnp.argsort``'s
+    ``order``, so every float32 sum keeps its order of terms."""
+    b = _SORTED_IDS_LANES
+    rng = np.random.default_rng(39)
+    ids = jnp.asarray(_SORTED_IDS_DRAWS[draw](rng, b), jnp.int32)
+    if fn == "device_compact_aux":
+        call = lambda: jax.jit(
+            lambda i: scatter.device_compact_aux(i, b // 4))(ids)
+    else:
+        delta = jnp.asarray(rng.normal(size=(b, width)) * 0.1, jnp.float32)
+        call = lambda: jax.jit(getattr(scatter, fn))(ids, delta)
+    got = jax.tree_util.tree_leaves(call())
+    sid, order = scatter._sorted_ids(ids)
+    assert order.dtype == sid.dtype == jnp.int32
+    monkeypatch.setattr(scatter, "_sorted_ids", _argsort_then_gather)
+    want = jax.tree_util.tree_leaves(call())
+    assert len(got) == len(want) >= 3
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    for g, w in zip((sid, order), _argsort_then_gather(ids)):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
 FFM_TABLE, DEEPFM_TABLE = (1 << 17, 384), (1 << 18, 128)
 DLRM_TABLE = (1 << 19, 128)
 # The mesh's form of config 3's tables: 65 columns, nothing padded on.

@@ -189,6 +189,31 @@ def test_xla_sorts_the_plain_add_where_update_lanes_says_it_is_cheap(
     assert update_lanes(over_it, table) == over_it
 
 
+# ops/scatter.coalesce sorts a field's ids ONCE, keys and permutation
+# together: ``jnp.argsort`` is that two-operand sort with the sorted
+# keys thrown away, and ``ids[order]`` after it compiled to XLA's gather
+# custom call over B scalars, 7.1 ns a lane on the v5e, 7-18% of four
+# training cells' steps (PERF.md §6, PR 39). The second sort brings the
+# unique ids to the front.
+@pytest.mark.parametrize("lanes,width", [
+    (8192, 369),        # ffm_r16.train's and ffm_r16_adagrad.train's
+    (16384, 17),        # deepfm_r16.train's
+    (55296, 128),       # dlrm_e128.train's
+])
+def test_coalesce_sorts_twice_and_gathers_no_ids(one_chip, lanes, width):
+    from fm_spark_tpu.ops import scatter
+
+    chip = SingleDeviceSharding(one_chip)
+    text = jax.jit(scatter.coalesce).lower(
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((lanes, width), jnp.float32, sharding=chip),
+    ).compile().as_text()
+    assert len(re.findall(r" sort\(", text)) == 2
+    id_gathers = re.findall(
+        rf"= s32\[{lanes}\]\S* fusion\([^\n]*kind=kCustom[^\n]*", text)
+    assert not id_gathers, id_gathers
+
+
 # The scorer's side (a holder that only reads): the tables as PredictEngine holds a
 # generation of each registry family at the sizes the benchmark serves or
 # trains, the engine's own program (``spec.predict`` under jit) compiled
