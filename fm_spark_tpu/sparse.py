@@ -1558,7 +1558,8 @@ def make_field_dcn_adagrad_body(spec, config: TrainConfig):
       ``pool/write``): column j's ``B x H_j`` lanes coalesced to its
       unique rows with each row's TOTAL gradient (``ops/scatter
       .coalesce_bags``: the rows come from the ``[B, rank]`` pullback
-      through the sort's permutation, never from a repeated block), then
+      through the sort's permutation, never from a repeated block, one
+      gather for each distinct ``(example, id)`` pair), then
       :func:`_adagrad_rows_write`, the FieldFFM AdaGrad body's chunked
       read-rule-write: the rule runs once a unique row, and a row the
       batch does not touch keeps its bits, row and accumulator.
@@ -1571,8 +1572,10 @@ def make_field_dcn_adagrad_body(spec, config: TrainConfig):
     ``opt_state`` is ``{"vw": {"n": [C tables]}, **{key: accumulators of
     params[key]}}`` for the spec's ``dense_keys`` (``optim
     .init_field_slots``). ``stats["unique_rows"]`` is the batch's unique
-    rows summed over the columns. No ``[B x H, rank]`` block and no
-    table-shaped temporary exists."""
+    rows summed over the columns, ``stats["pool_pairs"]`` the distinct
+    ``(example, id)`` pairs the write summed over them (a one-id column's
+    ``B``). No ``[B x H, rank]`` block and no table-shaped temporary
+    exists."""
     from fm_spark_tpu import optim
     from fm_spark_tpu.models.field_dcn import FieldDCNSpec
     from fm_spark_tpu.ops import scatter as scatter_lib
@@ -1616,11 +1619,15 @@ def make_field_dcn_adagrad_body(spec, config: TrainConfig):
         with jax.named_scope("dcn/backward"):
             g_dense, g_pooled = vjp(jnp.ones_like(loss))
         lr = lr_at(step_idx)
-        new_vw, new_n, unique = [], [], jnp.int32(0)
+        new_vw, new_n, unique, pairs = [], [], jnp.int32(0), jnp.int32(0)
         for j, (slots, g) in enumerate(zip(spec.column_slots, g_pooled)):
+            bag = ids[:, slots]
             with jax.named_scope("pool/coalesce"):
-                useg, g_bar, n = scatter_lib.coalesce_bags(
-                    ids[:, slots], g, tables[j].shape[0], batch)
+                coalesced = scatter_lib.coalesce_bags(
+                    bag, g, tables[j].shape[0], batch)
+            useg, g_bar, n = coalesced
+            # A coalesce that returns its three alone counts a pair a lane.
+            pairs = pairs + getattr(coalesced, "pairs", bag.size)
             chunk = min(scatter_lib.RULE_CHUNK, useg.shape[0])
             table, slot = _adagrad_rows_write(
                 tables[j], opt_state["vw"]["n"][j], useg, g_bar, n, lr,
@@ -1639,7 +1646,7 @@ def make_field_dcn_adagrad_body(spec, config: TrainConfig):
                 for i, tree in enumerate((dense, acc)))
         return ({"vw": new_vw, **new_dense},
                 {"vw": {"n": new_n}, **new_acc}, loss,
-                {"unique_rows": unique})
+                {"unique_rows": unique, "pool_pairs": pairs})
 
     return _step, init_opt_state
 

@@ -384,22 +384,47 @@ def test_the_pool_is_the_sum_of_one_id_gathers_and_its_write_is_the_shared_one()
                                       np.asarray(params0["vw"][j])[rest])
 
 
-@pytest.mark.parametrize("hot,block", [(1, 64), (4, 64), (4, 32), (5, 64)])
-def test_coalesce_bags_against_numpy(hot, block):
+@pytest.mark.parametrize("hot,block,draw", [
+    pytest.param(1, 64, "three_hot", id="1-64"),
+    pytest.param(4, 64, "three_hot", id="4-64"),
+    pytest.param(4, 32, "three_hot", id="4-32"),
+    pytest.param(5, 64, "three_hot", id="5-64"),
+    # The cell's widest bag: three ids take 60% of its 100 positions.
+    pytest.param(100, 64, "three_hot", id="100-64-three_hot"),
+    # Every position of a bag one id: one pair of 100 lanes a bag.
+    pytest.param(100, 64, "one_id", id="100-64-one_id"),
+    # No id twice in a bag: every lane a pair of its own.
+    pytest.param(5, 64, "distinct", id="5-64-distinct"),
+    # The hot ids' runs hold some 60 pairs each: blocks of 16 cut them.
+    pytest.param(20, 16, "three_hot", id="20-16-runs_across_blocks"),
+])
+def test_coalesce_bags_against_numpy(hot, block, draw):
     """Each unique id once, ascending, with the sum of its lanes' rows
     (an id twice in a bag takes its example's row twice), whatever blocks
-    the sorted lanes are summed in; sentinels and zeros behind."""
+    the sorted lanes, or pairs, are summed in; sentinels and zeros behind;
+    the count of distinct (example, id) pairs beside them."""
     rng = np.random.default_rng(hot * 100 + block)
     rows = 40
-    bag = np.where(rng.random((BATCH, hot)) < 0.6,
-                   rng.integers(0, 3, (BATCH, hot)),
-                   rng.integers(0, rows, (BATCH, hot))).astype(np.int32)
+    if draw == "three_hot":
+        bag = np.where(rng.random((BATCH, hot)) < 0.6,
+                       rng.integers(0, 3, (BATCH, hot)),
+                       rng.integers(0, rows, (BATCH, hot)))
+    elif draw == "one_id":
+        bag = np.repeat(rng.integers(0, rows, (BATCH, 1)), hot, axis=1)
+    else:
+        bag = np.stack([rng.choice(rows, hot, replace=False)
+                        for _ in range(BATCH)])
+    bag = bag.astype(np.int32)
     delta = rng.normal(size=(BATCH, K)).astype(np.float32)
-    useg, totals, n = jax.jit(
-        scatter.coalesce_bags, static_argnums=(2, 3))(
-            jnp.asarray(bag), jnp.asarray(delta), rows, block)
+    got = jax.jit(scatter.coalesce_bags, static_argnums=(2, 3))(
+        jnp.asarray(bag), jnp.asarray(delta), rows, block)
+    useg, totals, n = got
     uniq = np.unique(bag)
     assert int(n) == len(uniq)
+    pairs = sum(len(np.unique(row)) for row in bag)
+    assert int(got.pairs) == pairs
+    if draw != "three_hot":
+        assert pairs == (BATCH if draw == "one_id" else BATCH * hot)
     cap = min(BATCH * hot, rows)
     assert useg.shape == (cap,) and totals.shape == (cap + block, K)
     np.testing.assert_array_equal(np.asarray(useg[:len(uniq)]), uniq)
