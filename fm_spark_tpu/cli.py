@@ -429,6 +429,16 @@ _FIELD_CAPS = {
         multistep_single=True, multistep_sharded=True,
         sharded_score=False, sharded_deep=True,
     ),
+    # DeepFM's one-chip body with the CIN as its head: one chip, one step
+    # a call (no mesh step computes a CIN; the roll is not held by tests).
+    "FieldXDeepFMSpec": _FieldCap(
+        single_step=_single_deepfm_step, sharded_step=None,
+        carries_opt=True, table_rules=(),
+        sharded_2d=False, sharded_host_compact=False,
+        sharded_device_compact=False, sharded_multiproc=False,
+        multistep_single=False, multistep_sharded=False,
+        sharded_score=False, sharded_deep=False,
+    ),
     # One chip, one step a call: no mesh step takes a real-valued column
     # or a replicated bottom stack yet (ROADMAP Reach).
     "FieldDLRMSpec": _FieldCap(
@@ -965,10 +975,16 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                 jax.tree.leaves(params["vw"])[0].shape[-2:]))
     if getattr(spec, "dense_fields", 0):
         # A model with dense input columns: how many of the batch's slots
-        # are values, and its own count of a step's matrix products.
+        # are values.
         obs.gauge("train/dense_fields").set(spec.dense_fields)
+    if hasattr(spec, "mxu_flops_per_step"):
+        # The model's own count of a step's matrix products.
         obs.gauge("train/mxu_flops_per_step").set(
             spec.mxu_flops_per_step(tconfig.batch_size))
+    if getattr(spec, "cin_layers", ()):
+        # A CIN: the elements of the Hadamard-product blocks a step builds.
+        obs.gauge("train/cin_outer_elems_per_step").set(
+            spec.cin_outer_elems_per_step(tconfig.batch_size))
     if getattr(spec, "hots", ()):
         # A model whose columns are bags: the ids an example carries and
         # the lanes a step's pooled gather and write move.
@@ -1188,10 +1204,12 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
                             or i == tconfig.num_steps - 1):
                         with obs.interval("train/loss_fetch", step=i):
                             loss_now = fetch_loss(loss)
-                            # What a slot-carrying step counted (its
-                            # batch's unique rows): device scalars of
-                            # the step just fenced, no second wait.
-                            counted = {k: int(v) for st in stats
+                            # What the step reported (a slot-carrying
+                            # step's unique rows, a CIN's pooled maps):
+                            # device values of the step just fenced, no
+                            # second wait.
+                            counted = {k: np.asarray(v).tolist()
+                                       for st in stats
                                        for k, v in st.items()}
                         logger.log(i + 1, samples=since, loss=loss_now,
                                    **counted)
@@ -2244,7 +2262,7 @@ def _serve_opt_example(spec, cfg):
     canonical = spec.init(jax.random.key(cfg.seed))
     if isinstance(spec, FieldDeepFMSpec):
         return make_optimizer(cfg.train_config()).init(
-            {"w0": canonical["w0"], "mlp": canonical["mlp"]}
+            {key: canonical[key] for key in spec.dense_keys}
         )
     return make_optimizer(cfg.train_config()).init(canonical)
 

@@ -29,6 +29,11 @@ Registry names map to the BASELINE table (SURVEY.md §6):
   (mlcommons/training, recommendation_v2/torchrec_dlrm): 214 ids an
   example sum-pooled into 26 rows of 128, a three-layer low-rank cross
   network, AdaGrad on every parameter.
+- ``criteo_xdeepfm_cin200`` — xDeepFM (Lian et al., KDD 2018,
+  arXiv:1803.05170, eq. 6-9) at its paper's Criteo settings: 39 fields
+  of 10-wide embeddings, a Compressed Interaction Network of three layers
+  of 200 feature maps beside a 400-400 DNN and the linear term; Adam on
+  the dense leaves, SGD on the rows.
 """
 
 from __future__ import annotations
@@ -49,7 +54,7 @@ class RunConfig:
     description: str
     model: str                      # 'fm' | 'field_fm' | 'ffm' | 'deepfm' |
                                     # 'field_ffm' | 'field_deepfm' | 'field_dlrm'
-                                    # | 'field_dcn'
+                                    # | 'field_dcn' | 'field_xdeepfm'
     dataset: str                    # 'movielens' | 'criteo' | 'avazu' | 'synthetic'
     rank: int
     num_fields: int                 # fixed nnz slot count
@@ -75,6 +80,9 @@ class RunConfig:
     hots: tuple = ()
     cross_layers: int = 0
     cross_rank: int = 0
+    # 'field_xdeepfm' alone: the feature maps of each CIN layer
+    # (``mlp_dims`` is its DNN's hidden widths).
+    cin_layers: tuple = ()
     # Training recipe (TrainConfig subset).
     num_steps: int = 1000
     batch_size: int = 8192
@@ -103,7 +111,7 @@ class RunConfig:
         every CLI id-conversion gate (a missed conversion means XLA
         silently clamps out-of-range ids into the table edge)."""
         return self.model in ("field_fm", "field_ffm", "field_deepfm",
-                              "field_dlrm", "field_dcn")
+                              "field_dlrm", "field_dcn", "field_xdeepfm")
 
     @property
     def num_features(self) -> int:
@@ -155,6 +163,15 @@ class RunConfig:
             return models.FieldDeepFMSpec(
                 **common, num_fields=self.num_fields, bucket=self.bucket,
                 mlp_dims=self.mlp_dims,
+            )
+        if self.model == "field_xdeepfm":
+            if num_features is not None and num_features != self.num_features:
+                raise ValueError(
+                    "field_xdeepfm shapes are fixed by num_fields*bucket"
+                )
+            return models.FieldXDeepFMSpec(
+                **common, num_fields=self.num_fields, bucket=self.bucket,
+                mlp_dims=self.mlp_dims, cin_layers=self.cin_layers,
             )
         if self.model == "field_dlrm":
             if num_features is not None and num_features != self.num_features:
@@ -318,6 +335,28 @@ CONFIGS = {
             batch_size=65536, learning_rate=0.004, lr_schedule="constant",
             optimizer="adagrad", adagrad_init_accumulator=0.0,
             reg_factors=0.0,
+        ),
+        RunConfig(
+            name="criteo_xdeepfm_cin200",
+            description="xDeepFM (Lian et al., KDD 2018, arXiv:1803.05170:"
+            " the CIN layer of eq. 6, sum pooling of eq. 7, the output of"
+            " eq. 9) at its paper's Criteo settings (section 4.1.3): 39"
+            " fields (13 numeric discretized, 26 categorical; one active"
+            " feature each) of 10-wide embeddings, a Compressed Interaction"
+            " Network of three layers of 200 feature maps (identity"
+            " activation, every layer pooled to the output) beside a"
+            " 390-400-400 ReLU DNN and the linear term, log loss, L2 1e-4,"
+            " batch 4,096. Each field hashed into 2^18 buckets (the paper"
+            " keeps Criteo's own vocabulary); Adam at 1e-3 on the dense"
+            " leaves and plain SGD at 1e-3 on the rows (the paper: Adam on"
+            " everything). One chip: the fused DeepFM body with the CIN as"
+            " its head. Measured at these defaults by the benchmark's cell"
+            " xdeepfm_cin200.train (PERF.md).",
+            model="field_xdeepfm", dataset="criteo", rank=10, num_fields=39,
+            bucket=1 << 18, cin_layers=(200, 200, 200), mlp_dims=(400, 400),
+            strategy="field_sparse", num_steps=1_000_000, batch_size=4096,
+            learning_rate=1e-3, lr_schedule="constant", optimizer="adam",
+            reg_factors=1e-4,
         ),
     ]
 }

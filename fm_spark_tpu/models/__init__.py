@@ -16,6 +16,7 @@ from fm_spark_tpu.models.field_deepfm import FieldDeepFMSpec  # noqa: F401
 from fm_spark_tpu.models.field_dlrm import FieldDLRMSpec  # noqa: F401
 from fm_spark_tpu.models.field_fm import FieldFMSpec  # noqa: F401
 from fm_spark_tpu.models.field_ffm import FieldFFMSpec  # noqa: F401
+from fm_spark_tpu.models.field_xdeepfm import FieldXDeepFMSpec  # noqa: F401
 from fm_spark_tpu.models.io import save_model, load_model  # noqa: F401
 from fm_spark_tpu.models.libfm_io import save_libfm, load_libfm  # noqa: F401
 

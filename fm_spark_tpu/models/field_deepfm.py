@@ -62,6 +62,9 @@ class FieldDeepFMSpec(base.ModelSpec):
     # The parameters that are not tables: what the loop's optax state
     # covers (cli._fit_field_sparse).
     dense_keys = ("w0", "mlp")
+    # The FM second-order term beside the head (the fused body's analytic
+    # share of the rows' gradient); a head that replaces it says False.
+    fm_interaction = True
 
     @property
     def table_width(self) -> int:
@@ -122,6 +125,12 @@ class FieldDeepFMSpec(base.ModelSpec):
                 h = jax.nn.relu(h)
         return h[:, 0]
 
+    def head_scores(self, dense: dict, h: jax.Array) -> jax.Array:
+        """The head over ``h = concat(xv) [B, F*rank]`` → ``[B]``;
+        ``dense`` holds the ``dense_keys`` (the bias is not the head's).
+        The fused body's forward and its ``jax.vjp`` come through here."""
+        return self.deep_scores(dense["mlp"], h)
+
     def scores(self, params: dict, ids: jax.Array, vals: jax.Array) -> jax.Array:
         if ids.shape[1] != self.num_fields:
             raise ValueError(
@@ -133,9 +142,11 @@ class FieldDeepFMSpec(base.ModelSpec):
         rows = self.gather_rows(params, ids)
         k = self.rank
         xvs = [r[:, :k] * vals_c[:, f : f + 1] for f, r in enumerate(rows)]
-        s = sum(xvs)
-        sum_sq = sum(jnp.sum(x * x, axis=1) for x in xvs)
-        score = 0.5 * (jnp.sum(s * s, axis=1) - sum_sq)
+        score = 0.0
+        if self.fm_interaction:
+            s = sum(xvs)
+            sum_sq = sum(jnp.sum(x * x, axis=1) for x in xvs)
+            score = 0.5 * (jnp.sum(s * s, axis=1) - sum_sq)
         if self.use_linear:
             score = score + sum(
                 r[:, k] * vals_c[:, f] for f, r in enumerate(rows)
@@ -143,7 +154,8 @@ class FieldDeepFMSpec(base.ModelSpec):
         if self.use_bias:
             score = score + params["w0"].astype(cd)
         h = jnp.concatenate(xvs, axis=1)                  # [B, F*k]
-        return score + self.deep_scores(params["mlp"], h)
+        return score + self.head_scores(
+            {key: params[key] for key in self.dense_keys}, h)
 
     def predict(self, params: dict, ids: jax.Array, vals: jax.Array) -> jax.Array:
         return base.predict_from_scores(self, self.scores(params, ids, vals))

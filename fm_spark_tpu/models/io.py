@@ -42,6 +42,7 @@ def _register_families():
     from fm_spark_tpu.models.field_dlrm import FieldDLRMSpec
     from fm_spark_tpu.models.field_fm import FieldFMSpec
     from fm_spark_tpu.models.field_ffm import FieldFFMSpec
+    from fm_spark_tpu.models.field_xdeepfm import FieldXDeepFMSpec
 
     _FAMILIES.update(
         FMSpec=FMSpec,
@@ -52,6 +53,7 @@ def _register_families():
         FieldDLRMSpec=FieldDLRMSpec,
         FieldFMSpec=FieldFMSpec,
         FieldFFMSpec=FieldFFMSpec,
+        FieldXDeepFMSpec=FieldXDeepFMSpec,
     )
 
 
@@ -121,7 +123,7 @@ def load_model(path: str):
         spec_kwargs["min_target"] = -math.inf
     if spec_kwargs.get("max_target") is None:
         spec_kwargs["max_target"] = math.inf
-    for key in ("mlp_dims", "bottom_mlp_dims", "hots"):
+    for key in ("mlp_dims", "bottom_mlp_dims", "hots", "cin_layers"):
         if key in spec_kwargs:
             spec_kwargs[key] = tuple(spec_kwargs[key])
     # Every FieldFM model saved before the field went carries

@@ -1067,6 +1067,8 @@ def lower_field_sharded_step(spec, config: TrainConfig, mesh,
         )
     n_feat = mesh.shape["feat"]
     is_deepfm = isinstance(spec, FieldDeepFMSpec)
+    if is_deepfm and not spec.fm_interaction:
+        raise ValueError(f"{type(spec).__name__} has no field-sharded step")
     stack = (stack_field_deepfm_params if is_deepfm
              else stack_field_params)
     stacked_struct = jax.eval_shape(

@@ -32,6 +32,9 @@ def test_registry_has_all_five_baseline_configs():
         # DCN-v2 over multi-hot columns at MLPerf's sizes since v3.0
         # (PR 40): bags of ids pooled, AdaGrad on every parameter.
         "criteo1tb_dcnv2_multihot",
+        # xDeepFM at its paper's Criteo settings: a Compressed
+        # Interaction Network as the head of DeepFM's fused body.
+        "criteo_xdeepfm_cin200",
     }
     adagrad = configs_lib.CONFIGS["avazu_ffm_r16_adagrad"]
     sgd = configs_lib.CONFIGS["avazu_ffm_r16"]
