@@ -18,11 +18,15 @@ in column ``rank``, DeepFM's layout):
 5. the logit (eq. 9): ``w0 + sum_f x_f E_f[c_f][rank] + w_dnn . a_L +
    w_cin . p+``.
 
-The CIN is laid out for the MXU with the pair (example, d) on the rows:
-``X^k`` is ``[B * D, H_k]``, layer k's Hadamard products are one ``[B *
-D, H_{k-1} * m]`` block (``cin/outer``) and the compression one product
-of it with ``W^k`` as ``[H_k, H_{k-1} * m]`` (``cin/compress``); the
-pooling and the output weight are ``cin/pool``.
+The CIN is laid out for the MXU with the pair (d, example) on the lanes:
+``X^k`` is ``[H_k, D * B]``, lane ``d * B + b``; layer k's Hadamard
+products are one ``[m * H_{k-1}, D * B]`` block, row ``j * H_{k-1} + i``
+(``cin/outer``), and the compression one product of ``W^k``, read as
+``[H_k, m * H_{k-1}]``, with it (``cin/compress``). Every lane is
+dense, so the forward hands each block to its product as it was built
+(where ``H_{k-1}`` is a multiple of 8); the kernels are stored ``[H_k,
+H_{k-1}, m]`` all the same. The pooling, a sum over a leading axis, and
+the output weight are ``cin/pool``.
 
 The training split is DeepFM's (``sparse.make_field_deepfm_sparse_body``
 takes the spec's head): the rows by the sparse SGD write, ``{w0, cin,
@@ -137,19 +141,30 @@ class FieldXDeepFMSpec(ReluStacks, FieldDeepFMSpec):
         ``[B, sum_k H_k]``."""
         cd = self.cdtype
         rows, m = x0.shape
-        x, pooled = x0, []
+        d, batch = self.rank, rows // self.rank
+        with jax.named_scope("cin/outer"):
+            # [m, D * B], lane d * B + b: d-major, so pooling is a sum
+            # over a leading axis.
+            x0t = x0.reshape(batch, d, m).transpose(2, 1, 0).reshape(
+                m, d * batch)
+        x, pooled = x0t, []
         for w in kernels:
             h, h_prev, _ = w.shape
             with jax.named_scope("cin/outer"):
-                z = (x[:, :, None] * x0[:, None, :]).reshape(rows, h_prev * m)
+                # Row j * H_{k-1} + i holds X^0[j] * X^{k-1}[i]: a free
+                # merge of leading axes wherever H_{k-1} is a multiple
+                # of 8. A broadcast multiply, not an einsum, which would
+                # lower to one more dot_general.
+                z = (x0t[:, None, :] * x[None, :, :]).reshape(
+                    m * h_prev, d * batch)
             with jax.named_scope("cin/compress"):
                 x = jax.lax.dot_general(
-                    z, w.reshape(h, h_prev * m).astype(cd),
-                    (((1,), (1,)), ((), ())), precision=self._precision)
+                    jnp.swapaxes(w, 1, 2).reshape(h, m * h_prev).astype(cd),
+                    z, (((1,), (0,)), ((), ())), precision=self._precision)
             with jax.named_scope("cin/pool"):
-                pooled.append(x.reshape(-1, self.rank, h).sum(axis=1))
+                pooled.append(x.reshape(h, d, batch).sum(axis=1))
         with jax.named_scope("cin/pool"):
-            return jnp.concatenate(pooled, axis=1)
+            return jnp.concatenate(pooled, axis=0).T
 
     def cin_input(self, h: jax.Array) -> jax.Array:
         """``X^0`` as :meth:`cin` takes it, ``[B * D, m]``, from ``h =

@@ -6,7 +6,8 @@ fields, 64 buckets, rank 4, a CIN of 6, 5 and 4 feature maps, a DNN of 8
 and 8, batch 32.
 
 - the spec's CIN against eq. 6 and 7 written out pair by pair in float64
-  loops (not the same einsum), and the reference's likewise;
+  loops (not the same einsum), and the reference's likewise; its
+  gradients, kernels and ``X^0``, against the reference's;
 - ``scores`` against the reference's;
 - the fused body against the reference at 2 and 8 steps: losses, every
   touched row, every dense leaf and both of Adam's moments;
@@ -161,6 +162,43 @@ def test_the_swapped_first_kernel_is_the_same_function():
                                atol=1e-7)
     assert np.abs(wrong[:, 6:] - right[:, 6:]).max() > 0.1 * np.abs(
         right[:, 6:]).max()
+
+
+def test_the_cins_gradients_are_the_references():
+    """``jax.grad`` of ``spec.cin``, which holds its maps ``[H_k, D B]``,
+    against the reference's ``cin_pooled`` on ``[B, m, D]``, for the
+    kernels and ``X^0`` alike. ``H_0 = m = 5`` is no multiple of 8 and
+    the layers' widths differ (6, 4, 3), so a block flattened ``(i, j)``
+    where the kernel reads ``(j, i)``, or a layer pooled over the wrong
+    axis, cannot pass."""
+    cfg = dataclasses.replace(REGISTERED, **{**TINY, "rank": 3,
+                                             "cin_layers": (6, 4, 3)})
+    spec = cfg.spec()
+    m, d = spec.num_fields, spec.rank
+    kernels = spec.init(jax.random.key(12))["cin"]["layers"]
+    assert [w.shape for w in kernels] == [(6, 5, 5), (4, 6, 5), (3, 4, 5)]
+    k_h, k_c = jax.random.split(jax.random.key(13))
+    h = jax.random.normal(k_h, (BATCH, m * d), jnp.float32)
+    weight = jax.random.normal(k_c, (BATCH, sum(spec.cin_layers)),
+                               jnp.float32)
+
+    def program(ks, h):
+        return jnp.sum(weight * spec.cin(ks, spec.cin_input(h)))
+
+    def reference(ks, h):
+        return jnp.sum(weight * xdeepfm.cin_pooled(
+            ks, h.reshape(BATCH, m, d)))
+
+    got = jax.grad(program, argnums=(0, 1))(kernels, h)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(reference, argnums=(0, 1))(kernels, h)
+    for what, g, w in zip(("W^1", "W^2", "W^3", "X^0"), (*got[0], got[1]),
+                          (*want[0], want[1])):
+        assert g.shape == w.shape, what
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, what
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=1e-5 * scale, err_msg=what)
 
 
 def test_scores_are_the_references():

@@ -315,6 +315,70 @@ def test_coalesce_sorts_twice_and_gathers_no_ids(one_chip, lanes, width):
     assert not id_gathers, id_gathers
 
 
+# The xDeepFM CIN holds its maps with the pair (d, example) on the lanes
+# (models/field_xdeepfm.py): a Hadamard block laid out ``[B D, H, m]``
+# puts its m fields on the lanes, and the compiler copies every such
+# block into the product's layout, forward and for the kernel's gradient
+# (six of 1.28 GB a step at xdeepfm_cin200.train's sizes, PERF.md §6).
+# The CIN's forward and pullback compiled alone, at 13 fields of 8, 256
+# examples and 16 maps a layer.
+def _cin_program(chip, cin, m=13, d=8, batch=256, maps=(16, 16, 16)):
+    """``(spec, batch, lowered, compiled)``: ``cin(spec, kernels, x0)``
+    and its pullback, for ``chip``."""
+    from fm_spark_tpu import configs
+
+    spec = dataclasses.replace(
+        configs.CONFIGS["criteo_xdeepfm_cin200"], num_fields=m, rank=d,
+        bucket=64, cin_layers=maps).spec()
+    chip = SingleDeviceSharding(chip)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=chip)
+    kernels = [sds((h, h_prev, m)) for h_prev, h in
+               zip(spec.cin_dims[:-1], spec.cin_dims[1:])]
+
+    def forward_and_pullback(kernels, x0, g):
+        p, pullback = jax.vjp(functools.partial(cin, spec), kernels, x0)
+        return p, pullback(g)
+
+    lowered = jax.jit(forward_and_pullback).lower(
+        kernels, sds((batch * d, m)), sds((batch, sum(maps))))
+    return spec, batch, lowered, lowered.compile()
+
+
+def _block_relays(spec, batch, compiled):
+    """``copy`` / ``transpose`` ops as large as the CIN's smallest
+    Hadamard block, ``B D m min(H_k)``."""
+    least = batch * spec.rank * spec.num_fields * min(spec.cin_dims)
+    return [shape for shape in re.findall(
+        r"= f32\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(",
+        compiled.as_text())
+        if np.prod([int(n) for n in shape.split(",")]) >= least]
+
+
+def test_the_cin_relays_at_most_one_block_a_layer(one_chip):
+    from fm_spark_tpu.models.field_xdeepfm import FieldXDeepFMSpec
+
+    spec, batch, lowered, compiled = _cin_program(one_chip,
+                                                  FieldXDeepFMSpec.cin)
+    layers = len(spec.cin_layers)
+    stated = re.findall(r"dot_general.*precision = \[(\w+), (\w+)\]",
+                        lowered.as_text())
+    assert len(stated) == 3 * layers
+    assert set(stated) == {("HIGHEST", "HIGHEST")}
+    assert len(_block_relays(spec, batch, compiled)) <= layers
+    # The guard bites: the same CIN with its maps [B D, H_k] (the
+    # check's own copy, xdeep_faults._cin_fn) relays more.
+    import importlib.util
+
+    found = importlib.util.spec_from_file_location(
+        "xdeep_faults", os.path.join(ROOT, "benchmark", "tests",
+                                     "xdeep_faults.py"))
+    faults = importlib.util.module_from_spec(found)
+    found.loader.exec_module(faults)
+    spec, batch, _, before = _cin_program(one_chip, faults._cin_fn())
+    assert len(_block_relays(spec, batch, before)) > layers
+
+
 # The scorer's side (a holder that only reads): the tables as PredictEngine holds a
 # generation of each registry family at the sizes the benchmark serves or
 # trains, the engine's own program (``spec.predict`` under jit) compiled
