@@ -982,9 +982,13 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         obs.gauge("train/mxu_flops_per_step").set(
             spec.mxu_flops_per_step(tconfig.batch_size))
     if getattr(spec, "cin_layers", ()):
-        # A CIN: the elements of the Hadamard-product blocks a step builds.
+        # A CIN: the Hadamard products a step forms, and the operations
+        # of the products it runs (under mxu_flops_per_step's count where
+        # the last layer pools first).
         obs.gauge("train/cin_outer_elems_per_step").set(
             spec.cin_outer_elems_per_step(tconfig.batch_size))
+        obs.gauge("train/cin_product_flops_per_step").set(
+            spec.cin_product_flops_per_step(tconfig.batch_size))
     if getattr(spec, "hots", ()):
         # A model whose columns are bags: the ids an example carries and
         # the lanes a step's pooled gather and write move.

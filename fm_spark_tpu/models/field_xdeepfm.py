@@ -26,7 +26,11 @@ products are one ``[m * H_{k-1}, D * B]`` block, row ``j * H_{k-1} + i``
 dense, so the forward hands each block to its product as it was built
 (where ``H_{k-1}`` is a multiple of 8); the kernels are stored ``[H_k,
 H_{k-1}, m]`` all the same. The pooling, a sum over a leading axis, and
-the output weight are ``cin/pool``.
+the output weight are ``cin/pool``. The last layer's maps feed no next
+layer and are only pooled, and the compression is linear, so that layer
+pools first: ``p^K = W^K . sum_d (X^0[j, d] * X^{K-1}[i, d])``, a
+``[m * H_{K-1}, B]`` block, a D-th of the others', and a D-th of their
+products, forward and back.
 
 The training split is DeepFM's (``sparse.make_field_deepfm_sparse_body``
 takes the spec's head): the rows by the sparse SGD write, ``{w0, cin,
@@ -84,20 +88,35 @@ class FieldXDeepFMSpec(ReluStacks, FieldDeepFMSpec):
         return (self.num_fields * self.rank, *self.mlp_dims)
 
     def cin_outer_elems_per_step(self, batch: int) -> int:
-        """Elements of the Hadamard-product blocks a step's forward builds
-        (gauge ``train/cin_outer_elems_per_step``): ``B D sum_k H_{k-1}
-        m``."""
+        """Hadamard products a step's forward forms (gauge
+        ``train/cin_outer_elems_per_step``): ``B D sum_k H_{k-1} m``. The
+        last layer's are summed over the ``D`` columns as they are formed,
+        and never held as a block."""
         m, dims = self.num_fields, self.cin_dims
         return batch * self.rank * sum(h * m for h in dims[:-1])
 
+    def cin_product_flops_per_step(self, batch: int) -> int:
+        """Operations of the CIN's matrix products one training step runs
+        (gauge ``train/cin_product_flops_per_step``): as
+        :meth:`mxu_flops_per_step` counts them, but the last layer, which
+        pools first, meets ``B`` columns where the others meet ``D B``:
+        ``6 B (D sum_{k<K} H_{k-1} m H_k + H_{K-1} m H_K)``."""
+        m, dims = self.num_fields, self.cin_dims
+        per_column = [a * m * b for a, b in zip(dims[:-1], dims[1:])]
+        return 6 * batch * (self.rank * sum(per_column[:-1])
+                            + per_column[-1])
+
     def mxu_flops_per_step(self, batch: int) -> int:
         """Operations of one training step's matrix products over
-        ``batch`` examples (gauge ``train/mxu_flops_per_step``): per
-        kernel element a multiply-add forward, one for the input's
-        gradient and one for the kernel's, 2 operations each. A CIN
-        kernel element meets every one of the ``D`` columns; the DNN's
-        input and the CIN's are the rows, so every product has its input
-        gradient. The Hadamard products and the pooling are elementwise."""
+        ``batch`` examples as the model states them (gauge
+        ``train/mxu_flops_per_step``, which the benchmark holds to its own
+        count): per kernel element a multiply-add forward, one for the
+        input's gradient and one for the kernel's, 2 operations each. A
+        CIN kernel element meets every one of the ``D`` columns, eq. 6 as
+        written, although the last layer runs a D-th of that
+        (:meth:`cin_product_flops_per_step`); the DNN's input and the
+        CIN's are the rows, so every product has its input gradient. The
+        Hadamard products and the pooling are elementwise."""
         m, dims = self.num_fields, self.cin_dims
         cin = self.rank * sum(a * m * b for a, b in zip(dims[:-1], dims[1:]))
         dnn = sum(a * b for a, b in zip(self.dnn_dims[:-1],
@@ -148,21 +167,30 @@ class FieldXDeepFMSpec(ReluStacks, FieldDeepFMSpec):
             x0t = x0.reshape(batch, d, m).transpose(2, 1, 0).reshape(
                 m, d * batch)
         x, pooled = x0t, []
-        for w in kernels:
+        for k, w in enumerate(kernels, start=1):
             h, h_prev, _ = w.shape
+            # The last layer's maps are only pooled: pooling before the
+            # (linear) compression leaves B columns where there were D * B.
+            last = k == len(kernels)
             with jax.named_scope("cin/outer"):
-                # Row j * H_{k-1} + i holds X^0[j] * X^{k-1}[i]: a free
+                # Row j * H_{k-1} + i holds X^0[j] * X^{k-1}[i] (the
+                # last layer's summed over the D columns): a free
                 # merge of leading axes wherever H_{k-1} is a multiple
                 # of 8. A broadcast multiply, not an einsum, which would
                 # lower to one more dot_general.
-                z = (x0t[:, None, :] * x[None, :, :]).reshape(
-                    m * h_prev, d * batch)
+                if last:
+                    z = (x0t.reshape(m, 1, d, batch)
+                         * x.reshape(1, h_prev, d, batch)).sum(axis=2)
+                else:
+                    z = x0t[:, None, :] * x[None, :, :]
+                z = z.reshape(m * h_prev, -1)
             with jax.named_scope("cin/compress"):
                 x = jax.lax.dot_general(
                     jnp.swapaxes(w, 1, 2).reshape(h, m * h_prev).astype(cd),
                     z, (((1,), (0,)), ((), ())), precision=self._precision)
             with jax.named_scope("cin/pool"):
-                pooled.append(x.reshape(h, d, batch).sum(axis=1))
+                pooled.append(x if last
+                              else x.reshape(h, d, batch).sum(axis=1))
         with jax.named_scope("cin/pool"):
             return jnp.concatenate(pooled, axis=0).T
 

@@ -7,7 +7,9 @@ and 8, batch 32.
 
 - the spec's CIN against eq. 6 and 7 written out pair by pair in float64
   loops (not the same einsum), and the reference's likewise; its
-  gradients, kernels and ``X^0``, against the reference's;
+  gradients, kernels and ``X^0``, against the reference's; its last layer,
+  which pools before it compresses, against every layer built, compressed
+  and pooled in turn, forward and back;
 - ``scores`` against the reference's;
 - the fused body against the reference at 2 and 8 steps: losses, every
   touched row, every dense leaf and both of Adam's moments;
@@ -17,7 +19,8 @@ and 8, batch 32.
   the faults it exists to catch (``benchmark/tests/xdeep_faults.py``) and
   the reference one precision lower each FAIL it;
 - the capability row's and the body's refusals;
-- ``mxu_flops_per_step`` and the gauges against ``benchmark/cin_flops.py``;
+- ``mxu_flops_per_step`` and the gauges against ``benchmark/cin_flops.py``,
+  and ``cin_product_flops_per_step`` against its formula;
 - ``cli eval`` / ``cli predict`` and ``PredictEngine`` return
   ``spec.predict``.
 """
@@ -201,6 +204,71 @@ def test_the_cins_gradients_are_the_references():
                                    atol=1e-5 * scale, err_msg=what)
 
 
+def cin_layer_by_layer(self, kernels, x0):
+    """``FieldXDeepFMSpec.cin`` with every layer, the last too, built as a
+    ``[m H_{k-1}, D B]`` Hadamard block, compressed to ``X^k`` ``[H_k, D
+    B]`` and then pooled over ``D``: the form the last layer's pooling
+    first must equal (``tests/test_table_layout.py`` compiles it too)."""
+    rows, m = x0.shape
+    d, batch = self.rank, rows // self.rank
+    x0t = x0.reshape(batch, d, m).transpose(2, 1, 0).reshape(m, d * batch)
+    x, pooled = x0t, []
+    for w in kernels:
+        h, h_prev, _ = w.shape
+        z = (x0t[:, None, :] * x[None, :, :]).reshape(m * h_prev, d * batch)
+        x = jax.lax.dot_general(
+            jnp.swapaxes(w, 1, 2).reshape(h, m * h_prev), z,
+            (((1,), (0,)), ((), ())), precision=self._precision)
+        pooled.append(x.reshape(h, d, batch).sum(axis=1))
+    return jnp.concatenate(pooled, axis=0).T
+
+
+# K = 1 (the last layer is the first, X^{K-1} = X^0), 2 and 3 with
+# H_{K-1} = 5, 6, 5, no multiple of 8, and one of 16.
+@pytest.mark.parametrize("cin_layers", [(6,), (6, 5), (6, 5, 4),
+                                        (16, 16, 3)])
+def test_the_last_layer_pools_first_and_is_the_same_function(cin_layers):
+    """``p^K = W^K . sum_d (X^0 * X^{K-1})``, the sum moved inside the
+    linear map: the same ``p+`` and the same gradients, kernels and
+    ``X^0``, as the layer built at ``D B`` columns and pooled after, to a
+    millionth of each one's largest value; and the gauge of the products
+    the step runs is its formula, under the model's count."""
+    cfg = dataclasses.replace(REGISTERED, **{**TINY,
+                                             "cin_layers": cin_layers})
+    spec = cfg.spec()
+    m, d, dims = spec.num_fields, spec.rank, spec.cin_dims
+    kernels = spec.init(jax.random.key(14))["cin"]["layers"]
+    k_h, k_c = jax.random.split(jax.random.key(15))
+    h = jax.random.normal(k_h, (BATCH, m * d), jnp.float32)
+    weight = jax.random.normal(k_c, (BATCH, sum(cin_layers)), jnp.float32)
+
+    def loss(cin):
+        return lambda ks, h: jnp.sum(weight * cin(spec, ks,
+                                                  spec.cin_input(h)))
+
+    got_p = spec.cin(kernels, spec.cin_input(h))
+    want_p = cin_layer_by_layer(spec, kernels, spec.cin_input(h))
+    got = jax.grad(loss(FieldXDeepFMSpec.cin), argnums=(0, 1))(kernels, h)
+    want = jax.grad(loss(cin_layer_by_layer), argnums=(0, 1))(kernels, h)
+    ends = np.cumsum((0, *cin_layers))
+    parts = [(f"p{k + 1}", got_p[:, lo:hi], want_p[:, lo:hi])
+             for k, (lo, hi) in enumerate(zip(ends[:-1], ends[1:]))]
+    parts += [(f"W^{k + 1}", g, w)
+              for k, (g, w) in enumerate(zip(got[0], want[0]))]
+    for what, g, w in [*parts, ("X^0", got[1], want[1])]:
+        assert g.shape == w.shape, what
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, what
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=1e-6 * scale, err_msg=what)
+
+    per_column = [a * m * b for a, b in zip(dims[:-1], dims[1:])]
+    runs = spec.cin_product_flops_per_step(BATCH)
+    assert runs == 6 * BATCH * (d * sum(per_column[:-1]) + per_column[-1])
+    assert runs < cin_flops.step_matmul_flops(BATCH, m, d, cin_layers)
+    assert runs < spec.mxu_flops_per_step(BATCH)
+
+
 def test_scores_are_the_references():
     spec, _ = spec_and_config()
     params = scaled(spec.init(jax.random.key(3)))
@@ -256,6 +324,11 @@ def test_registry_shapes_are_the_published_ones():
     assert spec.mxu_flops_per_step(batch) == cin + 6 * batch * 317_000
     assert spec.cin_outer_elems_per_step(batch) == cin_flops.outer_elems(
         batch, 39, 10, spec.cin_layers) == 701_276_160
+    # The last layer pools first: its 200 x 39 x 200 kernel meets B
+    # columns, not D B.
+    assert spec.cin_product_flops_per_step(batch) == (
+        6 * batch * (10 * (39 * 39 * 200 + 200 * 39 * 200)
+                     + 200 * 39 * 200)) == 496_484_352_000
 
 
 def test_init_is_mirrored_by_the_reference():
@@ -452,6 +525,8 @@ def test_cli_train_matches_the_reference_from_its_own_init(tiny):
         + 6 * BATCH * (F * K * 8 + 8 * 8 + 8 + sum(CIN)))
     assert obs.gauge("train/cin_outer_elems_per_step").value == (
         cin_flops.outer_elems(BATCH, F, K, CIN))
+    assert obs.gauge("train/cin_product_flops_per_step").value == (
+        6 * BATCH * (K * (F * F * 6 + 6 * F * 5) + 5 * F * 4))
 
 
 def test_the_lowered_step_states_its_scopes_and_its_precision():

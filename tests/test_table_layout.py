@@ -379,6 +379,47 @@ def test_the_cin_relays_at_most_one_block_a_layer(one_chip):
     assert len(_block_relays(spec, batch, before)) > layers
 
 
+def _held_blocks(compiled, least):
+    """Shapes of the arrays of at least ``least`` elements the compiled
+    program holds: results of its entry computation, views and arguments
+    left out."""
+    entry = compiled.as_text().split("\nENTRY", 1)[1]
+    return [shape for shape, op in re.findall(
+        r"= f32\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(", entry)
+        if op not in ("bitcast", "parameter")
+        and np.prod([int(n) for n in shape.split(",")]) >= least]
+
+
+# The CIN's last layer pools over D before its compression
+# (models/field_xdeepfm.py), so its block is never formed at D B columns,
+# forward or back; at the cell's sizes only a layer below holds one
+# (layer 2's gradient). At _cin_program's sizes the compiler holds no
+# layer's block, and the form that builds the last layer's holds one.
+@pytest.mark.parametrize("maps", [(16,), (16, 16), (16, 16, 16)])
+def test_the_cins_last_layer_holds_no_block(one_chip, maps):
+    from fm_spark_tpu.models.field_xdeepfm import FieldXDeepFMSpec
+
+    spec, batch, lowered, compiled = _cin_program(
+        one_chip, FieldXDeepFMSpec.cin, maps=maps)
+    stated = re.findall(r"dot_general.*precision = \[(\w+), (\w+)\]",
+                        lowered.as_text())
+    assert len(stated) == 3 * len(maps)
+    assert set(stated) == {("HIGHEST", "HIGHEST")}
+    # The last layer's block at D B columns, B D m H_{K-1} elements.
+    last = batch * spec.rank * spec.num_fields * spec.cin_dims[-2]
+    assert not _held_blocks(compiled, last)
+    # The guard bites: every layer built at D B columns, the last too.
+    import importlib.util
+
+    found = importlib.util.spec_from_file_location(
+        "xdeepfm_tests", os.path.join(ROOT, "tests", "test_field_xdeepfm.py"))
+    tests = importlib.util.module_from_spec(found)
+    found.loader.exec_module(tests)
+    _, _, _, before = _cin_program(one_chip, tests.cin_layer_by_layer,
+                                   maps=maps)
+    assert _held_blocks(before, last)
+
+
 # The scorer's side (a holder that only reads): the tables as PredictEngine holds a
 # generation of each registry family at the sizes the benchmark serves or
 # trains, the engine's own program (``spec.predict`` under jit) compiled
