@@ -33,8 +33,8 @@ import time
 
 import numpy as np
 
+from fm_spark_tpu import parallel, sparse
 from fm_spark_tpu.cli_levers import (
-    _LEVERS,
     _add_lever_args,
     _lever_overrides,
     check_levers_any,
@@ -326,65 +326,12 @@ def _periodic_evaluator(spec, tconfig, eval_source, logger, evaluate=None):
     return maybe_eval
 
 
-def _single_fm_step(spec, tconfig):
-    from fm_spark_tpu.sparse import make_field_sparse_sgd_step
-
-    return make_field_sparse_sgd_step(spec, tconfig)
-
-
-def _single_ffm_step(spec, tconfig):
-    from fm_spark_tpu import sparse
-
-    if tconfig.optimizer == "adagrad":
-        return sparse.make_field_ffm_adagrad_step(spec, tconfig)
-    return sparse.make_field_ffm_sparse_sgd_step(spec, tconfig)
-
-
-def _single_deepfm_step(spec, tconfig):
-    from fm_spark_tpu.sparse import make_field_deepfm_sparse_step
-
-    return make_field_deepfm_sparse_step(spec, tconfig)
-
-
-def _single_dlrm_step(spec, tconfig):
-    from fm_spark_tpu.sparse import make_field_dlrm_sparse_step
-
-    return make_field_dlrm_sparse_step(spec, tconfig)
-
-
-def _single_dcn_step(spec, tconfig):
-    from fm_spark_tpu.sparse import make_field_dcn_adagrad_step
-
-    return make_field_dcn_adagrad_step(spec, tconfig)
-
-
-def _sharded_fm_step(spec, tconfig, mesh):
-    from fm_spark_tpu.parallel import make_field_sharded_sgd_step
-
-    return make_field_sharded_sgd_step(spec, tconfig, mesh)
-
-
-def _sharded_ffm_step(spec, tconfig, mesh):
-    from fm_spark_tpu.parallel import make_field_ffm_sharded_step
-
-    return make_field_ffm_sharded_step(spec, tconfig, mesh)
-
-
-def _sharded_deepfm_step(spec, tconfig, mesh):
-    from fm_spark_tpu.parallel import make_field_deepfm_sharded_step
-
-    return make_field_deepfm_sharded_step(spec, tconfig, mesh)
-
-
 @dataclasses.dataclass(frozen=True)
 class _FieldCap:
-    """One row of the field_sparse CAPABILITY TABLE: which step builder
-    serves a model family in each layout, and which levers that
-    family's steps actually consume. Every guard in
-    :func:`_fit_field_sparse` reads THIS row instead of open-coding a
-    type/flag test — adding a capability (or a family) means editing
-    one row, and an unsupported request hard-fails with the row as the
-    single source of truth (the project's no-silent-fallback rule)."""
+    """One row of the field_sparse CAPABILITY TABLE: which step factory
+    serves a model family in each layout (the levers it serves are its
+    own declaration, ``sparse.serves_of``), and what the loop around it
+    can do. An unsupported request hard-fails (no silent fallback)."""
 
     single_step: callable            # (spec, tconfig) -> step
     sharded_step: callable | None    # (spec, tconfig, mesh) -> step
@@ -394,71 +341,57 @@ class _FieldCap:
     table_rules: tuple               # table optimizers besides 'sgd' whose
                                      # slot tables ride the one-chip step
     sharded_2d: bool                 # 2-D (feat, row) mesh (--row-shards)
-    sharded_host_compact: bool       # host-built compact aux when sharded
-    sharded_device_compact: bool     # in-step compact aux when sharded
     sharded_multiproc: bool          # multi-process pseudo-cluster / pods
     multistep_single: bool           # --steps-per-call fori roll (1 chip)
     multistep_sharded: bool          # --steps-per-call on the sharded step
-    sharded_score: bool              # --score-sharded example-sharded dscores
-    sharded_deep: bool               # --deep-sharded example-sharded head
 
 
 _FIELD_CAPS = {
     "FieldFMSpec": _FieldCap(
-        single_step=_single_fm_step, sharded_step=_sharded_fm_step,
+        single_step=sparse.make_field_sparse_sgd_step,
+        sharded_step=parallel.make_field_sharded_sgd_step,
         carries_opt=False, table_rules=(),
-        sharded_2d=True, sharded_host_compact=True,
-        sharded_device_compact=True, sharded_multiproc=True,
+        sharded_2d=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
-        sharded_score=True, sharded_deep=False,
     ),
     "FieldFFMSpec": _FieldCap(
-        single_step=_single_ffm_step, sharded_step=_sharded_ffm_step,
+        single_step=sparse.make_field_ffm_step,
+        sharded_step=parallel.make_field_ffm_sharded_step,
         carries_opt=False, table_rules=("adagrad",),
-        sharded_2d=True, sharded_host_compact=True,
-        sharded_device_compact=True, sharded_multiproc=True,
+        sharded_2d=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
-        sharded_score=False, sharded_deep=False,
     ),
     "FieldDeepFMSpec": _FieldCap(
-        single_step=_single_deepfm_step,
-        sharded_step=_sharded_deepfm_step,
+        single_step=sparse.make_field_deepfm_sparse_step,
+        sharded_step=parallel.make_field_deepfm_sharded_step,
         carries_opt=True, table_rules=(),
-        sharded_2d=True, sharded_host_compact=False,
-        sharded_device_compact=True, sharded_multiproc=True,
+        sharded_2d=True, sharded_multiproc=True,
         multistep_single=True, multistep_sharded=True,
-        sharded_score=False, sharded_deep=True,
     ),
     # DeepFM's one-chip body with the CIN as its head: one chip, one step
     # a call (no mesh step computes a CIN; the roll is not held by tests).
     "FieldXDeepFMSpec": _FieldCap(
-        single_step=_single_deepfm_step, sharded_step=None,
+        single_step=sparse.make_field_deepfm_sparse_step, sharded_step=None,
         carries_opt=True, table_rules=(),
-        sharded_2d=False, sharded_host_compact=False,
-        sharded_device_compact=False, sharded_multiproc=False,
+        sharded_2d=False, sharded_multiproc=False,
         multistep_single=False, multistep_sharded=False,
-        sharded_score=False, sharded_deep=False,
     ),
     # One chip, one step a call: no mesh step takes a real-valued column
     # or a replicated bottom stack yet (ROADMAP Reach).
     "FieldDLRMSpec": _FieldCap(
-        single_step=_single_dlrm_step, sharded_step=None,
+        single_step=sparse.make_field_dlrm_sparse_step, sharded_step=None,
         carries_opt=True, table_rules=(),
-        sharded_2d=False, sharded_host_compact=False,
-        sharded_device_compact=False, sharded_multiproc=False,
+        sharded_2d=False, sharded_multiproc=False,
         multistep_single=False, multistep_sharded=False,
-        sharded_score=False, sharded_deep=False,
     ),
     # One chip, one step a call, AdaGrad on every parameter: table slots
     # AND the dense leaves' accumulators ride the step (no SGD body
     # pools a bag; no mesh step, no roll).
     "FieldDCNSpec": _FieldCap(
-        single_step=_single_dcn_step, sharded_step=None,
+        single_step=sparse.make_field_dcn_adagrad_step, sharded_step=None,
         carries_opt=True, table_rules=("adagrad",),
-        sharded_2d=False, sharded_host_compact=False,
-        sharded_device_compact=False, sharded_multiproc=False,
+        sharded_2d=False, sharded_multiproc=False,
         multistep_single=False, multistep_sharded=False,
-        sharded_score=False, sharded_deep=False,
     ),
 }
 
@@ -539,8 +472,9 @@ def _make_overflow_guard(tconfig):
 def _validate_field_caps(spec, tconfig, cap, n, pc, sharded,
                          row_shards, steps_per_call, ckpt_sharded):
     """The field_sparse guard block: every request a family's steps
-    cannot serve hard-fails against the capability row (_FIELD_CAPS) —
-    never a silent fallback. Returns ``(compact_sharded, multi)``.
+    cannot serve hard-fails against the capability row (_FIELD_CAPS) and
+    the declaration of the factory the loop builds — never a silent
+    fallback. Returns ``(compact_sharded, multi)``.
     Split out of _fit_field_sparse (VERDICT r3: the loop function was
     accreting validation, placement, resume, and the loop)."""
     if row_shards < 1:
@@ -563,47 +497,6 @@ def _validate_field_caps(spec, tconfig, cap, n, pc, sharded,
             f"(found {n} device(s)); the default canonical layout "
             "already serves single-chip runs"
         )
-    compact_sharded = (
-        tconfig.host_dedup and tconfig.compact_cap > 0 and sharded
-    )
-    if compact_sharded and not cap.sharded_host_compact:
-        raise SystemExit(
-            f"host-built --compact-cap is not supported by the sharded "
-            f"{type(spec).__name__} step"
-        )
-    if compact_sharded and (row_shards > 1 or pc > 1):
-        # The HOST-built aux needs some host to hold every field's full
-        # global column (excludes multi-process) and raw global ids
-        # (excludes 2-D row ownership). The device-built aux has neither
-        # constraint.
-        raise SystemExit(
-            "host-built --compact-cap on multiple chips requires a 1-D "
-            "field mesh (no --row-shards) and a single process; add "
-            "--compact-device to build the aux in-step, which composes "
-            "with both"
-        )
-    if (tconfig.compact_device and sharded
-            and not cap.sharded_device_compact):
-        raise SystemExit(
-            f"--compact-device on {n} devices is not supported by the "
-            f"sharded {type(spec).__name__} step"
-        )
-    if tconfig.host_dedup and sharded and not compact_sharded:
-        # The sharded steps consume only the COMPACT aux format; every
-        # other multi-device host-dedup request would silently train
-        # without the fast path — hard-fail instead.
-        raise SystemExit(
-            f"--host-dedup on {n} devices requires --compact-cap "
-            "(or drop --host-dedup / run on 1 chip)"
-        )
-    # Registry-driven per-lever guards (one validate per _Lever row).
-    ctx = dict(spec=spec, cap=cap, n=n, pc=pc, sharded=sharded,
-               row_shards=row_shards)
-    for lv in _LEVERS:
-        if lv.validate is not None:
-            msg = lv.validate(tconfig, ctx)
-            if msg:
-                raise SystemExit(msg)
     if pc > 1 and not cap.sharded_multiproc:
         raise SystemExit(
             f"multi-process training is not supported for "
@@ -624,6 +517,9 @@ def _validate_field_caps(spec, tconfig, cap, n, pc, sharded,
             "the field-sharded steps and the multistep roll implement "
             "plain SGD only"
         )
+    compact_sharded = (
+        tconfig.host_dedup and tconfig.compact_cap > 0 and sharded
+    )
     if multi:
         if sharded:
             # The SHARDED roll (round 4): the fori rides inside the
@@ -648,6 +544,27 @@ def _validate_field_caps(spec, tconfig, cap, n, pc, sharded,
                 "--steps-per-call > 1 is not supported for "
                 f"{type(spec).__name__} on a single device"
             )
+    # The levers: what the factory the loop builds declares it serves.
+    factory = cap.sharded_step if sharded else cap.single_step
+    layout = "field-sharded" if sharded else "single-chip"
+    try:
+        sparse.refuse_unserved(
+            tconfig, sparse.serves_of(factory, spec, tconfig),
+            f"the {layout} {type(spec).__name__} step (found {n} "
+            "device(s))", spec.loss)
+    except ValueError as refused:
+        raise SystemExit(str(refused)) from None
+    if compact_sharded and (row_shards > 1 or pc > 1):
+        # The HOST-built aux needs some host to hold every field's full
+        # global column (excludes multi-process) and raw global ids
+        # (excludes 2-D row ownership). The device-built aux has neither
+        # constraint.
+        raise SystemExit(
+            "host-built --compact-cap on multiple chips requires a 1-D "
+            "field mesh (no --row-shards) and a single process; add "
+            "--compact-device to build the aux in-step, which composes "
+            "with both"
+        )
     if sharded:
         if tconfig.batch_size % n:
             raise SystemExit(
@@ -755,7 +672,6 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
             )
     else:
         from fm_spark_tpu.models import rows
-        from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
         built = cap.single_step(spec, tconfig)
         step = built if is_deepfm or slots else adapt(built)
@@ -765,7 +681,7 @@ def _place_field_state(spec, tconfig, cap, canonical, opt0, n, pc,
         # here, each canonical table let go as its held one arrives.
         # What leaves the loop — evals, checkpoints, the returned model
         # — is canonical again (the identity where nothing changed form).
-        params, shapes, _ = rows.hold(canonical, FUSED_TABLE_KEYS,
+        params, shapes, _ = rows.hold(canonical, sparse.FUSED_TABLE_KEYS,
                                       writes=True, consume=True)
         to_canonical = lambda p, release=False: rows.canonical(
             p, shapes, release
@@ -796,9 +712,8 @@ def _hold_slots(slots0):
 
     from fm_spark_tpu import obs
     from fm_spark_tpu.models import rows
-    from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
-    slots, shapes, held = rows.hold(slots0, FUSED_TABLE_KEYS,
+    slots, shapes, held = rows.hold(slots0, sparse.FUSED_TABLE_KEYS,
                                     writes=True, consume=True)
     obs.gauge("train/slot_table_bytes").set(held["resident_table_bytes"])
     to_host = lambda o: jax.tree.map(
@@ -885,9 +800,7 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
         # but never in its provenance (ISSUE 8): surface which fused
         # Pallas family serves this run — or why the XLA path runs
         # instead — before any compile happens.
-        from fm_spark_tpu.sparse import fused_embed_plan
-
-        family, reason = fused_embed_plan(spec, tconfig)
+        family, reason = sparse.fused_embed_plan(spec, tconfig)
         print(
             (f"fused-embed: serving kernel family {family!r}"
              if family else
@@ -916,11 +829,10 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
             import functools
 
             from fm_spark_tpu import optim
-            from fm_spark_tpu.sparse import FUSED_TABLE_KEYS
 
             init_slots = functools.partial(
                 optim.init_field_slots, tconfig.optimizer,
-                keys=FUSED_TABLE_KEYS,
+                keys=sparse.FUSED_TABLE_KEYS,
                 init_accumulator=tconfig.adagrad_init_accumulator,
                 dense_keys=spec.dense_keys if is_deepfm else ())
             opt0 = jax.eval_shape(init_slots, canonical)
@@ -1113,15 +1025,11 @@ def _fit_field_sparse(spec, tconfig, batches, logger, checkpointer=None,
             else:
                 prep = lambda sb: shard_field_batch_stacked(sb, mesh)
         elif is_deepfm:
-            from fm_spark_tpu.sparse import make_field_deepfm_multistep
-
-            mstep = make_field_deepfm_multistep(spec, tconfig,
-                                                steps_per_call)
+            mstep = sparse.make_field_deepfm_multistep(spec, tconfig,
+                                                       steps_per_call)
         else:
-            from fm_spark_tpu.sparse import make_field_sparse_multistep
-
-            mstep = make_field_sparse_multistep(spec, tconfig,
-                                                steps_per_call)
+            mstep = sparse.make_field_sparse_multistep(spec, tconfig,
+                                                       steps_per_call)
         # Stacking runs in the prefetch producer thread. `total` bounds
         # source consumption so the tail stack pads instead of reading
         # batches that would never train (exact-resume cursor).

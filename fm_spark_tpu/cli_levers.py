@@ -1,18 +1,18 @@
 """The CLI lever registry (VERDICT r4 #7): one row per TrainConfig lever.
 
 Parser setup (:func:`add_lever_args`), train-config threading
-(:func:`lever_overrides`), and the per-lever capability guards
-(:data:`LEVERS` rows' ``validate``, run by cli._validate_field_caps)
-all iterate ONE table — adding lever N+1 to the CLI is one ``_Lever``
-row here (+ its TrainConfig field and step support); cli.py itself does
-not change. Multi-flag interplay (the compact-aux family) stays in
-cli._validate_field_caps' dedicated block: those guards couple several
-flags at once and would not be clearer as rows.
+(:func:`lever_overrides`) and the strategy-independent guards
+(``validate_any``) iterate ONE table: adding lever N+1 to the CLI is one
+``_Lever`` row here (+ its TrainConfig field and step support). Which
+step serves a lever is each factory's declaration (``sparse.declares``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+
+from fm_spark_tpu.sparse import overflow_without_cap
+
 
 @dataclasses.dataclass(frozen=True)
 class _Lever:
@@ -21,16 +21,8 @@ class _Lever:
     kind: str            # 'flag' | 'int' | 'choice'
     help: str
     choices: tuple = ()
-    # Optional guard: (tconfig, ctx) -> error message | None, where ctx
-    # has spec/cap/n/pc/sharded/row_shards. Raised as SystemExit by
-    # _validate_field_caps (field_sparse strategy only — the other
-    # strategies' step FACTORIES carry the per-flag rejects).
-    validate: object = None
-    # Optional strategy-INDEPENDENT guard: (tconfig) -> error message |
-    # None, run by cli.cmd_train for EVERY strategy right after the
-    # TrainConfig is built — for flags whose misuse the non-field
-    # factories cannot see (e.g. a policy flag that is a silent no-op
-    # without its companion cap).
+    # Optional guard (tconfig) -> error message | None, run by
+    # cli.cmd_train for EVERY strategy once the TrainConfig is built.
     validate_any: object = None
 
 
@@ -45,59 +37,6 @@ def check_levers_any(tconfig):
     return None
 
 
-def _v_overflow_needs_cap(tc):
-    if tc.compact_overflow != "error" and tc.compact_cap <= 0:
-        # The fused factories hard-fail this (sparse._check_host_dedup);
-        # the dense strategies never consult compact flags, so without
-        # this guard the CLI would accept a policy that does nothing
-        # (no-silent-fallback rule, ADVICE r3/r4).
-        return (
-            f"--compact-overflow {tc.compact_overflow} has no effect "
-            "without --compact-cap"
-        )
-
-
-def _v_collective_dtype(tc, ctx):
-    if tc.collective_dtype != "float32" and not ctx["sharded"]:
-        return (
-            f"--collective-dtype {tc.collective_dtype} is a wire-"
-            f"precision knob for multi-device runs (found {ctx['n']} "
-            "device(s))"
-        )
-
-
-def _v_score_sharded(tc, ctx):
-    if tc.score_sharded and not (ctx["sharded"]
-                                 and ctx["cap"].sharded_score):
-        return (
-            f"--score-sharded needs multiple devices and a model family "
-            f"with the example-sharded score path "
-            f"(found {ctx['n']} device(s), {type(ctx['spec']).__name__})"
-        )
-
-
-def _v_deep_sharded(tc, ctx):
-    if tc.deep_sharded and not (ctx["sharded"]
-                                and ctx["cap"].sharded_deep):
-        return (
-            f"--deep-sharded needs multiple devices and a model family "
-            f"with an example-sharded deep head "
-            f"(found {ctx['n']} device(s), {type(ctx['spec']).__name__})"
-        )
-
-
-def _v_sel_blocked(tc, ctx):
-    from fm_spark_tpu.models.field_ffm import FieldFFMSpec
-
-    if tc.sel_blocked and (ctx["sharded"]
-                           or type(ctx["spec"]) is not FieldFFMSpec):
-        return (
-            f"--sel-blocked is the single-chip FieldFFM body's lever "
-            f"(it blocks the [B, F, F, k] sel tensor; found "
-            f"{ctx['n']} device(s), {type(ctx['spec']).__name__})"
-        )
-
-
 def _v_hot_rows_need_tier(tc):
     if tc.hot_rows > 0 and tc.embed_tier == "off":
         # Capacity without the lever would be a silent no-op: the
@@ -109,29 +48,6 @@ def _v_hot_rows_need_tier(tc):
             f"--hot-rows {tc.hot_rows} must be a multiple of "
             f"--embed-bucket-rows {tc.embed_bucket_rows} (the hot tier "
             "is managed in whole buckets)"
-        )
-
-
-def _v_embed_tier(tc, ctx):
-    # 'require' off the single-attachment strategy dies later in the
-    # factories with a less situated message (the residency protocol is
-    # single-attachment); 'auto' is always legal — queryable fallback.
-    if tc.embed_tier == "require" and ctx["sharded"]:
-        return (
-            f"--embed-tier require is served by the SINGLE-CHIP tiered "
-            f"flat-FM trainer (found {ctx['n']} devices); use 'auto' "
-            "for fallback-to-in-HBM semantics on a sharded run"
-        )
-
-
-def _v_fused_embed(tc, ctx):
-    # 'require' on a sharded run dies later in the factory with a less
-    # situated message; 'auto' is always legal (queryable XLA fallback).
-    if tc.fused_embed == "require" and ctx["sharded"]:
-        return (
-            f"--fused-embed require is served by the SINGLE-CHIP fused "
-            f"Pallas bodies (found {ctx['n']} devices); use 'auto' for "
-            "fallback-to-XLA semantics on a sharded run"
         )
 
 
@@ -160,26 +76,23 @@ _LEVERS = (
            "ids behave as absent features), split (host: split the "
            "batch until every field fits — exact, more steps)",
            choices=("error", "drop", "split"),
-           validate_any=_v_overflow_needs_cap),
+           validate_any=overflow_without_cap),
     _Lever("--collective-dtype", "collective_dtype", "choice",
            "wire dtype for the sharded steps' activation collectives "
            "(score psums, DeepFM h, FFM sel all_to_all) — bfloat16 "
            "halves the dominant ICI bytes (parallel/projection.py); "
            "multi-device field_sparse only",
-           choices=("float32", "bfloat16"),
-           validate=_v_collective_dtype),
+           choices=("float32", "bfloat16")),
     _Lever("--score-sharded", "score_sharded", "flag",
            "shard the [B,k] score/dscores math over examples on the "
            "sharded FM step (exact; one tiny [B] dscores all_gather) — "
            "removes the only non-shardable batch-proportional term "
-           "(parallel/projection.py)",
-           validate=_v_score_sharded),
+           "(parallel/projection.py)"),
     _Lever("--deep-sharded", "deep_sharded", "flag",
            "example-shard the DeepFM deep head on the sharded step "
            "(h all_gather -> one all_to_all, MLP on B/n examples per "
            "chip, [B] deep-score gather) — ~n x fewer h wire bytes "
-           "and the deep FLOPs divide by n (parallel/projection.py)",
-           validate=_v_deep_sharded),
+           "and the deep FLOPs divide by n (parallel/projection.py)"),
     _Lever("--gfull-fused", "gfull_fused", "flag",
            "build each field's backward g_full buffer directly as "
            "ds·x·(s1 − m·xv_full) instead of concat([g_v, g_l]) — "
@@ -194,8 +107,7 @@ _LEVERS = (
            "tensors (config 4's dominant HBM traffic, PERF.md) are "
            "never materialized; largest live buffer drops to [B, F, "
            "k]. Single-chip FieldFFM body; staged for on-chip pricing "
-           "in the bench --model ffm sweep",
-           validate=_v_sel_blocked),
+           "in the bench --model ffm sweep"),
     _Lever("--segtotal-pallas", "segtotal_pallas", "flag",
            "compute the compact update's segment sums with the Pallas "
            "sorted-run kernel (streaming read, VMEM-resident [cap, w] "
@@ -212,8 +124,7 @@ _LEVERS = (
            "stderr notice when none does; 'require' hard-fails "
            "instead of falling back (bench legs that must price the "
            "kernel)",
-           choices=("off", "auto", "require"),
-           validate=_v_fused_embed),
+           choices=("off", "auto", "require")),
     _Lever("--embed-tier", "embed_tier", "choice",
            "tiered embedding store (fm_spark_tpu/embed): hot-bucket "
            "HBM cache of --hot-rows rows over host cold storage, "
@@ -223,8 +134,7 @@ _LEVERS = (
            "(flat FM, single strategy, sgd/ftrl/adagrad) and falls "
            "back with a stderr notice (embed.tier_plan's reason); "
            "'require' hard-fails instead of falling back",
-           choices=("off", "auto", "require"),
-           validate=_v_embed_tier),
+           choices=("off", "auto", "require")),
     _Lever("--hot-rows", "hot_rows", "int",
            "HBM hot-tier capacity in rows for --embed-tier (multiple "
            "of --embed-bucket-rows; must cover one batch's touched-"

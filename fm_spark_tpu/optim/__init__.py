@@ -310,22 +310,18 @@ def make_sparse_adaptive_step(spec, config, *, beta: float = 1.0,
             "(of the fused field families FieldFFM takes 'adagrad', "
             "sparse.make_field_ffm_adagrad_body; the others write by "
             "plain SGD)")
-    if config.optimizer not in ADAPTIVE_OPTIMIZERS:
-        raise ValueError(
-            f"make_sparse_adaptive_step handles {ADAPTIVE_OPTIMIZERS}; "
-            f"config.optimizer={config.optimizer!r}")
+    from fm_spark_tpu.sparse import Serves, refuse_unserved
+
+    # TieredTrainer builds THIS step over its hot-tier window with
+    # embed_tier neutralized to 'off'; a bare 'require' here means the
+    # caller skipped the tiered trainer.
+    refuse_unserved(config, Serves(optimizers=ADAPTIVE_OPTIMIZERS),
+                    "the flat-table sparse adaptive step", spec.loss)
     if config.reg_bias or config.reg_linear or config.reg_factors:
         raise ValueError(
             "the adaptive step rejects the reg_* triple: FTRL carries "
             "its own proximal l1/l2 and AdaGrad pairs with explicit "
             "weight decay, not lazy L2 — configure l1/l2 here instead")
-    from fm_spark_tpu.sparse import _reject_embed_tier_require
-
-    # TieredTrainer builds THIS step over its hot-tier window with
-    # embed_tier neutralized to 'off'; a bare 'require' here means the
-    # caller skipped the tiered trainer.
-    _reject_embed_tier_require(config, "the bare sparse adaptive step "
-                               "(drive it through embed.TieredTrainer)")
     per_example_loss = losses_lib.loss_fn(spec.loss)
     cd = spec.cdtype
     alpha = float(config.learning_rate)

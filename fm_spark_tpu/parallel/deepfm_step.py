@@ -18,6 +18,20 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fm_spark_tpu.ops import losses as losses_lib
 from fm_spark_tpu.parallel import field_step as _fs
+from fm_spark_tpu.sparse import (
+    OPTAX_OPTIMIZERS,
+    Serves,
+    _apply_field_updates,
+    _collective_dtype,
+    _compact_apply_all,
+    _fold_overflow,
+    _gather_fn,
+    _gfull_grads,
+    _lr_at,
+    _sr_base_key,
+    declares,
+    refuse_unserved,
+)
 from fm_spark_tpu.train import TrainConfig
 
 # ---------------------------------------------------------------- DeepFM
@@ -55,6 +69,14 @@ def shard_field_deepfm_params(stacked: dict, mesh) -> dict:
     return out
 
 
+# The in-step compact aux composes here as in the FM step (the deep head
+# touches activations, not tables); the host-built aux does not ride it.
+FIELD_DEEPFM_MESH = Serves(
+    frozenset({"compact_device", "segtotal_pallas", "use_pallas",
+               "gfull_fused", "collective_dtype", "deep_sharded"}),
+    optimizers=OPTAX_OPTIMIZERS)
+
+
 def _make_deepfm_sharded_one_step(spec, config: TrainConfig, mesh):
     """Field-sharded fused DeepFM step builder (1-D ``feat`` or 2-D
     ``(feat, row)`` mesh) — returns ``(apply_one, init_opt_state)``,
@@ -80,45 +102,18 @@ def _make_deepfm_sharded_one_step(spec, config: TrainConfig, mesh):
     import optax
 
     from fm_spark_tpu.models.field_deepfm import FieldDeepFMSpec
-    from fm_spark_tpu.sparse import (
-        _apply_field_updates,
-        _check_host_dedup,
-        _collective_dtype,
-        _compact_apply_all,
-        _fold_overflow,
-        _gather_fn,
-        _lr_at,
-        _reject_host_aux,
-        _sr_base_key,
-    )
     from fm_spark_tpu.train import make_optimizer
 
     if type(spec) is not FieldDeepFMSpec:
         raise ValueError("expected a FieldDeepFMSpec")
-    from fm_spark_tpu.sparse import _reject_score_sharded
-
-    _reject_score_sharded(config, "the field-sharded DeepFM step")
-    from fm_spark_tpu.sparse import _reject_sel_blocked
-
-    _reject_sel_blocked(config, "the field-sharded DeepFM step")
-    from fm_spark_tpu.sparse import _reject_fused_embed_require
-
-    _reject_fused_embed_require(config, "the field-sharded DeepFM step")
+    refuse_unserved(config, FIELD_DEEPFM_MESH,
+                    "the field-sharded DeepFM step", spec.loss)
     if set(mesh.axis_names) not in ({"feat"}, {"feat", "row"}):
         raise ValueError(
             "field-sharded DeepFM runs on a ('feat',) or ('feat', 'row') "
             "mesh (use make_field_mesh)"
         )
-    # Device-built compact aux composes here exactly as in the FM step
-    # (the deep head touches activations, not tables); the HOST aux does
-    # not ride this step — reject it rather than silently ignore.
-    _check_host_dedup(config, spec.loss)
     device_cap = config.compact_cap if config.compact_device else 0
-    if config.host_dedup:
-        # _check_host_dedup guarantees any compact_cap without
-        # compact_device implies host_dedup, so this one test covers
-        # every host-aux request.
-        _reject_host_aux(config, "the field-sharded DeepFM step")
     g = _fs._mesh_geometry(spec, mesh)
     wire = _collective_dtype(config)
     per_example_loss = losses_lib.loss_fn(spec.loss)
@@ -260,8 +255,6 @@ def _make_deepfm_sharded_one_step(spec, config: TrainConfig, mesh):
         lr = lr_at(step_idx)
         touched = weights > 0
         if config.gfull_fused:
-            from fm_spark_tpu.sparse import _gfull_grads
-
             gh_pad = jnp.pad(
                 g_h_loc.reshape(-1, f_local, k),
                 ((0, 0), (0, 0), (0, 1)))
@@ -352,6 +345,7 @@ def _make_deepfm_sharded_one_step(spec, config: TrainConfig, mesh):
     return apply_one, init_opt_state
 
 
+@declares(FIELD_DEEPFM_MESH)
 def make_field_deepfm_sharded_step(spec, config: TrainConfig, mesh):
     """Jitted field-sharded DeepFM step (see
     :func:`_make_deepfm_sharded_one_step`); params + opt donated;

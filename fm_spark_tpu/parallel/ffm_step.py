@@ -18,6 +18,23 @@ from jax.sharding import PartitionSpec as P
 
 from fm_spark_tpu.ops import losses as losses_lib
 from fm_spark_tpu.parallel import field_step as _fs
+from fm_spark_tpu.sparse import (
+    COMPACT_LEVERS,
+    SGD_TABLES,
+    Serves,
+    _apply_field_updates,
+    _collective_dtype,
+    _compact_apply_all,
+    _compact_gather_all,
+    _device_compact_aux_all,
+    _fold_overflow,
+    _gather_all,
+    _lr_at,
+    _psum_wire,
+    _sr_base_key,
+    declares,
+    refuse_unserved,
+)
 from fm_spark_tpu.train import TrainConfig
 
 # ---------------------------------------------------------------- FFM
@@ -57,13 +74,6 @@ def _ffm_field_forward(spec, g, vw, w0, ids, vals, labels, weights,
     chip's [B, f_local, F_pad, k] owner/transposed blocks for the
     analytic backward.
     """
-    from fm_spark_tpu.sparse import (
-        _compact_gather_all,
-        _device_compact_aux_all,
-        _gather_all,
-        _psum_wire,
-    )
-
     cd = spec.cdtype
     k = spec.rank
     F = spec.num_fields
@@ -181,62 +191,31 @@ def _ffm_field_forward(spec, g, vw, w0, ids, vals, labels, weights,
             labels, weights)
 
 
+FIELD_FFM_MESH = Serves(
+    COMPACT_LEVERS - {"host_dedup"} | {"collective_dtype"}, remedy=SGD_TABLES)
+
+
 def _make_ffm_local_step(spec, config: TrainConfig, mesh):
     """Build the FFM sharded LOCAL step + layout facts (the FFM
     counterpart of :func:`_make_field_local_step`; shared by the
     per-step wrapper and the multi-step roll). Returns ``(local_step,
     host_compact)``."""
     from fm_spark_tpu.models.field_ffm import FieldFFMSpec
-    from fm_spark_tpu.sparse import (
-        _apply_field_updates,
-        _check_host_dedup,
-        _collective_dtype,
-        _compact_apply_all,
-        _fold_overflow,
-        _lr_at,
-        _reject_host_aux,
-        _sr_base_key,
-    )
 
     if type(spec) is not FieldFFMSpec:
         raise ValueError("expected a FieldFFMSpec")
-    if config.optimizer != "sgd":
-        from fm_spark_tpu.sparse import _SGD_ONLY
-
-        raise ValueError(_SGD_ONLY.format(what="the field-sharded FieldFFM step",
-                                          got=config.optimizer))
-    from fm_spark_tpu.sparse import _reject_gfull
-
-    _reject_gfull(config, "the field-sharded FFM step")
-    from fm_spark_tpu.sparse import _reject_sel_blocked
-
-    _reject_sel_blocked(config, "the field-sharded FFM step (single-chip "
-                        "body lever; the sharded sel exchange has its own "
-                        "blocking)")
-    from fm_spark_tpu.sparse import (
-        _reject_deep_sharded,
-        _reject_score_sharded,
-    )
-
-    _reject_score_sharded(config, "the field-sharded FFM step")
-    _reject_deep_sharded(config, "the field-sharded FFM step")
-    from fm_spark_tpu.sparse import _reject_fused_embed_require
-
-    _reject_fused_embed_require(config, "the field-sharded FFM step")
+    refuse_unserved(config, FIELD_FFM_MESH, "the field-sharded FFM step",
+                    spec.loss)
     wire = _collective_dtype(config)
     if set(mesh.axis_names) not in ({"feat"}, {"feat", "row"}):
         raise ValueError(
             "field-sharded FFM runs on a ('feat',) or ('feat', 'row') "
             "mesh (use make_field_mesh)"
         )
-    if config.use_pallas:
-        raise ValueError("use_pallas is a single-chip experiment")
     g = _fs._mesh_geometry(spec, mesh)
     compact = config.compact_cap > 0
     device_cap = config.compact_cap if config.compact_device else 0
     host_compact = compact and not config.compact_device
-    # Unconditional, like the single-chip factories (see the FM body).
-    _check_host_dedup(config, spec.loss)
     if host_compact and g["two_d"]:
         # Same structural limit as the FM step: a host aux built from
         # raw global ids cannot express row ownership.
@@ -245,8 +224,6 @@ def _make_ffm_local_step(spec, config: TrainConfig, mesh):
             "1-D ('feat',) mesh; use compact_device=True for 2-D "
             "(feat, row) meshes"
         )
-    if not compact and config.host_dedup:
-        _reject_host_aux(config, "the field-sharded FFM step (non-compact)")
 
     per_example_loss = losses_lib.loss_fn(spec.loss)
     cd = spec.cdtype
@@ -335,6 +312,7 @@ def _make_ffm_local_step(spec, config: TrainConfig, mesh):
     return local_step, host_compact
 
 
+@declares(FIELD_FFM_MESH)
 def make_field_ffm_sharded_body(spec, config: TrainConfig, mesh):
     """Unjitted field-sharded fused FFM step — config 4's multi-chip
     layout, on a 1-D ``(feat,)`` or 2-D ``(feat, row)`` mesh (row
@@ -366,6 +344,7 @@ def make_field_ffm_sharded_body(spec, config: TrainConfig, mesh):
     )
 
 
+@declares(FIELD_FFM_MESH)
 def make_field_ffm_sharded_step(spec, config: TrainConfig, mesh):
     """Jitted field-sharded fused FFM step; params donated."""
     return jax.jit(

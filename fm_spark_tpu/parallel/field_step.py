@@ -59,6 +59,25 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fm_spark_tpu.ops import losses as losses_lib
+from fm_spark_tpu.sparse import (
+    COMPACT_LEVERS,
+    SGD_TABLES,
+    Serves,
+    _apply_field_updates,
+    _collective_dtype,
+    _compact_apply_all,
+    _compact_gather_all,
+    _device_compact_aux_all,
+    _fold_overflow,
+    _gather_all,
+    _gather_fn,
+    _gfull_grads,
+    _lr_at,
+    _psum_wire,
+    _sr_base_key,
+    declares,
+    refuse_unserved,
+)
 from fm_spark_tpu.train import TrainConfig
 
 
@@ -361,12 +380,6 @@ def _field_forward(spec, g, gat, vw, w0, ids, vals, labels, weights,
     and the backward can then build each g_full without a per-field
     concat (TrainConfig.gfull_fused).
     """
-    from fm_spark_tpu.sparse import (
-        _compact_gather_all,
-        _device_compact_aux_all,
-        _gather_all,
-    )
-
     cd = spec.cdtype
     k = spec.rank
     if caux is None:
@@ -455,8 +468,6 @@ def _field_forward(spec, g, gat, vw, w0, ids, vals, labels, weights,
     # ``psum_dtype`` (TrainConfig.collective_dtype) halves the wire
     # bytes of this — the projection model's dominant ICI term — at
     # bf16 wire precision; results come back in compute dtype.
-    from fm_spark_tpu.sparse import _psum_wire
-
     s = _psum_wire(s_p, g["score_axes"], psum_dtype, cd)
     sq = _psum_wire(sq_p, g["score_axes"], psum_dtype, cd)
     lin = _psum_wire(lin_p, g["score_axes"], psum_dtype, cd)
@@ -493,6 +504,14 @@ def _field_forward(spec, g, gat, vw, w0, ids, vals, labels, weights,
                 weights=weights, aux=aux, ovf=ovf)
 
 
+# The mesh steps take the COMPACT host aux alone (a full-batch one would
+# train without its fast path), and on a 1-D mesh alone (below).
+FIELD_FM_MESH = Serves(
+    COMPACT_LEVERS - {"host_dedup"}
+    | {"use_pallas", "gfull_fused", "collective_dtype", "score_sharded"},
+    remedy=SGD_TABLES)
+
+
 def _make_field_local_step(spec, config: TrainConfig, mesh):
     """Build the FM sharded LOCAL step (the per-shard function inside
     the shard_map) plus its layout facts. Shared by the per-step wrapper
@@ -505,30 +524,8 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
         raise ValueError("expected a FieldFMSpec")
     if not spec.fused_linear:
         raise ValueError("field-sharded step requires fused_linear=True")
-    if config.optimizer != "sgd":
-        from fm_spark_tpu.sparse import _SGD_ONLY
-
-        raise ValueError(_SGD_ONLY.format(what="the field-sharded FieldFM step",
-                                          got=config.optimizer))
-    from fm_spark_tpu.sparse import (
-        _apply_field_updates,
-        _check_host_dedup,
-        _collective_dtype,
-        _compact_apply_all,
-        _gather_all,
-        _gather_fn,
-        _lr_at,
-        _reject_deep_sharded,
-        _reject_host_aux,
-        _reject_sel_blocked,
-        _sr_base_key,
-    )
-
-    _reject_deep_sharded(config, "the field-sharded FM step")
-    _reject_sel_blocked(config, "the field-sharded FM step")
-    from fm_spark_tpu.sparse import _reject_fused_embed_require
-
-    _reject_fused_embed_require(config, "the field-sharded FM step")
+    refuse_unserved(config, FIELD_FM_MESH, "the field-sharded FM step",
+                    spec.loss)
     if set(mesh.axis_names) not in ({"feat"}, {"feat", "row"}):
         raise ValueError(
             "field-sharded step runs on a ('feat',) or ('feat', 'row') "
@@ -539,10 +536,6 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
     compact = config.compact_cap > 0
     device_cap = config.compact_cap if config.compact_device else 0
     host_compact = compact and not config.compact_device
-    # Unconditional, like the single-chip factories: compact_device
-    # without compact_cap (or a mismatched overflow policy) must fail
-    # loudly here too, never silently train the plain path.
-    _check_host_dedup(config, spec.loss)
     if host_compact:
         # Compact HOST-dedup on the sharded step: supported on the 1-D
         # feat mesh — the aux is built from the GLOBAL batch and shards
@@ -557,8 +550,6 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
                 "1-D ('feat',) mesh; use compact_device=True for 2-D "
                 "(feat, row) meshes"
             )
-    elif config.host_dedup:
-        _reject_host_aux(config, "the field-sharded step (non-compact)")
 
     sr_base_key = _sr_base_key(config)
     gat = _gather_fn(config)
@@ -627,8 +618,6 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
             # numerics as the single-chip body by definition. Non-owned
             # lanes still produce garbage that the sentinel index /
             # dropped segment discards.
-            from fm_spark_tpu.sparse import _gfull_grads
-
             g_fulls = _gfull_grads(
                 dscores, vals_c, s, fwd.xv_fulls, rows, touched, k, cd,
                 spec.use_linear, config,
@@ -674,8 +663,6 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
         if ovf is not None:
             # Worst overflow anywhere on the mesh; the fold (policy
             # 'error') poisons the replicated loss so every host sees it.
-            from fm_spark_tpu.sparse import _fold_overflow
-
             loss = _fold_overflow(
                 loss, lax.pmax(ovf, g["score_axes"]), config
             )
@@ -684,6 +671,7 @@ def _make_field_local_step(spec, config: TrainConfig, mesh):
     return local_step, host_compact
 
 
+@declares(FIELD_FM_MESH)
 def make_field_sharded_sgd_body(spec, config: TrainConfig, mesh):
     """Unjitted ``(params, step_idx, ids, vals, labels, weights) →
     (params, loss)`` over stacked/sharded inputs; same semantics as the
@@ -708,6 +696,7 @@ def make_field_sharded_sgd_body(spec, config: TrainConfig, mesh):
     )
 
 
+@declares(FIELD_FM_MESH)
 def make_field_sharded_sgd_step(spec, config: TrainConfig, mesh):
     """Jitted field-sharded fused sparse-SGD step; params donated."""
     return jax.jit(

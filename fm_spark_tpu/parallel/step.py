@@ -206,27 +206,13 @@ def make_parallel_train_step(
     (params, opt_state, {loss, grad_norm})``. Inputs must be placed with
     :func:`shard_params` / :func:`shard_batch`.
     """
-    from fm_spark_tpu.sparse import (
-        _reject_collective_dtype,
-        _reject_deep_sharded,
-        _reject_host_aux,
-        _reject_score_sharded,
-    )
+    from fm_spark_tpu.sparse import OPTAX_OPTIMIZERS, Serves, refuse_unserved
 
-    _reject_host_aux(config, "the dense optax parallel step")
-    _reject_score_sharded(config, "the dense optax parallel step")
-    from fm_spark_tpu.sparse import _reject_sel_blocked
-
-    _reject_sel_blocked(config, "the dense optax parallel step")
-    _reject_deep_sharded(config, "the dense optax parallel step")
-    from fm_spark_tpu.sparse import _reject_fused_embed_require
-
-    _reject_fused_embed_require(config, "the dense optax parallel step")
     # Grad psums here feed the optimizer DIRECTLY (no later fp32
     # re-derivation), a different precision contract from the fused
-    # steps' activation collectives — not wired up; reject rather than
-    # silently ignore.
-    _reject_collective_dtype(config, "the dense optax parallel step")
+    # steps' activation collectives: collective_dtype is not served.
+    refuse_unserved(config, Serves(optimizers=OPTAX_OPTIMIZERS),
+                    "the dense optax parallel step", spec.loss)
     _check_divisibility(spec, mesh, strategy)
     optimizer = optimizer or make_optimizer(config)
     add_reg = _group_reg(config)

@@ -304,29 +304,10 @@ def make_train_step(spec, config: TrainConfig, optimizer=None):
     Returns ``step(params, opt_state, ids, vals, labels, weights) →
     (params, opt_state, metrics_dict)`` with donated params/opt_state.
     """
-    from fm_spark_tpu.sparse import (
-        _reject_collective_dtype,
-        _reject_deep_sharded,
-        _reject_host_aux,
-        _reject_score_sharded,
-    )
+    from fm_spark_tpu.sparse import OPTAX_OPTIMIZERS, Serves, refuse_unserved
 
-    from fm_spark_tpu.sparse import (
-        _reject_fused_embed_require,
-        _reject_sel_blocked,
-    )
-
-    _reject_host_aux(config, "the dense optax train step")
-    _reject_collective_dtype(config, "the dense single-device train step")
-    _reject_score_sharded(config, "the dense single-device train step")
-    _reject_deep_sharded(config, "the dense single-device train step")
-    _reject_sel_blocked(config, "the dense single-device train step")
-    _reject_fused_embed_require(
-        config, "the dense single-device train step")
-    from fm_spark_tpu.sparse import _reject_embed_tier_require
-
-    _reject_embed_tier_require(
-        config, "the dense single-device train step")
+    refuse_unserved(config, Serves(optimizers=OPTAX_OPTIMIZERS),
+                    "the dense single-device train step", spec.loss)
     optimizer = optimizer or make_optimizer(config)
     per_example_loss = losses_lib.loss_fn(spec.loss)
     add_reg = _group_reg(config)

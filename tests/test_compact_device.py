@@ -289,12 +289,13 @@ def test_config_validation():
     # The 'error' policy's -inf sentinel requires a provably
     # non-negative loss; an unlisted loss must fail at construction,
     # not silently corrupt the sentinel (ADVICE r4).
-    from fm_spark_tpu.sparse import _check_host_dedup
+    from fm_spark_tpu.sparse import FIELD_FM, refuse_unserved
 
     with pytest.raises(ValueError, match="non-negative losses"):
-        _check_host_dedup(
+        refuse_unserved(
             _base_cfg(sparse_update="dedup_sr", compact_device=True,
                       compact_cap=8, compact_overflow="error"),
+            FIELD_FM, "the single-chip FieldFM body",
             "exotic_negative_loss",
         )
 

@@ -461,13 +461,54 @@ _LOWERED_AT_PARENT = {
 }
 
 
+# The families of the newer cells, each under the optimizer its registered
+# configuration trains with; sha256 at commit 8c90096 (jax 0.9.0), CPU
+# devices, taken before the guards that build them were rewritten.
+_LOWERED_AT_PARENT.update({
+    ("dcn", 1):
+        "a6c69b8f01a087ab991e5fa8382d65aaef7da5866607cff8a6cc6116fb4127d1",
+    ("dlrm", 1):
+        "5f6bf469a5fed4c9641fe8a6480980ef8e3746881ff7bb32c4314e31cc618fe5",
+    ("ffm_adagrad", 1):
+        "f0f43ddbb0888fc49dcc22c485a478bcf4ea8eeca6add56d25f272b52c8f92da",
+    ("xdeepfm", 1):
+        "2e774be1025d3b26fdd1ed7d9c75b55f30074900ed5b853cae1cbd5ce7394103",
+})
+_PIN_HOTS = (3, 1, 2)
+_PIN_CONFIGS = {
+    "ffm_adagrad": TrainConfig(learning_rate=0.2, lr_schedule="constant",
+                               optimizer="adagrad", reg_factors=2e-5,
+                               adagrad_init_accumulator=2.0 ** -16),
+    "dlrm": TrainConfig(learning_rate=1.0, lr_schedule="constant",
+                        optimizer="sgd", reg_factors=0.0),
+    "dcn": TrainConfig(learning_rate=0.004, lr_schedule="constant",
+                       optimizer="adagrad", reg_factors=0.0,
+                       adagrad_init_accumulator=0.0),
+    "xdeepfm": TrainConfig(learning_rate=1e-3, lr_schedule="constant",
+                           optimizer="adam", reg_factors=1e-4),
+}
+
+
 def _pin_spec(family):
     common = dict(num_features=_PIN_FIELDS * _PIN_BUCKET,
                   num_fields=_PIN_FIELDS, bucket=_PIN_BUCKET)
     if family == "fm":
         return models.FieldFMSpec(rank=64, **common)
-    if family == "ffm":
+    if family in ("ffm", "ffm_adagrad"):
         return models.FieldFFMSpec(rank=16, **common)
+    if family == "dlrm":
+        return models.FieldDLRMSpec(rank=8, dense_fields=2,
+                                    bottom_mlp_dims=(16, 8),
+                                    mlp_dims=(16, 16), **common)
+    if family == "dcn":
+        return models.FieldDCNSpec(
+            num_features=(2 + len(_PIN_HOTS)) * _PIN_BUCKET, rank=8,
+            num_fields=2 + sum(_PIN_HOTS), bucket=_PIN_BUCKET,
+            dense_fields=2, hots=_PIN_HOTS, bottom_mlp_dims=(16, 8),
+            cross_layers=2, cross_rank=4, mlp_dims=(16, 16))
+    if family == "xdeepfm":
+        return models.FieldXDeepFMSpec(rank=10, cin_layers=(8, 6, 4),
+                                       mlp_dims=(16, 16), **common)
     return models.FieldDeepFMSpec(rank=16, mlp_dims=(32, 16), **common)
 
 
@@ -479,10 +520,11 @@ def test_step_lowers_to_the_parents_text(family, chips):
     from fm_spark_tpu.parallel import lower_field_sharded_step
 
     spec = _pin_spec(family)
+    config = _PIN_CONFIGS.get(family, _PIN_CONFIG)
     lowered = (
-        sparse.lower_field_sparse_step(spec, _PIN_CONFIG, _PIN_BATCH)
+        sparse.lower_field_sparse_step(spec, config, _PIN_BATCH)
         if chips == 1 else
-        lower_field_sharded_step(spec, _PIN_CONFIG, make_field_mesh(chips),
+        lower_field_sharded_step(spec, config, make_field_mesh(chips),
                                  _PIN_BATCH))
     text = lowered.as_text()
     assert (hashlib.sha256(text.encode()).hexdigest()

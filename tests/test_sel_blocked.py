@@ -133,19 +133,25 @@ def test_sharded_ffm_step_rejects_sel_blocked():
 
 
 def test_cli_lever_rejects_non_ffm():
-    from fm_spark_tpu.cli_levers import _v_sel_blocked
+    from fm_spark_tpu import cli
 
     fm = models.FieldFMSpec(num_features=64, rank=3, num_fields=4,
                             bucket=16, init_std=0.1)
     tc = TrainConfig(learning_rate=0.1, lr_schedule="constant",
                      optimizer="sgd", sel_blocked=True)
-    ctx = {"spec": fm, "n": 1, "sharded": False}
-    assert "sel-blocked" in _v_sel_blocked(tc, ctx)
-    ffm_ctx = {"spec": _spec(), "n": 1, "sharded": False}
-    assert _v_sel_blocked(tc, ffm_ctx) is None
-    assert "sel-blocked" in _v_sel_blocked(
-        tc, {"spec": _spec(), "n": 8, "sharded": True}
-    )
+
+    def refusal(spec, n):
+        try:
+            cli._validate_field_caps(
+                spec, tc, cli._FIELD_CAPS[type(spec).__name__], n, 1,
+                n > 1, 1, 1, False)
+        except SystemExit as refused:
+            return str(refused)
+        return None
+
+    assert "sel-blocked" in refusal(fm, 1)
+    assert refusal(_spec(), 1) is None
+    assert "sel-blocked" in refusal(_spec(), 8)
 
 
 def test_dense_and_sharded_fm_factories_reject_sel_blocked():
